@@ -334,7 +334,8 @@ class DistributedBackend(SortBackend):
 
     @staticmethod
     def _host_mesh():
-        return jax.make_mesh((len(jax.devices()),), ("data",))
+        from repro.launch.mesh import make_host_mesh
+        return make_host_mesh()
 
     # -- mesh execution (what SortSpec.mesh routes to) ----------------------
     def sort_mesh(self, x, mesh, axis_name, *, values=None, descending=False,

@@ -45,11 +45,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.5 exports shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def bitonic_merge_halves(lo_sorted: jnp.ndarray, hi_sorted: jnp.ndarray):
     """Merge two ascending arrays (each length m) and return the ascending
@@ -127,6 +122,8 @@ def distributed_sort(x: jnp.ndarray, mesh: Mesh, axis_name=None,
     """
     from repro.core import topology as _topology
     from repro.engine import planner, samplesort
+    mesh = _topology.auto_mesh(mesh)
+    x = _topology.to_auto_mesh(x)
     axes = samplesort._axes_tuple(mesh, axis_name)
     n_dev = samplesort._n_dev(mesh, axes)
     multi = len(axes) > 1
@@ -204,7 +201,7 @@ def _oddeven_fn(mesh: Mesh, axis_name: str, local_method: Optional[str],
         return xs
 
     spec = P(axis_name)
-    fn = _shard_map(local, mesh=mesh, in_specs=(spec,), out_specs=spec)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(spec,), out_specs=spec)
     return jax.jit(fn)
 
 

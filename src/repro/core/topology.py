@@ -220,6 +220,37 @@ def _default_rates(tier: str) -> Tuple[float, float]:
     return bw, lat
 
 
+def auto_mesh(mesh):
+    """``mesh`` with every axis Auto-typed (the same devices and names).
+
+    ``jax.make_mesh`` gives Explicit axes, whose sharding-in-types rules
+    reject gathers and ``with_sharding_constraint`` on the arrays they
+    shard.  The library's mesh programs are ``shard_map``s and its
+    placement rules are constraints, so they run on the Auto-typed twin
+    of whatever mesh a caller builds."""
+    from jax.sharding import AxisType, Mesh
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
+def to_auto_mesh(x, mesh=None):
+    """An array placed on an Explicit-axes mesh, moved (same layout) onto
+    the Auto-typed twin of that mesh, or of ``mesh`` (needed under a
+    trace, where the array's own mesh is abstract); anything else is
+    returned unchanged."""
+    import jax
+    from jax.sharding import AxisType, NamedSharding
+    sh = jax.typeof(x).sharding if isinstance(x, jax.core.Tracer) \
+        else getattr(x, "sharding", None)
+    if not isinstance(sh, NamedSharding) \
+            or all(t == AxisType.Auto for t in sh.mesh.axis_types):
+        return x
+    target = auto_mesh(sh.mesh if mesh is None else mesh)
+    return jax.device_put(x, NamedSharding(target, sh.spec))
+
+
 def _mesh_signature(mesh, axis_names=None) -> Tuple[Tuple[str, int], ...]:
     names = tuple(axis_names) if axis_names is not None \
         else tuple(mesh.axis_names)
@@ -426,11 +457,6 @@ def calibrate(mesh, axis_names: Optional[Sequence[str]] = None, *,
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    try:
-        _shard_map = jax.shard_map
-    except AttributeError:              # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map as _shard_map
-
     base = from_mesh(mesh, axis_names)
     probe: Dict[str, float] = {}
     axes_out = []
@@ -446,12 +472,8 @@ def calibrate(mesh, axis_names: Optional[Sequence[str]] = None, *,
             def body(v):
                 return jax.lax.all_to_all(v, name, split_axis=0,
                                           concat_axis=0, tiled=True)
-            try:
-                fn = _shard_map(body, mesh=mesh, in_specs=(P(name),),
-                                out_specs=P(name), check_rep=False)
-            except TypeError:
-                fn = _shard_map(body, mesh=mesh, in_specs=(P(name),),
-                                out_specs=P(name), check_vma=False)
+            fn = jax.shard_map(body, mesh=mesh, in_specs=(P(name),),
+                               out_specs=P(name), check_vma=False)
             x = jnp.zeros((size * size * per_row,), jnp.float32)
             return _time_ns(jax.jit(fn), x, reps=reps), 4 * size * per_row
 

@@ -147,6 +147,8 @@ def merge_runs(run_keys: jnp.ndarray, run_vals: Optional[jnp.ndarray] = None,
     rows, r, l = run_keys.shape
     if r & (r - 1):
         raise ValueError(f"run count must be a power of two, got {r}")
+    if backend == "pallas":
+        return _merge_runs_flat(run_keys, run_vals, descending, interpret)
     keys, vals = run_keys, run_vals
     while r > 1:
         kv = keys.reshape(rows * (r // 2), 2, l)
@@ -167,6 +169,34 @@ def merge_runs(run_keys: jnp.ndarray, run_vals: Optional[jnp.ndarray] = None,
     if run_vals is None:
         return keys
     return keys, vals.reshape(rows, l)
+
+
+def _merge_runs_flat(run_keys, run_vals, descending, interpret):
+    """``merge_runs`` on the merge-path kernel, with every level on the
+    flat array (adjacent runs are the pairs), so no level materialises a
+    few-row, very wide array.  Descending flips the whole array once:
+    that reverses every run and the run order, so the ascending merge's
+    left-wins-ties rule still keeps the earlier run first after the flip
+    back."""
+    from repro.kernels import merge_path as _mp
+    rows, r, l = run_keys.shape
+    keys = run_keys.reshape(-1)
+    vals = None if run_vals is None else run_vals.reshape(-1)
+    if descending:
+        keys = jnp.flip(keys)
+        vals = None if vals is None else jnp.flip(vals)
+    while r > 1:
+        if vals is None:
+            keys = _mp.merge_flat(keys, l=l, interpret=interpret)
+        else:
+            keys, vals = _mp.merge_flat(keys, vals, l=l, interpret=interpret)
+        r //= 2
+        l *= 2
+    if descending:
+        keys = jnp.flip(keys)
+        vals = None if vals is None else jnp.flip(vals)
+    keys = keys.reshape(rows, l)
+    return keys if run_vals is None else (keys, vals.reshape(rows, l))
 
 
 def _pad_value(dtype, descending: bool):

@@ -423,8 +423,13 @@ def _probe_registered(x, sel_k: int, reps: int,
                 f = jax.jit(lambda v, b=be: b.topk(v, sel_k)[0])
                 table[f"{name}.topk.n{n}.k{sel_k}"] = _time_ns(
                     lambda: jax.block_until_ready(f(x)), reps)
-        except Exception:       # a broken third-party backend must not
-            continue            # sink the whole calibration
+        except Exception:
+            # a broken third-party backend must not sink the whole
+            # calibration; one of this repo's must not drop out of the
+            # profile unseen
+            if type(be).__module__.startswith("repro."):
+                raise
+            continue
     return table
 
 
@@ -541,22 +546,17 @@ def _sweep_capacity_slack(reps: int) -> Tuple[Optional[float],
     devs = jax.devices()
     if len(devs) < 2:
         return None, {}
-    from jax.sharding import Mesh
     from repro.engine.samplesort import sample_sort
+    from repro.launch.mesh import make_host_mesh
     n_dev = len(devs)
-    mesh = Mesh(np.array(devs), ("data",))
+    mesh = make_host_mesh()
     x = jnp.asarray(np.random.default_rng(2).standard_normal(1024 * n_dev),
                     jnp.float32)
     table: Dict[str, float] = {}
     for slack in (1.0, 1.25, 1.5):
-        try:
-            ns = _time_ns(lambda s=slack: jax.block_until_ready(
+        table[f"capacity_slack={slack}"] = _time_ns(
+            lambda s=slack: jax.block_until_ready(
                 sample_sort(x, mesh, "data", capacity_slack=s)), reps)
-        except Exception:
-            continue
-        table[f"capacity_slack={slack}"] = ns
-    if not table:
-        return None, {}
     best = min(table, key=table.__getitem__)
     return float(best.split("=")[1]), table
 

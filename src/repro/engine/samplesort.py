@@ -63,14 +63,10 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import keycodec
 from repro.core import tuning as _tuning
+from repro.core.topology import auto_mesh, to_auto_mesh
 from repro.engine import collectives as coll
 from repro.engine.merge import merge_runs
 from repro.obs import metrics, trace as _obs
-
-try:  # jax >= 0.5 exports shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 __all__ = ["sample_sort", "sample_topk", "select_splitters", "bucket_bounds",
            "default_samples_per_shard", "alltoall_bytes_per_device",
@@ -125,15 +121,13 @@ def bucket_bounds(ks: jnp.ndarray, splitters: jnp.ndarray, *,
             else interpret
         # tile the shard exactly like the radix passes do — one full-shard
         # row would materialise an un-tiled (1, m, D) one-hot in VMEM.
-        # Pad slots carry an extra bucket id (n_dev) counted into a
-        # throwaway histogram column
-        tile = min(max(8, _tuning.active().radix_tile), m)
+        # Pad slots carry an out-of-range bucket id (n_dev), not counted
+        tile = _rs.lane_tile(m, _tuning.active().radix_tile)
         mt = -(-m // tile) * tile
         if mt != m:
             ids = jnp.pad(ids, (0, mt - m), constant_values=n_dev)
-        hist, _ = _rs._digit_stats(ids.reshape(mt // tile, tile),
-                                   n_dev + 1, interp)
-        counts = jnp.sum(hist, axis=0)[:n_dev]
+        hist = _rs.tile_hist(ids.reshape(mt // tile, tile), n_dev, interp)
+        counts = jnp.sum(hist, axis=0)
     else:
         starts = jnp.searchsorted(ks, splitters, side="right")
         counts = jnp.diff(jnp.concatenate(
@@ -147,12 +141,8 @@ def _smap(f, mesh, in_specs, out_specs):
     # replication checking has no rule for pallas_call (the histogram
     # kernel and any Pallas local sort), so it is disabled; every output
     # is explicitly sharded over the axis anyway
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-    except TypeError:  # jax >= 0.6 renamed the flag
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -296,6 +286,17 @@ def _exchange_merge(ks, vs, starts, vcnt, coll_axis, p, local_len, c,
     return mk, mv, mvalid, recv_cnt
 
 
+def _running_count(flags, width: int = 1024):
+    """Inclusive running count of a 1-D bool array: ``jnp.cumsum`` over
+    rows of ``width``, then over the row totals.  The TPU compiler takes
+    ten times longer over one flat scan of 2^27 elements."""
+    n = flags.shape[0]
+    y = jnp.pad(flags.astype(jnp.int32), (0, -n % width)).reshape(-1, width)
+    within = jnp.cumsum(y, axis=1)
+    total = within[:, -1]
+    return (within + (jnp.cumsum(total) - total)[:, None]).reshape(-1)[:n]
+
+
 def _rebalance(mk, mv, mvalid, recv_cnt, coll_axis, group, m, my):
     """Rank-directed rebalance of a merged pool back to equal ``m``-slot
     shards over ``group`` devices: rank r lives at slot ``r % m`` of
@@ -310,7 +311,7 @@ def _rebalance(mk, mv, mvalid, recv_cnt, coll_axis, group, m, my):
     c_my = jnp.sum(recv_cnt).astype(jnp.int32)
     counts_all = jax.lax.all_gather(c_my, coll_axis).reshape(-1)  # (group,)
     offset = jnp.sum(jnp.where(jnp.arange(group) < my, counts_all, 0))
-    lrank = jnp.cumsum(mvalid.astype(jnp.int32)) - 1
+    lrank = _running_count(mvalid) - 1
     grank = offset + lrank
     flat = jnp.where(mvalid, grank, n_slots)                  # OOB -> drop
     outk = jnp.zeros((n_slots,), mk.dtype).at[flat].set(
@@ -534,7 +535,7 @@ def _hier_phase3(mesh: Mesh, outer: str, inner: str, n: int, kv: bool,
         # a rank scatter (maxkey fill keeps the tail sorted for the
         # sub-splitter search)
         c_my = jnp.sum(recv_cnt).astype(jnp.int32)
-        lrank = jnp.cumsum(mvalid.astype(jnp.int32)) - 1
+        lrank = _running_count(mvalid) - 1
         tgt = jnp.where(mvalid, lrank, L)                  # OOB -> drop
         ck = jnp.full((L,), maxkey, mk.dtype).at[tgt].set(mk, mode="drop")
         cv = None
@@ -659,7 +660,8 @@ def sample_sort(x: jnp.ndarray, mesh: Mesh, axis_name: AxisArg = "data", *,
     codec — keys always travel wide, so the sort ORDER stays exact while
     payload values are quantised.
     """
-    x = jnp.asarray(x)
+    mesh = auto_mesh(mesh)
+    x = jnp.asarray(to_auto_mesh(x))
     if x.ndim != 1:
         raise ValueError(f"sample_sort sorts flat 1-D arrays, got {x.shape}")
     if not keycodec.supports(x.dtype):
@@ -673,7 +675,7 @@ def sample_sort(x: jnp.ndarray, mesh: Mesh, axis_name: AxisArg = "data", *,
     n_pad = n_dev * m
     kv = values is not None
     if kv:
-        values = jnp.asarray(values)
+        values = jnp.asarray(to_auto_mesh(values))
         if values.shape != x.shape:
             raise ValueError(f"values shape {values.shape} must match "
                              f"keys shape {x.shape}")
@@ -996,7 +998,8 @@ def sample_topk(x: jnp.ndarray, k: int, mesh: Mesh,
     contributes ``min(kc, g)`` of them, and ``sum(min(kc, g_d)) >= k``
     whenever ``n >= k`` — so the global top-k is always inside the pool.
     """
-    x = jnp.asarray(x)
+    mesh = auto_mesh(mesh)
+    x = jnp.asarray(to_auto_mesh(x))
     if x.ndim != 1:
         raise ValueError(f"sample_topk selects over flat 1-D arrays, "
                          f"got {x.shape}")

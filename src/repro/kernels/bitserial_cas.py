@@ -26,42 +26,46 @@ from repro.core.imc_array import Movement, OpKind, ROW_A, ROW_B, ROW_ONE, ROW_ZE
 
 
 def _exec_program(a: jnp.ndarray, b: jnp.ndarray, width: int):
-    """Run the gate program on int operands of shape (rows, lanes)."""
-    prog = gates.build_cas_program(width)
-    shape = a.shape + (width,)
-    # MSB first; built from an in-trace iota so Pallas sees no captured consts
-    shifts = (width - 1) - jax.lax.broadcasted_iota(jnp.int32, (width,), 0)
+    """Run the gate program on int operands of shape (rows, lanes).
 
-    planes = {
-        ROW_ZERO: jnp.zeros(shape, dtype=bool),
-        ROW_ONE: jnp.ones(shape, dtype=bool),
-        ROW_A: ((a[..., None] >> shifts) & 1).astype(bool),
-        ROW_B: ((b[..., None] >> shifts) & 1).astype(bool),
-    }
+    Each SRAM row is held as ``width`` separate (rows, lanes) 0/1 int32
+    planes, one per bit column (MSB first), so the column movements are
+    list shuffles and every gate is one lane-wise VPU op: Mosaic lays
+    (sublane, lane) vectors out, not a trailing bit axis."""
+    prog = gates.build_cas_program(width)
+    zero, one = jnp.zeros_like(a), jnp.ones_like(a)
+
+    def bit_planes(v):
+        return [(v >> (width - 1 - c)) & 1 for c in range(width)]
+
+    planes = {ROW_ZERO: [zero] * width, ROW_ONE: [one] * width,
+              ROW_A: bit_planes(a), ROW_B: bit_planes(b)}
 
     for op in prog.ops:
         x = planes[op.src1]
         if op.kind is OpKind.NOR:
-            r = ~(x | planes[op.src2])
+            r = [1 ^ (u | v) for u, v in zip(x, planes[op.src2])]
         elif op.kind is OpKind.AND:
-            r = x & planes[op.src2]
+            r = [u & v for u, v in zip(x, planes[op.src2])]
         elif op.kind is OpKind.NOT:
-            r = ~(x | planes[ROW_ZERO])
+            r = [1 ^ u for u in x]
         else:  # COPY
-            r = x & planes[ROW_ONE]
+            r = list(x)
         if op.movement is Movement.SHIFT_RIGHT:
-            fill = jnp.full_like(r[..., :1], bool(op.fill))
-            r = jnp.concatenate([fill, r[..., :-1]], axis=-1)
+            r = [one if op.fill else zero] + r[:-1]
         elif op.movement is Movement.BCAST_LAST:
-            r = jnp.broadcast_to(r[..., -1:], r.shape)
+            r = [r[-1]] * width
         elif op.movement is Movement.BCAST_COL:
-            r = jnp.broadcast_to(r[..., op.bcast_col:op.bcast_col + 1], r.shape)
+            r = [r[op.bcast_col]] * width
         planes[op.dst] = r
 
-    weights = (1 << shifts).astype(jnp.int32)
-    lo = jnp.sum(planes[ROW_A].astype(jnp.int32) * weights, axis=-1)
-    hi = jnp.sum(planes[ROW_B].astype(jnp.int32) * weights, axis=-1)
-    return lo, hi
+    def value(bits):
+        out = zero
+        for bit in bits:
+            out = (out << 1) | bit
+        return out
+
+    return value(planes[ROW_A]), value(planes[ROW_B])
 
 
 def _cas_kernel(a_ref, b_ref, lo_ref, hi_ref, *, width: int):
@@ -77,16 +81,18 @@ def cas_blocks(a: jnp.ndarray, b: jnp.ndarray, *, width: int = 4,
                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Elementwise in-memory CAS of (rows, lanes) unsigned ints < 2**width."""
     rows, lanes = a.shape
-    br = max(1, min(block_rows, rows))
-    while rows % br:
-        br -= 1
+    # a row block is a multiple of 8 sublanes or the whole array
+    br = rows if rows <= block_rows else max(8, block_rows // 8 * 8)
+    rows_p = -(-rows // br) * br
+    a, b = (jnp.pad(v.astype(jnp.int32), ((0, rows_p - rows), (0, 0)))
+            for v in (a, b))
     spec = pl.BlockSpec((br, lanes), lambda i: (i, 0))
-    return pl.pallas_call(
+    lo, hi = pl.pallas_call(
         functools.partial(_cas_kernel, width=width),
-        grid=(rows // br,),
+        grid=(rows_p // br,),
         in_specs=[spec, spec],
         out_specs=[spec, spec],
-        out_shape=[jax.ShapeDtypeStruct((rows, lanes), jnp.int32),
-                   jax.ShapeDtypeStruct((rows, lanes), jnp.int32)],
+        out_shape=[jax.ShapeDtypeStruct((rows_p, lanes), jnp.int32)] * 2,
         interpret=interpret,
-    )(a.astype(jnp.int32), b.astype(jnp.int32))
+    )(a, b)
+    return lo[:rows], hi[:rows]

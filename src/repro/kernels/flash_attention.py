@@ -46,12 +46,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, off_ref, o_ref, *, q_block: int,
 
     def body(c, carry):
         m, l, acc = carry
-        # index the leading block dim with a size-1 ds: this jax build's
-        # pl.load rejects bare int indices (int has no .shape)
-        k = pl.load(k_ref, (pl.ds(0, 1), pl.ds(c * k_block, k_block),
-                            slice(None)))[0].astype(jnp.float32)
-        v = pl.load(v_ref, (pl.ds(0, 1), pl.ds(c * k_block, k_block),
-                            slice(None)))[0].astype(jnp.float32)
+        k = k_ref[0, pl.ds(c * k_block, k_block), :].astype(jnp.float32)
+        v = v_ref[0, pl.ds(c * k_block, k_block), :].astype(jnp.float32)
         s = q @ k.T                                      # (qb, kb)
         kpos = c * k_block + jax.lax.broadcasted_iota(
             jnp.int32, (qb, k_block), 1)

@@ -11,18 +11,22 @@ partition-then-exchange structure ADS-IMC uses across its SRAM CAS partitions
 
 Division of labour:
 
-  host (jnp)     diagonal binary search -> per-chunk window starts/counts,
-                 windows gathered into contiguous (rows*chunks, C) arrays.
-  kernel (VMEM)  rank-based merge of the two windows: each element's output
-                 slot is its window index plus its cross-rank in the other
-                 window (counted with a C x C comparison matrix on the VPU),
-                 then a one-hot select writes the chunk — no dynamic scatter,
-                 no serial loop, everything vector ops.
+  host (jnp)     diagonal binary search -> per-chunk window starts/counts;
+                 each chunk's ``ca`` a-elements and ``C - ca`` b-elements
+                 are gathered into one row ``a-window ++ reversed b-window``,
+                 which is bitonic (ascending, then descending).
+  kernel (VMEM)  the bitonic network's final merge stage sorts every such
+                 row: log2(C) compare-and-swap substages of lane and
+                 sublane rotations (kernels/bitonic_sort.py) — no dynamic
+                 scatter, no serial loop, everything vector ops.
 
 Validity is tracked with explicit per-window counts (not key sentinels), so
-inputs containing the dtype's extreme values still merge bit-exactly.  Keys
-must be NaN-free (comparisons follow min/max semantics, like the bitonic
-kernels).  Ascending only — callers flip for descending merges.
+inputs containing the dtype's extreme values still merge bit-exactly.  The
+key-value merge orders by (key, origin), where the origin numbers the
+a-window before the b-window, so ties keep a's elements first (stable) and
+payloads ride along.  Keys must be NaN-free (comparisons follow min/max
+semantics, like the bitonic kernels).  Ascending only — callers flip for
+descending merges.
 """
 from __future__ import annotations
 
@@ -31,160 +35,128 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
+
+from repro.kernels import bitonic_sort as _bs
 
 DEFAULT_CHUNK = 256
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-# ---------------------------------------------------------------------------
-# kernel bodies
-# ---------------------------------------------------------------------------
-
-def _window_ranks(a, b, ca):
-    """Output slot of every window element (ascending, a wins ties).
-
-    a, b: (br, C) value windows; ca: (br, 1) count of valid a-elements
-    (valid b-count is C - ca).  Invalid slots rank past the chunk (>= C).
-    """
-    br, c = a.shape
-    ii = jax.lax.broadcasted_iota(jnp.int32, (br, c), 1)
-    valid_a = ii < ca
-    valid_b = ii < (c - ca)
-    # b_before[r, i, j]: does b[j] precede a[i]?  (strict: a first on ties)
-    b_before = (b[:, None, :] < a[:, :, None]) & valid_b[:, None, :]
-    ra = ii + jnp.sum(b_before.astype(jnp.int32), axis=2)
-    ra = jnp.where(valid_a, ra, c)
-    # a_before_or_tie[r, i, j]: does a[i] precede b[j]?
-    a_before = (a[:, :, None] <= b[:, None, :]) & valid_a[:, :, None]
-    rb = ii + jnp.sum(a_before.astype(jnp.int32), axis=1)
-    rb = jnp.where(valid_b, rb, c)
-    return ra, rb
-
-
-def _one_hot_place(src, ranks, c):
-    """Route src[r, i] to output slot ranks[r, i]; slots >= c drop out."""
-    slots = jax.lax.broadcasted_iota(jnp.int32, (1, 1, c), 2)
-    hit = ranks[:, :, None] == slots
-    zero = jnp.zeros((), src.dtype)
-    return jnp.sum(jnp.where(hit, src[:, :, None], zero), axis=1)
-
-
-def _merge_chunk_kernel(ca_ref, wa_ref, wb_ref, o_ref):
-    a, b = wa_ref[...], wb_ref[...]
-    c = a.shape[-1]
-    ra, rb = _window_ranks(a, b, ca_ref[...])
-    o_ref[...] = _one_hot_place(a, ra, c) + _one_hot_place(b, rb, c)
-
-
-def _merge_chunk_kv_kernel(ca_ref, wa_ref, wb_ref, va_ref, vb_ref,
-                           ok_ref, ov_ref):
-    a, b = wa_ref[...], wb_ref[...]
-    c = a.shape[-1]
-    ra, rb = _window_ranks(a, b, ca_ref[...])
-    ok_ref[...] = _one_hot_place(a, ra, c) + _one_hot_place(b, rb, c)
-    ov_ref[...] = (_one_hot_place(va_ref[...], ra, c)
-                   + _one_hot_place(vb_ref[...], rb, c))
 
 
 # ---------------------------------------------------------------------------
 # host side: diagonal partition + window gather
 # ---------------------------------------------------------------------------
 
-def _diag_search(a, b, diag):
+# Pairs are stored flat, each ``a ++ b`` run pair contiguous, and every
+# diagonal carries the flat offsets of its pair's runs, so the search, the
+# window gathers and the output are plain 1-D: the TPU compiler takes
+# minutes over the relayout of a (2, 2^26) array, and over the row-batched
+# form of these gathers.
+
+def _diag_search(z, diag, base_a, base_b, l: int):
     """Merge-path split: #a-elements among the first ``diag`` merged outputs.
 
-    a, b: (rows, La/Lb) ascending; diag: (n_diag,) int32.  Returns
-    (rows, n_diag).  Ties go to ``a`` (stable when a precedes b).  Classic
-    monotone-predicate binary search, vectorised over rows x diagonals.
+    z: flat pairs of ascending runs of length ``l``; diag, base_a, base_b:
+    (m,) int32 diagonals and the flat offsets of their pair's a and b runs.
+    Returns (m,).  Ties go to ``a`` (stable when a precedes b).  Classic
+    monotone-predicate binary search, vectorised over all diagonals.
     """
-    la, lb = a.shape[-1], b.shape[-1]
-    d = jnp.broadcast_to(diag[None, :], (a.shape[0], diag.shape[0]))
-    lo = jnp.maximum(0, d - lb)
-    hi = jnp.minimum(d, la)
-    steps = max(1, int(la).bit_length())
-    for _ in range(steps):
+    lo = jnp.maximum(0, diag - l)
+    hi = jnp.minimum(diag, l)
+    for _ in range(max(1, int(l).bit_length())):
         mid = (lo + hi + 1) // 2
-        a_prev = jnp.take_along_axis(a, jnp.clip(mid - 1, 0, la - 1), axis=-1)
-        b_next = jnp.take_along_axis(b, jnp.clip(d - mid, 0, lb - 1), axis=-1)
+        a_prev = _take(z, base_a + jnp.clip(mid - 1, 0, l - 1))
+        b_next = _take(z, base_b + jnp.clip(diag - mid, 0, l - 1))
         # feasible(mid): can take >= mid elements of a before diag?
-        feasible = (mid <= lo) | (d - mid >= lb) | (a_prev <= b_next)
+        feasible = (mid <= lo) | (diag - mid >= l) | (a_prev <= b_next)
         lo = jnp.where(feasible, jnp.maximum(lo, mid), lo)
         hi = jnp.where(feasible, hi, jnp.minimum(hi, mid - 1))
     return lo
 
 
-def _gather_windows(x, starts, c):
-    """x: (rows, L) -> (rows, n_chunks, c) windows starting at ``starts``."""
-    l = x.shape[-1]
-    idx = jnp.clip(starts[..., None]
-                   + jnp.arange(c, dtype=jnp.int32)[None, None, :], 0, l - 1)
-    return jnp.take_along_axis(x[:, None, :], idx, axis=-1)
+def _take(x, idx):
+    return x.at[idx].get(mode="promise_in_bounds")
 
 
-def _pick_block_rows(total_rows: int, c: int) -> int:
-    # the (br, C, C) comparison tensor dominates VMEM: keep it ~2 MB
-    br = max(1, min(total_rows, (2 << 20) // max(1, c * c * 4)))
-    while total_rows % br:
-        br -= 1
-    return br
+def _gather_windows(x, starts, base, l: int, c: int):
+    """(m, c) windows of the runs at flat offsets ``base`` of ``x``,
+    starting at ``starts`` within their run."""
+    idx = jnp.clip(starts[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :],
+                   0, l - 1)
+    return _take(x, base[:, None] + idx)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("l", "chunk", "interpret"))
+def merge_flat(z: jnp.ndarray, values: Optional[jnp.ndarray] = None, *,
+               l: int, chunk: int = DEFAULT_CHUNK,
+               interpret: Optional[bool] = None):
+    """Merge every pair of adjacent ascending runs of length ``l`` in the
+    flat ``z`` (``z[2pl:(2p+1)l]`` with ``z[(2p+1)l:(2p+2)l]``) into one
+    ascending run of ``2l`` in place; ``values`` (same layout) ride along.
+    Returns the merged keys, or (keys, values)."""
+    total = 2 * l
+    pairs = z.shape[0] // total
+    c = min(chunk, total)
+    nc = total // c
+    # one entry per (pair, chunk), pair-major
+    chunk_id = jnp.arange(pairs * nc, dtype=jnp.int32) % nc
+    diag = chunk_id * c
+    base_a = jnp.arange(pairs * nc, dtype=jnp.int32) // nc * total
+    base_b = base_a + l
+    starts_a = _diag_search(z, diag, base_a, base_b, l)
+    ends_a = jnp.where(chunk_id == nc - 1, l,
+                       jnp.concatenate([starts_a[1:], starts_a[:1]]))
+    counts_a = (ends_a - starts_a)[:, None]
+    starts_b = diag - starts_a
+    # slot s of a chunk row holds a[start_a + s] for s < ca and the
+    # b-window reversed, b[start_b + (cw - 1 - s)], in the last c - ca
+    # slots; a non-power-of-two chunk pads the peak between them with the
+    # maximal key, which keeps the row bitonic and sorts the pads last
+    cw = _bs.next_pow2(c)
+    slot = jnp.arange(cw, dtype=jnp.int32)[None, :]
+    from_a = slot < counts_a
+    from_b = slot >= cw - (c - counts_a)
+
+    def window_row(x, fill):
+        wa = _gather_windows(x, starts_a, base_a, l, cw)
+        wb = _gather_windows(x, starts_b, base_b, l, cw)[:, ::-1]
+        return jnp.where(from_a, wa, jnp.where(from_b, wb, fill))
+
+    zw = window_row(z, _max_key(z.dtype))
+    if values is None:
+        (out,) = _bs.merge_bitonic_blocks(zw, interpret=interpret)
+        return out[:, :c].reshape(-1)
+    origin = jnp.where(from_a, slot,
+                       jnp.where(from_b, counts_a + (cw - 1 - slot),
+                                 cw + slot))
+    mk, _, mv = _bs.merge_bitonic_blocks(
+        zw, origin, (window_row(values, jnp.zeros((), values.dtype)),),
+        interpret=interpret)
+    return mk[:, :c].reshape(-1), mv[:, :c].reshape(-1)
+
+
 def merge_pairs_blocks(a: jnp.ndarray, b: jnp.ndarray, *,
                        chunk: int = DEFAULT_CHUNK,
                        interpret: Optional[bool] = None) -> jnp.ndarray:
     """Merge row-wise sorted (rows, L) + (rows, L) -> (rows, 2L), ascending."""
-    (out,) = _merge_impl(a, b, (), chunk, interpret)
-    return out
+    rows, l = a.shape
+    z = jnp.concatenate([a, b], axis=-1).reshape(-1)
+    return merge_flat(z, l=l, chunk=chunk,
+                      interpret=interpret).reshape(rows, 2 * l)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def merge_pairs_kv_blocks(a: jnp.ndarray, b: jnp.ndarray,
                           va: jnp.ndarray, vb: jnp.ndarray, *,
                           chunk: int = DEFAULT_CHUNK,
                           interpret: Optional[bool] = None
                           ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Key-value variant: payloads ride along their keys through the merge."""
-    return tuple(_merge_impl(a, b, (va, vb), chunk, interpret))
-
-
-def _merge_impl(a, b, values, chunk, interpret):
-    interp = _interpret_default() if interpret is None else interpret
     rows, l = a.shape
-    total = 2 * l
-    c = min(chunk, total)
-    nc = total // c
-    diag = (jnp.arange(nc, dtype=jnp.int32)) * c
-    starts_a = _diag_search(a, b, diag)                     # (rows, nc)
-    ends_a = jnp.concatenate(
-        [starts_a[:, 1:], jnp.full((rows, 1), l, jnp.int32)], axis=-1)
-    counts_a = (ends_a - starts_a).reshape(rows * nc, 1)
-    starts_b = diag[None, :] - starts_a
-    wa = _gather_windows(a, starts_a, c).reshape(rows * nc, c)
-    wb = _gather_windows(b, starts_b, c).reshape(rows * nc, c)
-    ins = [counts_a, wa, wb]
-    outs = [jax.ShapeDtypeStruct((rows * nc, c), a.dtype)]
-    kernel = _merge_chunk_kernel
-    if values:
-        va, vb = values
-        ins += [_gather_windows(va, starts_a, c).reshape(rows * nc, c),
-                _gather_windows(vb, starts_b, c).reshape(rows * nc, c)]
-        outs.append(jax.ShapeDtypeStruct((rows * nc, c), va.dtype))
-        kernel = _merge_chunk_kv_kernel
-    br = _pick_block_rows(rows * nc, c)
-    grid = (rows * nc // br,)
-    cspec = pl.BlockSpec((br, 1), lambda i: (i, 0))
-    wspec = pl.BlockSpec((br, c), lambda i: (i, 0))
-    res = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[cspec] + [wspec] * (len(ins) - 1),
-        out_specs=[wspec] * len(outs),
-        out_shape=outs,
-        interpret=interp,
-    )(*ins)
-    return [r.reshape(rows, total) for r in res]
+    z = jnp.concatenate([a, b], axis=-1).reshape(-1)
+    v = jnp.concatenate([va, vb], axis=-1).reshape(-1)
+    mk, mv = merge_flat(z, v, l=l, chunk=chunk, interpret=interpret)
+    return mk.reshape(rows, 2 * l), mv.reshape(rows, 2 * l)
+
+
+def _max_key(dtype):
+    if jnp.issubdtype(dtype, jnp.floating):
+        return jnp.array(jnp.inf, dtype)
+    return jnp.array(jnp.iinfo(dtype).max, dtype)

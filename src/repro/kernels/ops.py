@@ -15,7 +15,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import bitonic_sort as _bs
-from repro.kernels import bitonic_topk as _bt
 from repro.kernels import bitserial_cas as _bc
 
 
@@ -66,8 +65,7 @@ def _sort_fwd_impl(x, axis, descending, interpret):
     if m != n:
         rows = jnp.pad(rows, ((0, 0), (0, m - n)),
                        constant_values=_sentinel(x.dtype, descending))
-    idx = jnp.broadcast_to(jnp.arange(m, dtype=jnp.int32), rows.shape)
-    sk, si = _bs.sort_kv_blocks(rows, idx, descending=descending,
+    sk, si = _bs.argsort_blocks(rows, descending=descending,
                                 interpret=interp)
     sk, si = sk[:, :n], si[:, :n]
     return _from_rows(sk, lead, ax), _from_rows(si, lead, ax)
@@ -134,7 +132,7 @@ def _topk_impl(x, k, chunk, interpret):
         m = max(_next_pow2(n), _next_pow2(k))
         if m != n:
             rows = jnp.pad(rows, ((0, 0), (0, m - n)), constant_values=sent)
-        v, i = _bt.topk_blocks(rows, k, interpret=interp)
+        v, i = _bs.topk_blocks(rows, k, interpret=interp)
         return (v.reshape(*lead, k), i.reshape(*lead, k))
 
     # hierarchical: per-chunk top-k, then merge candidates by key
@@ -144,7 +142,7 @@ def _topk_impl(x, k, chunk, interpret):
         rows = jnp.pad(rows, ((0, 0), (0, m - n)), constant_values=sent)
     r = rows.reshape(-1, chunk)
     kk = min(k, chunk)
-    v, i = _bt.topk_blocks(r, kk, interpret=interp)
+    v, i = _bs.topk_blocks(r, kk, interpret=interp)
     offs = (jnp.arange(n_chunks, dtype=jnp.int32) * chunk)[None, :, None]
     v = v.reshape(-1, n_chunks, kk)
     i = i.reshape(-1, n_chunks, kk) + offs

@@ -34,9 +34,9 @@ The refinement has two interchangeable engines, mirroring
 
   * ``use_kernel=True`` (TPU default) — DIGIT_BITS-wide passes on a
     per-tile one-hot histogram Pallas kernel in the style of
-    ``radix_sort._digit_stats``: the grid partitions tiles exactly like
+    ``radix_sort.tile_hist``: the grid partitions tiles exactly like
     the paper partitions its SRAM macro, inactive/pad slots carry an
-    extra digit counted into a throwaway column.
+    out-of-range digit that the kernel does not count.
   * ``use_kernel=False`` (host default) — radix-2 refinement, the
     faithful analogue of the paper's bit-serial CAS walk: one masked
     zero-count per key bit, pure branchless compare+reduce jnp with no
@@ -51,14 +51,13 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-
 from repro.core import keycodec
 # kernel shape parameters (digit width, histogram tile) come from the
 # tuning layer's active profile — the same object the cost model prices
 # with (cost_model.selection_cost_ns), so pricing, the LSD sort kernels,
 # and this module can't drift apart
 from repro.core import tuning as _tuning
+from repro.kernels import radix_sort as _rs
 
 __all__ = ["select_topk", "select_topk_kv", "select_topk_encoded",
            "kth_key_encoded"]
@@ -97,58 +96,27 @@ def pass_tile_counts(n: int, dtype, use_kernel: Optional[bool] = None,
     if not use_kernel:
         return bits, 0
     tile, digit_bits = _resolve(tile, digit_bits)
-    tile = min(tile, max(8, n))
-    return -(-bits // digit_bits), -(-n // tile)
+    return -(-bits // digit_bits), -(-n // _rs.lane_tile(n, tile))
 
 
 # ---------------------------------------------------------------------------
-# per-tile histogram kernel (the radix_sort._digit_stats counting half)
+# masked histogram on the radix sort's per-tile counting kernel
 # ---------------------------------------------------------------------------
-
-def _hist_kernel(d_ref, hist_ref, *, ncols: int):
-    """Per-tile digit histogram from one one-hot expansion on the VPU."""
-    slots = jax.lax.broadcasted_iota(jnp.int32, (1, 1, ncols), 2)
-    oh = (d_ref[...][:, :, None] == slots).astype(jnp.int32)
-    hist_ref[...] = jnp.sum(oh, axis=1)
-
-
-def _pick_block_rows(total_rows: int, c: int, ncols: int) -> int:
-    # the (br, C, ncols) one-hot tensor dominates VMEM: keep it ~2 MB
-    br = max(1, min(total_rows, (2 << 20) // max(1, c * ncols * 4)))
-    while total_rows % br:
-        br -= 1
-    return br
-
-
-@functools.partial(jax.jit, static_argnames=("ncols", "interpret"))
-def _tile_hist(d: jnp.ndarray, ncols: int, interpret: bool) -> jnp.ndarray:
-    """(tiles, C) int32 digits in [0, ncols) -> (tiles, ncols) counts."""
-    rows, c = d.shape
-    br = _pick_block_rows(rows, c, ncols)
-    return pl.pallas_call(
-        functools.partial(_hist_kernel, ncols=ncols),
-        grid=(rows // br,),
-        in_specs=[pl.BlockSpec((br, c), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((br, ncols), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, ncols), jnp.int32),
-        interpret=interpret,
-    )(d)
-
 
 def _masked_hist(digits: jnp.ndarray, active: jnp.ndarray, radix: int,
                  tile: int, interpret: Optional[bool]) -> jnp.ndarray:
     """(rows, n) digits + active mask -> (rows, radix) active-only counts
-    on the per-tile Pallas kernel: inactive slots carry digit ``radix``,
-    counted into a throwaway column (the bucket_bounds pad trick)."""
+    on the per-tile Pallas kernel: inactive and pad slots carry the digit
+    ``radix``, which the kernel does not count."""
     rows, n = digits.shape
     d = jnp.where(active, digits, radix)
-    tile = min(tile, max(8, n))
+    tile = _rs.lane_tile(n, tile)
     m = -(-n // tile) * tile
     if m != n:
         d = jnp.pad(d, ((0, 0), (0, m - n)), constant_values=radix)
     interp = _interpret_default() if interpret is None else interpret
-    hist = _tile_hist(d.reshape(rows * (m // tile), tile), radix + 1, interp)
-    return jnp.sum(hist.reshape(rows, m // tile, radix + 1), axis=1)[:, :radix]
+    hist = _rs.tile_hist(d.reshape(rows * (m // tile), tile), radix, interp)
+    return jnp.sum(hist.reshape(rows, m // tile, radix), axis=1)
 
 
 # ---------------------------------------------------------------------------

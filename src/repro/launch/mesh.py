@@ -6,6 +6,13 @@ never touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    # Auto axes: the placement rules are sharding constraints, which
+    # Explicit axes (jax.make_mesh's default) reject
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,13 +22,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Whatever this host offers, as a 1-D data mesh (tests / examples)."""
     n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return _auto_mesh((n,), ("data",))
 
 
 def dp_axes_of(mesh) -> tuple:

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ShapeSpec
+from repro.core.topology import auto_mesh, to_auto_mesh
 from repro.models.model_zoo import Model
 from repro.optim import optimizers as opt_lib
 from repro.sharding.partitioning import ShardingPolicy
@@ -118,6 +119,7 @@ def abstract_init(model: Model, key):
 def shardings_of(tree_specs, mesh: Optional[Mesh]):
     if mesh is None:
         return None
+    mesh = auto_mesh(mesh)
     return jax.tree.map(lambda s: NamedSharding(mesh, s), tree_specs,
                         is_leaf=lambda x: isinstance(x, P))
 
@@ -157,13 +159,22 @@ def build_train_step(model: Model, optimizer: opt_lib.Optimizer,
         return loss, aux
 
     def train_step(params, opt_state, step, batch):
+        if policy is not None and policy.mesh is not None:
+            # inputs placed on an Explicit-axes mesh join the policy's
+            # Auto-typed one (the model's placement rules are constraints)
+            batch = jax.tree.map(lambda x: to_auto_mesh(x, policy.mesh),
+                                 batch)
         if microbatch > 1:
+            # microbatch i takes rows i, i + microbatch, ...: the batch
+            # dimension's data sharding stays on the per-microbatch rows
+            # (every device works in every microbatch), and the scanned
+            # microbatch axis stays unsharded on Explicit-axes meshes
             def split(x):
-                b = x.shape[0]
                 if x.ndim >= 2 and x.shape[0] == 3:   # (3,B,S) positions
                     return jnp.moveaxis(
-                        x.reshape(3, microbatch, -1, *x.shape[2:]), 1, 0)
-                return x.reshape(microbatch, b // microbatch, *x.shape[1:])
+                        x.reshape(3, -1, microbatch, *x.shape[2:]), 2, 0)
+                return jnp.moveaxis(
+                    x.reshape(-1, microbatch, *x.shape[1:]), 1, 0)
 
             mbs = jax.tree.map(split, batch)
 

@@ -36,6 +36,11 @@ class ShardingPolicy:
     # KV cache sequence-sharded — removes the per-layer TP all-reduces that
     # dominate the prefill roofline (EXPERIMENTS.md §Perf iC.2)
 
+    def __post_init__(self):
+        if self.mesh is not None:
+            from repro.core.topology import auto_mesh
+            object.__setattr__(self, "mesh", auto_mesh(self.mesh))
+
     # ------------------------------------------------------------- helpers
     @property
     def tp_size(self) -> int:

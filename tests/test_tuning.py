@@ -146,10 +146,10 @@ def test_kernels_consume_profile_digit_bits():
     """Swap in digit_bits=4 and the radix kernels must run 8 passes (visible
     via pass_tile_counts) and still sort correctly."""
     tuning.set_active(dataclasses.replace(tuning.active(), digit_bits=4,
-                                          radix_tile=64))
+                                          radix_tile=128))
     passes, tiles = radix_sort.pass_tile_counts(1000, np.uint32)
     assert passes == 8                      # 32 bits / 4 per pass
-    assert tiles == -(-1000 // 64)
+    assert tiles == -(-1000 // 128)
     x = np.random.default_rng(0).integers(0, 2**32, (2, 500),
                                           dtype=np.uint32)
     out = np.asarray(radix_sort.sort_blocks(jnp.asarray(x)))
