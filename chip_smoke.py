@@ -11,16 +11,17 @@ checks every result bit-exactly against an independent reference
 numpy on the host).  Data is drawn from ``--seed`` on the device.
 
 Each phase prints one line: the operation, its size and dtype, the
-planner's method, whether it matched, a warm wall time (a smoke timing of
-one call, not a benchmark) and the device's peak memory.  The last line is
-one JSON object naming the device.  A phase that does not match, or any
-error, ends the run with a non-zero exit code; so does a machine whose
-first JAX device is not a TPU.
+planner's method, whether it matched, a wall time (a smoke timing of one
+call, not a benchmark) and the device's peak memory.  One-chip phases time
+a warm second call; mesh phases time their single first call, compilation
+included.  The last line is one JSON object naming the device.  A phase
+that does not match, or any error, ends the run with a non-zero exit code;
+so does a machine whose first JAX device is not a TPU.
 
 ``--chips 4`` runs only the mesh phases over ``jax.make_mesh((4,),
-("data",))``: sort, sort_kv, top-k, unique and group-by of 2^28 int32 keys
-(256 MiB per chip), each checked against numpy and for output shards on
-four distinct devices.
+("data",))``: sort, unique, sort_kv, group-by and top-k of 2^28 int32
+keys (256 MiB per chip), each checked against numpy and for output shards
+on four distinct devices.
 """
 from __future__ import annotations
 
@@ -38,6 +39,7 @@ N_LARGE = 1 << 26            # 256 MiB of 32-bit keys
 BATCH_SHAPE = (64, 1 << 16)  # rows at the Pallas network's width cap
 TOPK_SHAPE = (128, 1 << 18)  # sampling over a vocabulary slice
 TOPK_K = 64
+TOPK_PALLAS_SHAPE = (128, 1 << 15)   # rows the Pallas network holds whole
 N_REL = 1 << 24
 N_EXPLICIT = 1 << 22
 # four chips
@@ -77,18 +79,25 @@ class Smoke:
         self.key, k = jax.random.split(self.key)
         return k
 
-    def phase(self, op: str, shape, dtype, method: str, fn, check) -> None:
-        """Run ``fn`` (compile + first call), check it, time a warm call."""
+    def phase(self, op: str, shape, dtype, method: str, fn, check,
+              warm: bool = True) -> None:
+        """Run ``fn`` (compile + first call) and check it; with ``warm``
+        time a second call, else report the first call's time."""
         import jax
+        t0 = time.perf_counter()
         out = jax.block_until_ready(fn())
+        wall = time.perf_counter() - t0
         ok = bool(check(out))
         del out
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn())
-        wall = time.perf_counter() - t0
+        if warm:
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn())
+            wall = time.perf_counter() - t0
         stats = self.dev.memory_stats() or {}
+        label = "smoke_wall_s" if warm else \
+            "smoke_first_call_s (compilation included)"
         print(f"phase op={op} shape={tuple(shape)} dtype={dtype} "
-              f"method={method} ok={ok} smoke_wall_s={wall} "
+              f"method={method} ok={ok} {label}={wall} "
               f"(smoke timing, not a benchmark) "
               f"peak_bytes_in_use={stats.get('peak_bytes_in_use')}",
               flush=True)
@@ -199,11 +208,17 @@ def one_chip(s: Smoke) -> None:
     sort_phases(s, BATCH_SHAPE)
     topk_phase(s, TOPK_SHAPE, jnp.bfloat16, TOPK_K)
     relational_phases(s, N_REL)
-    # every kernel-backed method by name, so none is bypassed unseen
-    sort_phases(s, BATCH_SHAPE, method="pallas", ops=("sort",),
-                dtypes=("float32",))
-    topk_phase(s, (128, 1 << 15), jnp.float32, TOPK_K, method="pallas")
-    topk_phase(s, TOPK_SHAPE, jnp.float32, TOPK_K, method="select")
+    # every kernel-backed method by name, so none is bypassed unseen, and
+    # the xla backend beside them at the shapes auto chose for above
+    for method in ("xla", "radix"):
+        sort_phases(s, (N_LARGE,), method=method, ops=("sort",),
+                    dtypes=("float32",))
+    for method in ("xla", "radix", "pallas"):
+        sort_phases(s, BATCH_SHAPE, method=method, ops=("sort",),
+                    dtypes=("float32",))
+    for method in ("xla", "select"):
+        topk_phase(s, TOPK_SHAPE, jnp.bfloat16, TOPK_K, method=method)
+    topk_phase(s, TOPK_PALLAS_SHAPE, jnp.float32, TOPK_K, method="pallas")
     sort_phases(s, (N_EXPLICIT,), method="radix", ops=("sort_kv",),
                 dtypes=("int32",))
     sort_phases(s, (N_EXPLICIT,), method="merge", ops=("sort", "argsort"),
@@ -241,8 +256,10 @@ def mesh_phases(s: Smoke) -> None:
     uniq = np.nonzero(counts)[0].astype(np.int32)
     ref_sorted = np.repeat(np.arange(MESH_KEY_RANGE, dtype=np.int32), counts)
     sums = np.bincount(hk, weights=hv, minlength=MESH_KEY_RANGE)[uniq]
-    # lax.top_k order: values descending, ties by ascending index
-    kth = np.partition(hk, n - TOPK_K)[n - TOPK_K]
+    # lax.top_k order: values descending, ties by ascending index; the
+    # k-th largest key read off the counts, then one pass for candidates
+    desc = uniq[::-1]
+    kth = desc[np.searchsorted(np.cumsum(counts[desc]), TOPK_K)]
     cand = np.nonzero(hk >= kth)[0]
     top_i = cand[np.lexsort((cand, -hk[cand].astype(np.int64)))][:TOPK_K]
     strategy = planner.choose_distributed_cached(n, 4, jnp.int32).strategy
@@ -279,18 +296,22 @@ def mesh_phases(s: Smoke) -> None:
                 and np.array_equal(np.asarray(g.keys[:m]), uniq)
                 and np.array_equal(np.asarray(g.aggregates[0][:m]), sums))
 
+    # one call each, timed with its compilation: a mesh sort of 2^28 keys
+    # runs for tens of seconds.  unique and group_by reuse the programs
+    # the sort and sort_kv calls compiled.
     shape = (n,)
-    s.phase("sort", shape, "int32", method,
-            lambda: rsort.sort(keys, mesh=mesh), sort_ok)
-    s.phase("sort_kv", shape, "int32", method,
-            lambda: rsort.sort_kv(keys, payload, mesh=mesh), sort_kv_ok)
+    for op, fn, check in (
+            ("sort", lambda: rsort.sort(keys, mesh=mesh), sort_ok),
+            ("unique", lambda: relational.unique(keys, mesh=mesh),
+             unique_ok),
+            ("sort_kv", lambda: rsort.sort_kv(keys, payload, mesh=mesh),
+             sort_kv_ok),
+            ("group_by",
+             lambda: relational.group_by(keys, vals, agg="sum", mesh=mesh),
+             group_ok)):
+        s.phase(op, shape, "int32", method, fn, check, warm=False)
     s.phase("topk", shape, "int32", "distributed/candidates",
-            lambda: rsort.topk(keys, TOPK_K, mesh=mesh), topk_ok)
-    s.phase("unique", shape, "int32", method,
-            lambda: relational.unique(keys, mesh=mesh), unique_ok)
-    s.phase("group_by", shape, "int32", method,
-            lambda: relational.group_by(keys, vals, agg="sum", mesh=mesh),
-            group_ok)
+            lambda: rsort.topk(keys, TOPK_K, mesh=mesh), topk_ok, warm=False)
 
 
 # ---------------------------------------------------------------------------

@@ -58,8 +58,18 @@ def bitonic_merge_halves(lo_sorted: jnp.ndarray, hi_sorted: jnp.ndarray):
     ``sort_api.bitonic_sort``), while the reshape view compiles flat.
     """
     m = lo_sorted.shape[-1]
+    p = 1 << max(0, (m - 1).bit_length())
+    if p != m:
+        # the merge box needs a power-of-two length: pad both runs with
+        # the dtype's top key, which keeps them sorted and sorts last
+        dt = lo_sorted.dtype
+        top = jnp.array(jnp.inf if jnp.issubdtype(dt, jnp.floating)
+                        else jnp.iinfo(dt).max, dt)
+        pad = [(0, 0)] * (lo_sorted.ndim - 1) + [(0, p - m)]
+        lo_sorted = jnp.pad(lo_sorted, pad, constant_values=top)
+        hi_sorted = jnp.pad(hi_sorted, pad, constant_values=top)
     z = jnp.concatenate([lo_sorted, jnp.flip(hi_sorted, -1)], axis=-1)
-    n = 2 * m
+    n = 2 * p
     lead = z.shape[:-1]
     j = n // 2
     while j >= 1:
@@ -68,7 +78,7 @@ def bitonic_merge_halves(lo_sorted: jnp.ndarray, hi_sorted: jnp.ndarray):
         z = jnp.stack([jnp.minimum(lo, hi), jnp.maximum(lo, hi)],
                       axis=-2).reshape(*lead, n)
         j //= 2
-    return z[..., :m], z[..., m:]
+    return z[..., :m], z[..., m:2 * m]
 
 
 def _round_permutation(n_dev: int, even_round: bool):

@@ -142,6 +142,17 @@ class DeviceSortConstants:
     host_merge_level: float = 8.0
 
 
+# TPU seeds: leading constants fitted to warm single calls on one TPU v5e
+# (JAX 0.9.0).  xla: jnp.sort of 64 x 65536 f32 rows, 5.58 ms; pallas:
+# the bitonic network on the same rows, 11.4 ms; radix: 2^26 f32 keys,
+# 9.07 s; select: top-64 of 128 x 2^18 bf16 rows, 0.220 s.  A 2^26 jnp.sort
+# took 1.27 s, 9x what the n log n model predicts from the small-row fit,
+# but still the cheapest plan at that size.  The merge constants keep their
+# host seeds (not measured on a chip yet).
+TPU_CONSTANTS = DeviceSortConstants(xla=0.0832, pallas=0.0106, radix=33.8,
+                                    select=3.28)
+
+
 class ProfileError(ValueError):
     """A persisted profile that cannot be trusted: wrong schema version,
     malformed JSON, or field values outside the validated ranges."""
@@ -179,8 +190,10 @@ class TuningProfile:
             raise ProfileError(
                 f"digit_bits must be one of {_VALID_DIGIT_BITS}, "
                 f"got {self.digit_bits}")
-        if self.radix_tile < 8:
-            raise ProfileError(f"radix_tile too small: {self.radix_tile}")
+        if self.radix_tile < 128 or self.radix_tile % 128:
+            # the radix kernels tile rows in whole 128-lane vector rows
+            raise ProfileError(f"radix_tile must be a positive multiple of "
+                               f"128, got {self.radix_tile}")
         if self.run_len < 2:
             raise ProfileError(f"run_len too small: {self.run_len}")
         if self.capacity_slack < 1.0:
@@ -260,6 +273,7 @@ def default_profile() -> TuningProfile:
     tpu = jax.default_backend() == "tpu"
     return TuningProfile(
         fingerprint=device_fingerprint(),
+        constants=TPU_CONSTANTS if tpu else DeviceSortConstants(),
         run_len=DEFAULT_RUN_LEN if tpu else DEFAULT_CPU_RUN_LEN,
         source="default")
 

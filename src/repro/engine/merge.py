@@ -6,7 +6,7 @@ Each level is O(n) ranking work, so the whole tree is O(n log(n/run_len)) on
 top of the O(n log run_len) run generation — the O(n log n) total that the
 whole-array bitonic network (O(n log^2 n) CAS count) cannot reach.
 
-Three interchangeable merge backends:
+Four interchangeable merge backends:
 
   ``xla``     rank merge in pure jnp: each element's output position is its
               own index plus a binary-searched cross-rank in the partner run
@@ -20,9 +20,15 @@ Three interchangeable merge backends:
               it as its interpret-mode merge.  Needs power-of-two run
               lengths and is NOT stable (ties follow a consistent
               left-wins predicate, payloads stay attached to their keys).
+  ``sort``    XLA's stable sort of the concatenated runs.  O(n log n)
+              work, but no gathers: on a TPU v5e a random gather of 2^26
+              elements takes about a second, so the ranking merges lose to
+              it there (merging four sorted runs of 2^24 int32 keys with
+              an int32 payload: merge path 13.2 s; a two-operand stable
+              sort of 2^26 int32 key/payload pairs: 0.26 s).
 
-``xla``/``pallas`` are ascending-stable (left run wins ties); descending
-merges flip in, merge ascending, flip out.  Key-value variants carry an
+``xla``/``pallas``/``sort`` are ascending-stable (left run wins ties);
+descending merges flip in, merge ascending, flip out.  Key-value variants carry an
 int payload for argsort / top-k.
 """
 from __future__ import annotations
@@ -34,7 +40,7 @@ import jax.numpy as jnp
 
 from repro.engine import runs as _runs
 
-MERGE_BACKENDS = ("xla", "pallas", "bitonic")
+MERGE_BACKENDS = ("xla", "pallas", "bitonic", "sort")
 
 
 def _vsearch(sorted_rows: jnp.ndarray, queries: jnp.ndarray, side: str):
@@ -110,6 +116,10 @@ def merge_pairs(a: jnp.ndarray, b: jnp.ndarray, *, descending: bool = False,
         raise ValueError(
             f"merge backend must be one of {MERGE_BACKENDS}, got {backend!r}")
     va, vb = values
+    if backend in ("pallas", "sort"):
+        return _merge_flat(jnp.stack([a, b], 1),
+                           None if va is None else jnp.stack([va, vb], 1),
+                           descending, interpret, backend)
     if descending:
         # flip to ascending AND swap the pair: the ascending merge's
         # left-wins-ties rule turns into right-wins after the final flip,
@@ -118,14 +128,7 @@ def merge_pairs(a: jnp.ndarray, b: jnp.ndarray, *, descending: bool = False,
         a, b = jnp.flip(b, -1), jnp.flip(a, -1)
         va, vb = (None if vb is None else jnp.flip(vb, -1),
                   None if va is None else jnp.flip(va, -1))
-    if backend == "pallas":
-        from repro.kernels import merge_path as _mp
-        if va is None:
-            out, vout = _mp.merge_pairs_blocks(a, b, interpret=interpret), None
-        else:
-            out, vout = _mp.merge_pairs_kv_blocks(a, b, va, vb,
-                                                  interpret=interpret)
-    elif backend == "bitonic":
+    if backend == "bitonic":
         out, vout = _bitonic_box_merge(a, b, va, vb)
     else:
         out, vout = _rank_merge(a, b, va, vb)
@@ -147,8 +150,8 @@ def merge_runs(run_keys: jnp.ndarray, run_vals: Optional[jnp.ndarray] = None,
     rows, r, l = run_keys.shape
     if r & (r - 1):
         raise ValueError(f"run count must be a power of two, got {r}")
-    if backend == "pallas":
-        return _merge_runs_flat(run_keys, run_vals, descending, interpret)
+    if backend in ("pallas", "sort"):
+        return _merge_flat(run_keys, run_vals, descending, interpret, backend)
     keys, vals = run_keys, run_vals
     while r > 1:
         kv = keys.reshape(rows * (r // 2), 2, l)
@@ -171,10 +174,11 @@ def merge_runs(run_keys: jnp.ndarray, run_vals: Optional[jnp.ndarray] = None,
     return keys, vals.reshape(rows, l)
 
 
-def _merge_runs_flat(run_keys, run_vals, descending, interpret):
-    """``merge_runs`` on the merge-path kernel, with every level on the
-    flat array (adjacent runs are the pairs), so no level materialises a
-    few-row, very wide array.  Descending flips the whole array once:
+def _merge_flat(run_keys, run_vals, descending, interpret, backend):
+    """``merge_runs`` (and ``merge_pairs``, as two runs per row) on the
+    flat array: level by level on the merge-path kernel (adjacent runs are
+    the pairs), so no level materialises a few-row, very wide array; or as
+    one stable sort of each row.  Descending flips the whole array once:
     that reverses every run and the run order, so the ascending merge's
     left-wins-ties rule still keeps the earlier run first after the flip
     back."""
@@ -185,6 +189,13 @@ def _merge_runs_flat(run_keys, run_vals, descending, interpret):
     if descending:
         keys = jnp.flip(keys)
         vals = None if vals is None else jnp.flip(vals)
+    if backend == "sort":
+        ops = (keys.reshape(rows, r * l),) if vals is None \
+            else (keys.reshape(rows, r * l), vals.reshape(rows, r * l))
+        out = jax.lax.sort(ops, dimension=1, num_keys=1, is_stable=True)
+        keys = out[0].reshape(-1)
+        vals = None if vals is None else out[1].reshape(-1)
+        r, l = 1, r * l
     while r > 1:
         if vals is None:
             keys = _mp.merge_flat(keys, l=l, interpret=interpret)

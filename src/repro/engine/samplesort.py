@@ -197,9 +197,10 @@ def _pick_merge_backend(run_len: int) -> str:
     """Default merge backend for runs of ``run_len`` slots (the same rule
     the flat path always used, parameterised so each hierarchical phase
     picks for its own capacity)."""
-    from repro.kernels.merge_path import DEFAULT_CHUNK
-    if jax.default_backend() == "tpu" and (2 * run_len) % DEFAULT_CHUNK == 0:
-        return "pallas"             # the merge-path VMEM kernel
+    if jax.default_backend() == "tpu":
+        # a stable sort of the received runs: the ranking merges are
+        # gather-bound there (engine/merge.py)
+        return "sort"
     if run_len & (run_len - 1) == 0:
         # off-TPU the gather-bound rank merge loses badly to the
         # word-parallel min/max box (capacities are pow2-rounded, so this
@@ -396,7 +397,7 @@ def _phase1(mesh: Mesh, axes: Tuple[str, ...], part_axes: Tuple[str, ...],
 
 
 # ---------------------------------------------------------------------------
-# flat phase 2: bucket exchange + merge-path merge + rank rebalance
+# flat phase 2: bucket exchange + merge of the received runs + rebalance
 # ---------------------------------------------------------------------------
 
 @functools.lru_cache(maxsize=128)

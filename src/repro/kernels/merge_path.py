@@ -31,7 +31,7 @@ descending merges.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -131,29 +131,6 @@ def merge_flat(z: jnp.ndarray, values: Optional[jnp.ndarray] = None, *,
         zw, origin, (window_row(values, jnp.zeros((), values.dtype)),),
         interpret=interpret)
     return mk[:, :c].reshape(-1), mv[:, :c].reshape(-1)
-
-
-def merge_pairs_blocks(a: jnp.ndarray, b: jnp.ndarray, *,
-                       chunk: int = DEFAULT_CHUNK,
-                       interpret: Optional[bool] = None) -> jnp.ndarray:
-    """Merge row-wise sorted (rows, L) + (rows, L) -> (rows, 2L), ascending."""
-    rows, l = a.shape
-    z = jnp.concatenate([a, b], axis=-1).reshape(-1)
-    return merge_flat(z, l=l, chunk=chunk,
-                      interpret=interpret).reshape(rows, 2 * l)
-
-
-def merge_pairs_kv_blocks(a: jnp.ndarray, b: jnp.ndarray,
-                          va: jnp.ndarray, vb: jnp.ndarray, *,
-                          chunk: int = DEFAULT_CHUNK,
-                          interpret: Optional[bool] = None
-                          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Key-value variant: payloads ride along their keys through the merge."""
-    rows, l = a.shape
-    z = jnp.concatenate([a, b], axis=-1).reshape(-1)
-    v = jnp.concatenate([va, vb], axis=-1).reshape(-1)
-    mk, mv = merge_flat(z, v, l=l, chunk=chunk, interpret=interpret)
-    return mk.reshape(rows, 2 * l), mv.reshape(rows, 2 * l)
 
 
 def _max_key(dtype):

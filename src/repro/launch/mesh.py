@@ -6,13 +6,8 @@ never touches jax device state.
 from __future__ import annotations
 
 import jax
-from jax.sharding import AxisType
 
-
-def _auto_mesh(shape, axes):
-    # Auto axes: the placement rules are sharding constraints, which
-    # Explicit axes (jax.make_mesh's default) reject
-    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+from repro.core.topology import auto_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,13 +17,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _auto_mesh(shape, axes)
+    return auto_mesh(jax.make_mesh(shape, axes))
 
 
 def make_host_mesh():
     """Whatever this host offers, as a 1-D data mesh (tests / examples)."""
     n = len(jax.devices())
-    return _auto_mesh((n,), ("data",))
+    return auto_mesh(jax.make_mesh((n,), ("data",)))
 
 
 def dp_axes_of(mesh) -> tuple:

@@ -3,15 +3,16 @@ primitives, planner resolution, and obs plumbing.
 
 Every op in this package is (sort via the front door) + (an O(n) scan /
 searchsorted post-pass on the sorted column).  The post-passes here are
-scatter-free where possible: compaction uses the cumulative-count
-searchsorted trick (XLA:CPU serializes scatters; a binary-search gather
-vectorizes), mirroring the survivor-compaction idiom in
-``kernels/radix_select.py``.
+scatter-free where possible (XLA:CPU serializes scatters): compaction is
+a stable partition by one sort, which also avoids the random gathers a
+TPU runs slowly.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.relational.relspec import RelSpec, SORT_OPS, STABLE_OPS
@@ -33,25 +34,93 @@ def boundary_mask(s: jnp.ndarray) -> jnp.ndarray:
 
 def compact_sorted(s: jnp.ndarray, mask: jnp.ndarray
                    ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Gather the masked (first-of-run) elements of a sorted column to the
-    front WITHOUT a scatter -> (compacted, n_valid, segment_ids).
+    """Move the masked (first-of-run) elements of a column to the front,
+    in order, WITHOUT a scatter -> (compacted, n_valid, segment_ids).
 
-    ``compacted`` is (n,) with the distinct values ascending in the first
-    ``n_valid`` slots; the tail repeats the maximum value, so the array
-    stays globally non-decreasing (searchsorted-safe — ``inverse`` and the
-    distributed post-pass both rely on this).  ``segment_ids[i]`` is the
-    0-based run id of sorted position i.
+    ``compacted`` is (n,) with the masked elements in the first
+    ``n_valid`` slots; the tail repeats the last element (the maximum of a
+    sorted column, so the array stays globally non-decreasing —
+    searchsorted-safe; ``inverse`` and the distributed post-pass both rely
+    on this).  ``segment_ids[i]`` is the 0-based run id of position i.
+
+    The compaction is one stable two-operand sort keyed on the mask, not
+    a searchsorted gather: on a TPU a random gather of 2^24 elements takes
+    about a quarter of a second and a searchsorted runs log2(n) of them,
+    and over a mesh each of those gathers reads the whole column.
     """
     n = s.shape[0]
     csum = jnp.cumsum(mask.astype(jnp.int32))
-    n_valid = csum[-1] if n else jnp.zeros((), jnp.int32)
-    # slot j holds the first sorted position whose cumulative run count
-    # reaches j+1; past the valid prefix searchsorted answers n -> clipped
-    # to the maximum element
-    src = jnp.searchsorted(csum, jnp.arange(1, n + 1, dtype=jnp.int32),
-                           side="left")
-    compacted = s[jnp.clip(src, 0, max(n - 1, 0))]
+    if n == 0:
+        return s, jnp.zeros((), jnp.int32), csum - 1
+    n_valid = csum[-1]
+    _, front = jax.lax.sort((jnp.where(mask, 0, 1).astype(jnp.int32), s),
+                            num_keys=1, is_stable=True)
+    compacted = jnp.where(jnp.arange(n, dtype=jnp.int32) < n_valid, front,
+                          s[-1])
     return compacted, n_valid, csum - 1
+
+
+def compact(spec: RelSpec, s: jnp.ndarray, mask: jnp.ndarray
+            ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """``compact_sorted``, run shard by shard when ``spec`` puts the
+    column on a mesh that splits it evenly: the global program would
+    gather and sort the whole column on every device."""
+    if spec.mesh is None:
+        return compact_sorted(s, mask)
+    from repro.core.topology import auto_mesh
+    from repro.engine import samplesort
+    mesh = auto_mesh(spec.mesh)
+    axes = samplesort._axes_tuple(mesh, spec.axis_name)
+    if s.shape[0] % samplesort._n_dev(mesh, axes):
+        return compact_sorted(s, mask)
+    return _compact_program(mesh, axes)(s, mask)
+
+
+@functools.lru_cache(maxsize=32)
+def _compact_program(mesh, axes):
+    """Jitted shard_map: each device moves its first-of-run elements to
+    the front of its shard (one stable sort), and one all-to-all sends
+    them to their global slots, which are contiguous ranges of the
+    devices' shards.  Slots are combined by summing bit patterns, each
+    filled by exactly one source, so every value (-0.0 too) is exact."""
+    from jax.sharding import PartitionSpec as P
+    from repro.engine import collectives as coll
+    from repro.engine import samplesort
+    d = samplesort._n_dev(mesh, axes)
+    ax = samplesort._coll_axis(axes)
+
+    def local(s, mask):
+        m = s.shape[0]
+        my = samplesort._lin_index(mesh, axes)
+        flags = mask.astype(jnp.int32)
+        cnt = jnp.sum(flags)
+        counts = jax.lax.all_gather(cnt, ax).reshape(-1)           # (d,)
+        offset = jnp.sum(jnp.where(jnp.arange(d) < my, counts, 0))
+        n_valid = jnp.sum(counts)
+        seg = offset + samplesort._running_count(mask) - 1
+        _, front = jax.lax.sort((1 - flags, s), num_keys=1, is_stable=True)
+        bits = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32}[s.dtype.itemsize]
+        front = jax.lax.bitcast_convert_type(front, bits)
+        zeros = jnp.zeros((m,), bits)
+        padded = jnp.concatenate([zeros, front, zeros])
+        # front[j] goes to global slot offset + j: device t's slot i reads
+        # j = t*m - offset + i; windows that dynamic_slice would clamp hold
+        # no valid j at all
+        sends = []
+        for t in range(d):
+            j = t * m - offset + jnp.arange(m, dtype=jnp.int32)
+            win = jax.lax.dynamic_slice(padded, (m + t * m - offset,), (m,))
+            sends.append(jnp.where((j >= 0) & (j < cnt), win, zeros))
+        out = jnp.sum(coll.all_to_all(jnp.stack(sends), ax), axis=0,
+                      dtype=bits)
+        out = jax.lax.bitcast_convert_type(out, s.dtype)
+        last = jax.lax.all_gather(s[-1], ax).reshape(-1)[-1]
+        slot = my * m + jnp.arange(m, dtype=jnp.int32)
+        return jnp.where(slot < n_valid, out, last), n_valid, seg
+
+    spec = P(axes)
+    return jax.jit(samplesort._smap(local, mesh, (spec, spec),
+                                    (spec, P(), spec)))
 
 
 def pad_tail(arr: jnp.ndarray, n_valid: jnp.ndarray, fill) -> jnp.ndarray:
@@ -146,6 +215,6 @@ def stable_order(x: jnp.ndarray, method: Optional[str],
     return rsort.argsort(x, stable=True, method=method, interpret=interpret)
 
 
-__all__ = ["boundary_mask", "compact_sorted", "pad_tail", "resolve_plan",
+__all__ = ["boundary_mask", "compact", "compact_sorted", "pad_tail", "resolve_plan",
            "span", "finish", "sorted_column", "stable_order",
            "SORT_OPS", "STABLE_OPS"]

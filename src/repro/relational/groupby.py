@@ -87,7 +87,7 @@ def run(spec: RelSpec, keys: jnp.ndarray, values: jnp.ndarray) -> GroupBy:
         # (the stable local pipeline just fixes the summation order)
         sk, sv = _core.sorted_column(spec, keys, method, values=values)
         mask = _core.boundary_mask(sk)
-        ukeys, n_groups, seg = _core.compact_sorted(sk, mask)
+        ukeys, n_groups, seg = _core.compact(spec, sk, mask)
         aggs = _aggregate(sv, seg, n, spec.agg, n_groups, spec.fill_value)
         out = GroupBy(keys=_core.pad_tail(ukeys, n_groups, spec.fill_value),
                       n_groups=n_groups, aggregates=aggs)

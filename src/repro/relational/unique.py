@@ -42,7 +42,7 @@ def run(spec: RelSpec, x: jnp.ndarray) -> Unique:
     with sp:
         s = _core.sorted_column(spec, x, method)
         mask = _core.boundary_mask(s)
-        uvals, n_unique, _ = _core.compact_sorted(s, mask)
+        uvals, n_unique, _ = _core.compact(spec, s, mask)
         inverse = counts = None
         if spec.return_inverse or spec.return_counts:
             # uvals is non-decreasing (tail repeats the max), and every

@@ -83,6 +83,20 @@ def test_bitonic_merge_halves():
     np.testing.assert_array_equal(np.array(out_hi), ref[32:])
 
 
+@pytest.mark.parametrize("m,dtype", [(192, np.int32), (3, np.float32),
+                                     (100, np.uint32)])
+def test_bitonic_merge_halves_non_power_of_two(m, dtype):
+    """Shards of a non-power-of-two length merge exactly (the odd-even
+    mesh sort of 768 keys over 4 devices runs 192-key halves)."""
+    rng = np.random.default_rng(m)
+    lo = np.sort(rng.integers(0, 50, m).astype(dtype))
+    hi = np.sort(rng.integers(0, 50, m).astype(dtype))
+    out_lo, out_hi = ds.bitonic_merge_halves(jnp.asarray(lo), jnp.asarray(hi))
+    ref = np.sort(np.concatenate([lo, hi]))
+    np.testing.assert_array_equal(np.array(out_lo), ref[:m])
+    np.testing.assert_array_equal(np.array(out_hi), ref[m:])
+
+
 # ---------------------------------------------------------------------------
 # bitonic kv tie-break
 # ---------------------------------------------------------------------------

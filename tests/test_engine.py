@@ -138,7 +138,7 @@ def test_engine_topk_matches_lax(n, k):
 # merge primitives (both backends)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("backend", ["xla", "pallas"])
+@pytest.mark.parametrize("backend", ["xla", "pallas", "sort"])
 @pytest.mark.parametrize("l", [64, 256, 1024])
 def test_merge_pairs_backends_agree_with_np(backend, l):
     rng = np.random.default_rng(l)
@@ -150,7 +150,7 @@ def test_merge_pairs_backends_agree_with_np(backend, l):
     np.testing.assert_array_equal(out, ref)
 
 
-@pytest.mark.parametrize("backend", ["xla", "pallas"])
+@pytest.mark.parametrize("backend", ["xla", "pallas", "sort"])
 def test_merge_pairs_kv_payloads_follow_keys(backend):
     rng = np.random.default_rng(23)
     a = np.sort(rng.standard_normal((2, 128)).astype(np.float32), -1)
@@ -164,6 +164,23 @@ def test_merge_pairs_kv_payloads_follow_keys(backend):
     np.testing.assert_array_equal(k, np.sort(np.concatenate([a, b], -1), -1))
     both = np.concatenate([a, b], -1)
     np.testing.assert_array_equal(np.take_along_axis(both, v, -1), k)
+
+
+@pytest.mark.parametrize("descending", [False, True])
+@pytest.mark.parametrize("backend", ["pallas", "sort"])
+def test_merge_runs_stable_ties_match_rank_merge(backend, descending):
+    """Stable backends agree on tie order: earlier runs first, both ways."""
+    rng = np.random.default_rng(31)
+    runs = np.sort(rng.integers(0, 8, (2, 4, 64)).astype(np.int32), -1)
+    if descending:
+        runs = runs[..., ::-1].copy()
+    pos = np.arange(2 * 4 * 64, dtype=np.int32).reshape(2, 4, 64)
+    ref = engine_merge.merge_runs(jnp.asarray(runs), jnp.asarray(pos),
+                                  descending=descending, backend="xla")
+    out = engine_merge.merge_runs(jnp.asarray(runs), jnp.asarray(pos),
+                                  descending=descending, backend=backend)
+    for o, r in zip(out, ref):
+        np.testing.assert_array_equal(np.array(o), np.array(r))
 
 
 def test_merge_pairs_pallas_extreme_values():

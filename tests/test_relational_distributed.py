@@ -35,6 +35,9 @@ def _cases():
         np.full(512, 7, np.int32),                      # all-equal
         np.where(rng.random(777) < 0.4, -0.0,
                  rng.integers(0, 9, 777)).astype(np.float32),  # signed zeros
+        # an even split over the mesh: the per-shard compaction path
+        np.where(rng.random(768) < 0.3, -0.0,
+                 rng.integers(-3, 600, 768)).astype(np.float32),
     ]
 
 

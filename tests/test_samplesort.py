@@ -113,6 +113,25 @@ def test_sample_sort_kv_uneven_extreme_keys(descending):
     assert len(set(sv.tolist())) == v.size       # a true permutation
 
 
+@pytest.mark.parametrize("descending", [False, True])
+def test_sample_sort_kv_sort_merge_backend(descending):
+    """The TPU default merge (a stable sort of the received runs) keeps
+    payloads on their keys and pads behind genuine dtype-max keys."""
+    rng = np.random.default_rng(19)
+    k = rng.integers(0, 4, 333).astype(np.int32)
+    k[k == 3] = np.iinfo(np.int32).max
+    v = np.arange(333, dtype=np.int32)
+    sk, sv = samplesort.sample_sort(jnp.asarray(k), _mesh(),
+                                    values=jnp.asarray(v),
+                                    descending=descending,
+                                    merge_backend="sort")
+    sk, sv = np.asarray(sk), np.asarray(sv)
+    ref = np.sort(k)
+    np.testing.assert_array_equal(sk, np.flip(ref) if descending else ref)
+    np.testing.assert_array_equal(k[sv], sk)
+    assert len(set(sv.tolist())) == v.size
+
+
 @pytest.mark.parametrize("dtype", sorted(keycodec.SUPPORTED))
 def test_sample_sort_every_codec_dtype(dtype):
     rng = np.random.default_rng(29)

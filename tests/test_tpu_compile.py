@@ -52,14 +52,14 @@ def _topk(x):
     return bs.topk_blocks(x, 64, interpret=False)
 
 
-def _merge(a, b):
+def _merge(z):
     from repro.kernels import merge_path as mp
-    return mp.merge_pairs_blocks(a, b, interpret=False)
+    return mp.merge_flat(z, l=z.shape[0] // 2, interpret=False)
 
 
-def _merge_kv(a, b, va, vb):
+def _merge_kv(z, v):
     from repro.kernels import merge_path as mp
-    return mp.merge_pairs_kv_blocks(a, b, va, vb, interpret=False)
+    return mp.merge_flat(z, v, l=z.shape[0] // 2, interpret=False)
 
 
 def _radix(x):
@@ -94,10 +94,10 @@ CASES = {
     "bitonic-kv-1x65536-u32": (_bitonic_kv, [((1, 65536), "uint32"),
                                              ((1, 65536), "int32")]),
     "bitonic-topk-8x2048-k64": (_topk, [((8, 2048), "float32")]),
-    "merge-path-1x65536": (_merge, [((1, 32768), "float32")] * 2),
-    "merge-path-1x65536-u32": (_merge, [((1, 32768), "uint32")] * 2),
-    "merge-path-kv-1x65536": (_merge_kv, [((1, 32768), "float32")] * 2
-                              + [((1, 32768), "int32")] * 2),
+    "merge-path-1x65536": (_merge, [((65536,), "float32")]),
+    "merge-path-1x65536-u32": (_merge, [((65536,), "uint32")]),
+    "merge-path-kv-1x65536": (_merge_kv, [((65536,), "float32"),
+                                          ((65536,), "int32")]),
     "radix-1x2^20": (_radix, [((1, 1 << 20), "uint32")]),
     "radix-kv-1x2^20": (_radix_kv, [((1, 1 << 20), "uint32"),
                                     ((1, 1 << 20), "int32")]),

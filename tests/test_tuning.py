@@ -63,6 +63,7 @@ def test_save_load_round_trip_on_disk(tmp_path):
     {"digit_bits": 3},
     {"digit_bits": 0},
     {"radix_tile": 4},
+    {"radix_tile": 192},
     {"run_len": 1},
     {"capacity_slack": 0.5},
     {"select_min_n": -1},
@@ -167,7 +168,7 @@ def test_explicit_digit_bits_overrides_profile():
 def test_selection_consumes_profile_digit_bits():
     x = np.random.default_rng(2).standard_normal((1, 400)).astype(np.float32)
     tuning.set_active(dataclasses.replace(tuning.active(), digit_bits=4,
-                                          radix_tile=64))
+                                          radix_tile=128))
     v, _ = radix_select.select_topk(jnp.asarray(x), 16, use_kernel=True,
                                     interpret=True)
     ref = np.sort(x, -1)[:, ::-1][:, :16]
