@@ -247,13 +247,10 @@ class RadixBackend(SortBackend):
         self.check_dtype(rows.dtype)
         n = rows.shape[-1]
         passes, tiles = _rs.pass_tile_counts(n, rows.dtype)
-        sp = _obs.trace("radix.sort", n=n, passes=passes, tiles=tiles)
-        with sp:
+        with _obs.trace("radix.sort", n=n, passes=passes, tiles=tiles):
             enc = keycodec.encode(rows, descending=descending)
             out = _rs.sort_blocks(enc, interpret=interpret)
-            out = keycodec.decode(out, rows.dtype, descending=descending)
-            sp.fence(out)
-        return out
+            return keycodec.decode(out, rows.dtype, descending=descending)
 
     def sort_kv(self, keys, values, *, descending=False, plan=None,
                 interpret=None):
@@ -263,13 +260,11 @@ class RadixBackend(SortBackend):
         self.check_dtype(keys.dtype)
         n = keys.shape[-1]
         passes, tiles = _rs.pass_tile_counts(n, keys.dtype)
-        sp = _obs.trace("radix.sort_kv", n=n, passes=passes, tiles=tiles)
-        with sp:
+        with _obs.trace("radix.sort_kv", n=n, passes=passes, tiles=tiles):
             enc = keycodec.encode(keys, descending=descending)
             sk, sv = _rs.sort_kv_blocks(enc, values, interpret=interpret)
-            sk = keycodec.decode(sk, keys.dtype, descending=descending)
-            sp.fence((sk, sv))
-        return sk, sv
+            return keycodec.decode(sk, keys.dtype,
+                                   descending=descending), sv
 
 
 # ---------------------------------------------------------------------------
@@ -297,11 +292,9 @@ class SelectBackend(SortBackend):
         self.check_dtype(rows.dtype)
         n = rows.shape[-1]
         passes, tiles = _sel.pass_tile_counts(n, rows.dtype)
-        sp = _obs.trace("select.topk", n=n, k=k, passes=passes, tiles=tiles)
-        with sp:
-            out = _sel.select_topk(rows, k, interpret=interpret)
-            sp.fence(out)
-        return out
+        with _obs.trace("select.topk", n=n, k=k, passes=passes,
+                        tiles=tiles):
+            return _sel.select_topk(rows, k, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------

@@ -180,11 +180,8 @@ def distributed_sort(x: jnp.ndarray, mesh: Mesh, axis_name=None,
             n_dev, -(-n // n_dev), jnp.dtype(x.dtype).itemsize)
         _metrics.counter("distsort.oddeven_bytes").inc(coll_bytes)
         _metrics.counter("distsort.oddeven_sorts").inc()
-    sp = _obs.trace("distsort.oddeven", n=n, n_dev=n_dev, bytes=coll_bytes)
-    with sp:
-        out = _oddeven_fn(mesh, axis_name, local_method, interpret)(x)
-        sp.fence(out)
-    return out
+    with _obs.trace("distsort.oddeven", n=n, n_dev=n_dev, bytes=coll_bytes):
+        return _oddeven_fn(mesh, axis_name, local_method, interpret)(x)
 
 
 @functools.lru_cache(maxsize=64)

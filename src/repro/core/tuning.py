@@ -487,13 +487,19 @@ def refresh_if_stale(threshold: float = REFRESH_P90_THRESHOLD,
 _autotune_live: Optional[bool] = None
 
 
+def autotune_armed() -> bool:
+    """True iff ``REPRO_AUTOTUNE=1`` opts the process into closed-loop
+    re-probing.  Spans fence (block on the device) only then: the loop's
+    ``planner.cost_model_error`` signal is what reads their device time."""
+    global _autotune_live
+    if _autotune_live is None:
+        _autotune_live = os.environ.get(AUTOTUNE_ENV) == "1"
+    return _autotune_live
+
+
 def maybe_refresh() -> None:
     """Zero-cost hook the engine calls after every cost observation: a
     no-op unless ``REPRO_AUTOTUNE=1`` opts the process into closed-loop
     re-probing (calibration mid-serve is deliberate, never a surprise)."""
-    global _autotune_live
-    if _autotune_live is None:
-        _autotune_live = os.environ.get(AUTOTUNE_ENV) == "1"
-    if not _autotune_live:
-        return
-    refresh_if_stale()
+    if autotune_armed():
+        refresh_if_stale()

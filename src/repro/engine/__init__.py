@@ -48,8 +48,9 @@ def _obs_finish(sp, op: str, plan: planner.Plan, n: int, batch: int,
 
     The 313ms-vs-3.4ms top-k inversion class of bug surfaces here as a
     two-orders-of-magnitude error ratio instead of hiding in a CSV.  No-op
-    when observability is off (``sp`` is the no-op span) or when the call
-    ran under an outer jit (no fence -> no honest device time).  The first
+    unless the span was fenced (the autotune loop armed with
+    ``REPRO_AUTOTUNE=1``, its only reader, and no outer jit); records only
+    with observability on.  The first
     call at a new shape includes compile time — cold and warm observations
     both land in the histogram, like the bench's cold/warm split.
     """
@@ -116,6 +117,12 @@ def merge_sort_rows_kv(k2: jnp.ndarray, v2: jnp.ndarray, *, descending: bool,
 # public entry points (any array size, planner-dispatched)
 # ---------------------------------------------------------------------------
 
+def _backend_span(method: str):
+    """The ``backend.<method>`` span around the one backend an engine call
+    runs ("merge" is the engine's own run + merge-tree pipeline)."""
+    return _obs.trace("backend." + method)
+
+
 def sort(x: jnp.ndarray, *, axis: int = -1, descending: bool = False,
          method: str = "auto", run_len: Optional[int] = None,
          interpret: Optional[bool] = None) -> jnp.ndarray:
@@ -124,22 +131,25 @@ def sort(x: jnp.ndarray, *, axis: int = -1, descending: bool = False,
     ``method`` is "auto" (cost-model pick), "merge" (force the engine), or
     any registered backend name to delegate to.
     """
-    x2, lead, ax = _to_rows(x, axis)
-    batch, n = x2.shape
-    plan = _spill_fallback(
-        planner.choose_cached(n, batch, x.dtype, requested=method,
-                              run_len=run_len), x2)
-    sp = _obs.trace("engine.sort", n=n, batch=batch, method=plan.method)
-    with sp:
-        if plan.method == "merge":
-            out = merge_sort_rows(x2, descending=descending, plan=plan,
-                                  interpret=interpret)
-        else:
-            out = sortspec.get_backend(plan.method).sort(
-                x2, descending=descending, plan=plan, interpret=interpret)
+    with _obs.trace("engine.sort") as sp:
+        x2, lead, ax = _to_rows(x, axis)
+        batch, n = x2.shape
+        plan = _spill_fallback(
+            planner.choose_cached(n, batch, x.dtype, requested=method,
+                                  run_len=run_len), x2)
+        sp.set(n=n, batch=batch, method=plan.method)
+        with _backend_span(plan.method):
+            if plan.method == "merge":
+                out = merge_sort_rows(x2, descending=descending, plan=plan,
+                                      interpret=interpret)
+            else:
+                out = sortspec.get_backend(plan.method).sort(
+                    x2, descending=descending, plan=plan,
+                    interpret=interpret)
         sp.fence(out)
+        out = _from_rows(out, lead, ax)
     _obs_finish(sp, "sort", plan, n, batch)
-    return _from_rows(out, lead, ax)
+    return out
 
 
 def sort_kv(keys: jnp.ndarray, values: jnp.ndarray, *, axis: int = -1,
@@ -153,27 +163,32 @@ def sort_kv(keys: jnp.ndarray, values: jnp.ndarray, *, axis: int = -1,
     planner's backend preference — segmented sort and MoE grouping rely on
     equal keys keeping their input order.
     """
-    k2, lead, ax = _to_rows(keys, axis)
-    v2, _, _ = _to_rows(values, axis)
-    batch, n = k2.shape
-    plan = _spill_fallback(
-        planner.choose_cached(n, batch, keys.dtype, requested=method,
-                              run_len=run_len), k2)
-    sp = _obs.trace("engine.sort_kv", n=n, batch=batch, method=plan.method)
-    with sp:
-        sk = sv = None
+    with _obs.trace("engine.sort_kv") as sp:
+        k2, lead, ax = _to_rows(keys, axis)
+        v2, _, _ = _to_rows(values, axis)
+        batch, n = k2.shape
+        plan = _spill_fallback(
+            planner.choose_cached(n, batch, keys.dtype, requested=method,
+                                  run_len=run_len), k2)
+        sp.set(n=n, batch=batch, method=plan.method)
+        be = None
         if plan.method != "merge":
             be = sortspec.get_backend(plan.method)
-            if not stable or be.capabilities.stable:
-                sk, sv = be.sort_kv(k2, v2, descending=descending, plan=plan,
-                                    interpret=interpret)
-        if sk is None:
-            sk, sv = merge_sort_rows_kv(k2, v2, descending=descending,
-                                        plan=plan, stable=stable,
-                                        interpret=interpret)
+            if stable and not be.capabilities.stable:
+                be = None
+        if be is not None:
+            with _backend_span(plan.method):
+                sk, sv = be.sort_kv(k2, v2, descending=descending,
+                                    plan=plan, interpret=interpret)
+        else:
+            with _backend_span("merge"):
+                sk, sv = merge_sort_rows_kv(k2, v2, descending=descending,
+                                            plan=plan, stable=stable,
+                                            interpret=interpret)
         sp.fence((sk, sv))
+        out = _from_rows(sk, lead, ax), _from_rows(sv, lead, ax)
     _obs_finish(sp, "sort_kv", plan, n, batch)
-    return _from_rows(sk, lead, ax), _from_rows(sv, lead, ax)
+    return out
 
 
 def argsort(x: jnp.ndarray, *, axis: int = -1, descending: bool = False,
@@ -186,28 +201,33 @@ def argsort(x: jnp.ndarray, *, axis: int = -1, descending: bool = False,
     resolved to one, else stable tile sort + merge-path merges (stable by
     construction), regardless of the planner's preference.
     """
-    x2, lead, ax = _to_rows(x, axis)
-    batch, n = x2.shape
-    plan = _spill_fallback(
-        planner.choose_cached(n, batch, x.dtype, requested=method,
-                              run_len=run_len), x2)
-    sp = _obs.trace("engine.argsort", n=n, batch=batch, method=plan.method)
-    with sp:
-        order = None
+    with _obs.trace("engine.argsort") as sp:
+        x2, lead, ax = _to_rows(x, axis)
+        batch, n = x2.shape
+        plan = _spill_fallback(
+            planner.choose_cached(n, batch, x.dtype, requested=method,
+                                  run_len=run_len), x2)
+        sp.set(n=n, batch=batch, method=plan.method)
+        be = None
         if plan.method != "merge":
             be = sortspec.get_backend(plan.method)
-            if not stable or be.capabilities.stable:
+            if stable and not be.capabilities.stable:
+                be = None
+        if be is not None:
+            with _backend_span(plan.method):
                 order = be.argsort(x2, descending=descending, plan=plan,
                                    interpret=interpret)
-        if order is None:
-            idx = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[None, :],
-                                   x2.shape)
-            _, order = merge_sort_rows_kv(x2, idx, descending=descending,
-                                          plan=plan, stable=stable,
-                                          interpret=interpret)
+        else:
+            with _backend_span("merge"):
+                idx = jnp.broadcast_to(
+                    jnp.arange(n, dtype=jnp.int32)[None, :], x2.shape)
+                _, order = merge_sort_rows_kv(x2, idx, descending=descending,
+                                              plan=plan, stable=stable,
+                                              interpret=interpret)
         sp.fence(order)
+        order = _from_rows(order, lead, ax)
     _obs_finish(sp, "argsort", plan, n, batch)
-    return _from_rows(order, lead, ax)
+    return order
 
 
 def topk(x: jnp.ndarray, k: int, *, method: str = "auto",
@@ -222,33 +242,35 @@ def topk(x: jnp.ndarray, k: int, *, method: str = "auto",
     path: per-run top-k candidates (the paper's partition-then-merge,
     §II-B) followed by a key-value merge tree over the k-prefixes.
     """
-    x2, lead, _ = _to_rows(x, -1)
-    batch, n = x2.shape
-    if not 1 <= k <= n:
-        raise ValueError(
-            f"topk k must satisfy 1 <= k <= n (n={n}); got k={k}")
-    plan = planner.choose_cached(n, batch, x.dtype, requested=method,
-                                 run_len=run_len, k=k)
-    sp = _obs.trace("engine.topk", n=n, batch=batch, k=k, method=plan.method)
-    with sp:
-        if plan.method != "merge":
-            v, i = sortspec.get_backend(plan.method).topk(
-                x2, k, plan=plan, interpret=interpret)
-        else:
-            idx = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[None, :],
-                                   x2.shape)
-            rk, rv = runs.generate_runs_kv(x2, idx, plan.run_len,
-                                           method=plan.run_method,
-                                           descending=True,
-                                           interpret=interpret)
-            # candidate prefixes: only the first k of each run can reach
-            # the top k
-            kk = runs.next_pow2(min(k, rk.shape[-1]))
-            ck, cv = rk[..., :kk], rv[..., :kk]
-            mk, mv = merge_runs(ck, cv, descending=True,
-                                backend=plan.merge_backend,
-                                interpret=interpret)
-            v, i = mk[:, :k], mv[:, :k]
+    with _obs.trace("engine.topk") as sp:
+        x2, lead, _ = _to_rows(x, -1)
+        batch, n = x2.shape
+        if not 1 <= k <= n:
+            raise ValueError(
+                f"topk k must satisfy 1 <= k <= n (n={n}); got k={k}")
+        plan = planner.choose_cached(n, batch, x.dtype, requested=method,
+                                     run_len=run_len, k=k)
+        sp.set(n=n, batch=batch, k=k, method=plan.method)
+        with _backend_span(plan.method):
+            if plan.method != "merge":
+                v, i = sortspec.get_backend(plan.method).topk(
+                    x2, k, plan=plan, interpret=interpret)
+            else:
+                idx = jnp.broadcast_to(
+                    jnp.arange(n, dtype=jnp.int32)[None, :], x2.shape)
+                rk, rv = runs.generate_runs_kv(x2, idx, plan.run_len,
+                                               method=plan.run_method,
+                                               descending=True,
+                                               interpret=interpret)
+                # candidate prefixes: only the first k of each run can
+                # reach the top k
+                kk = runs.next_pow2(min(k, rk.shape[-1]))
+                ck, cv = rk[..., :kk], rv[..., :kk]
+                mk, mv = merge_runs(ck, cv, descending=True,
+                                    backend=plan.merge_backend,
+                                    interpret=interpret)
+                v, i = mk[:, :k], mv[:, :k]
         sp.fence((v, i))
+        out = v.reshape(*lead, k), i.reshape(*lead, k)
     _obs_finish(sp, "topk", plan, n, batch, k)
-    return v.reshape(*lead, k), i.reshape(*lead, k)
+    return out

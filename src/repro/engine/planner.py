@@ -31,6 +31,8 @@ from repro.core import cost_model, sortspec
 from repro.core import tuning as _tuning
 from repro.core.backends import MAX_BITONIC_N, MAX_PALLAS_N  # noqa: F401
 from repro.engine import runs as _runs
+from repro.obs import metrics as _metrics
+from repro.obs import trace as _obs
 
 
 @dataclasses.dataclass(frozen=True)
@@ -146,7 +148,6 @@ def _record_decision(plan: Plan, *, n: int, batch: int, dtype,
                      requested: str, k: Optional[int]) -> None:
     """One structured event per resolved plan (cache misses only — hits
     never reach ``choose``).  No-op unless observability is enabled."""
-    from repro.obs import trace as _obs
     if not _obs.enabled():
         return
     _obs.record_event(
@@ -155,8 +156,7 @@ def _record_decision(plan: Plan, *, n: int, batch: int, dtype,
         predicted_ns=plan.costs.get(plan.method),
         costs={m: c for m, c in plan.costs.items()},
         run_len=plan.run_len, backend=jax.default_backend())
-    from repro.obs import metrics as _m
-    _m.counter("planner.decisions").inc()
+    _metrics.counter("planner.decisions").inc()
 
 
 def choose_method(n: int, batch: int = 1, dtype=jnp.float32) -> str:
@@ -217,15 +217,13 @@ def choose_relational(op: str, n: int, batch: int = 1, dtype=jnp.float32, *,
         else "xla"
     plan = Plan(method=method, run_len=rl, run_method=run_method,
                 merge_backend="pallas" if on_tpu() else "xla", costs=costs)
-    from repro.obs import trace as _obs
     if _obs.enabled():
         _obs.record_event(
             "relational_plan_decision", op=op, n=n, batch=batch,
             dtype=jnp.dtype(dtype).name, requested=requested,
             method=plan.method, predicted_ns=plan.costs.get(plan.method),
             costs=dict(plan.costs), backend=jax.default_backend())
-        from repro.obs import metrics as _m
-        _m.counter("planner.relational_decisions").inc()
+        _metrics.counter("planner.relational_decisions").inc()
     return plan
 
 
@@ -234,18 +232,14 @@ def choose_relational_cached(op: str, n: int, batch: int = 1,
                              requested: str = "auto") -> Plan:
     """``choose_relational`` memoized in the shared plan cache — same
     invalidation rules (calibration generation, registry generation)."""
-    key = ("rel", op, n, batch, jnp.dtype(dtype).name, requested,
-           _tuning.generation(), sortspec.registry_generation(),
-           jax.default_backend())
-    plan = _PLAN_CACHE.get(key)
-    if plan is None:
-        plan = choose_relational(op, n, batch, dtype, requested=requested)
-        _PLAN_CACHE[key] = plan
-    else:
-        from repro.obs import trace as _obs
-        if _obs.enabled():
-            from repro.obs import metrics as _m
-            _m.counter("planner.plan_cache_hits").inc()
+    with _obs.trace("planner.choose") as sp:
+        key = ("rel", op, n, batch, jnp.dtype(dtype).name, requested,
+               _tuning.generation(), sortspec.registry_generation(),
+               jax.default_backend())
+        plan = _cached(sp, key)
+        if plan is None:
+            plan = _PLAN_CACHE[key] = choose_relational(
+                op, n, batch, dtype, requested=requested)
     return plan
 
 
@@ -335,13 +329,14 @@ def choose_distributed_cached(n: int, n_dev: int, dtype=jnp.float32, *,
     tsig = None if topology is None else tuple(
         (a.name, a.size, a.tier, a.bandwidth_bytes_per_s, a.latency_ns)
         for a in topology.axes)
-    key = ("dist", n, n_dev, jnp.dtype(dtype).name, tsig,
-           _topo.generation(), _tuning.generation(),
-           sortspec.registry_generation(), jax.default_backend())
-    plan = _PLAN_CACHE.get(key)
-    if plan is None:
-        plan = choose_distributed(n, n_dev, dtype, topology=topology)
-        _PLAN_CACHE[key] = plan
+    with _obs.trace("planner.choose") as sp:
+        key = ("dist", n, n_dev, jnp.dtype(dtype).name, tsig,
+               _topo.generation(), _tuning.generation(),
+               sortspec.registry_generation(), jax.default_backend())
+        plan = _cached(sp, key)
+        if plan is None:
+            plan = _PLAN_CACHE[key] = choose_distributed(
+                n, n_dev, dtype, topology=topology)
     return plan
 
 
@@ -350,6 +345,16 @@ def choose_distributed_cached(n: int, n_dev: int, dtype=jnp.float32, *,
 # ---------------------------------------------------------------------------
 
 _PLAN_CACHE: Dict[tuple, Plan] = {}
+
+
+def _cached(sp, key: tuple):
+    """The plan cached under ``key`` or None; marks the ``planner.choose``
+    span ``sp`` with ``hit`` and counts hits."""
+    plan = _PLAN_CACHE.get(key)
+    sp.set(hit=plan is not None)
+    if plan is not None and _obs.enabled():
+        _metrics.counter("planner.plan_cache_hits").inc()
+    return plan
 
 
 def choose_cached(n: int, batch: int = 1, dtype=jnp.float32, *,
@@ -364,19 +369,14 @@ def choose_cached(n: int, batch: int = 1, dtype=jnp.float32, *,
     state and the registry generation, so ``calibrate()`` or registering a
     new backend transparently re-plans.
     """
-    key = (n, batch, jnp.dtype(dtype).name, requested, run_len, k,
-           _tuning.generation(), sortspec.registry_generation(),
-           jax.default_backend())
-    plan = _PLAN_CACHE.get(key)
-    if plan is None:
-        plan = choose(n, batch, dtype, requested=requested, run_len=run_len,
-                      k=k)
-        _PLAN_CACHE[key] = plan
-    else:
-        from repro.obs import trace as _obs
-        if _obs.enabled():
-            from repro.obs import metrics as _m
-            _m.counter("planner.plan_cache_hits").inc()
+    with _obs.trace("planner.choose") as sp:
+        key = (n, batch, jnp.dtype(dtype).name, requested, run_len, k,
+               _tuning.generation(), sortspec.registry_generation(),
+               jax.default_backend())
+        plan = _cached(sp, key)
+        if plan is None:
+            plan = _PLAN_CACHE[key] = choose(
+                n, batch, dtype, requested=requested, run_len=run_len, k=k)
     return plan
 
 

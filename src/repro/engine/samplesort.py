@@ -607,11 +607,14 @@ def _hier_phase4(mesh: Mesh, outer: str, inner: str, n: int, kv: bool,
 # ---------------------------------------------------------------------------
 
 def _sync_max(vcnt) -> Optional[int]:
-    """Host-sync the measured bucket maximum (None under an outer jit)."""
+    """Host-sync the measured bucket maximum (None under an outer jit),
+    counted as ``samplesort.host_syncs``."""
     try:
-        return int(np.max(np.asarray(vcnt)))
+        counts = np.asarray(vcnt)
     except jax.errors.TracerArrayConversionError:
         return None
+    metrics.counter("samplesort.host_syncs").inc()
+    return int(np.max(counts))
 
 
 def sample_sort(x: jnp.ndarray, mesh: Mesh, axis_name: AxisArg = "data", *,
@@ -744,14 +747,12 @@ def _flat_sample_sort(enc, values, mesh, axes, n, kv, padded, local_method,
     m = -(-n // n_dev)
     p1 = _phase1(mesh, axes, axes, n, kv, padded, local_method, s,
                  use_histogram, interpret)
-    sp1 = _obs.trace("samplesort.phase1", n=n, n_dev=n_dev, kv=kv,
-                     samples_per_shard=s)
-    with sp1:
+    with _obs.trace("samplesort.phase1", n=n, n_dev=n_dev, kv=kv,
+                    samples_per_shard=s):
         if kv:
             ks, vs, starts, vcnt = p1(enc, values)
         else:
             ks, starts, vcnt = p1(enc)
-        sp1.fence(vcnt)
 
     # the one host sync: the realized bucket maximum sets the static
     # exchange capacity, so buffers and merge work scale with what the
@@ -794,7 +795,6 @@ def _flat_sample_sort(enc, values, mesh, axes, n, kv, padded, local_method,
         mean_fill = float(counts.mean()) if counts.size else 0.0
         skew = float(max_bucket) / mean_fill if mean_fill else 1.0
         metrics.gauge("samplesort.bucket_skew").set(skew)
-        metrics.histogram("samplesort.bucket_fill_max").observe(max_bucket)
         metrics.counter("samplesort.alltoall_bytes").inc(total_bytes)
         metrics.counter("samplesort.sorts").inc()
         if len(axes) == 2:
@@ -806,17 +806,12 @@ def _flat_sample_sort(enc, values, mesh, axes, n, kv, padded, local_method,
 
     p2 = _phase2(mesh, axes, n, kv, cap, kname, vname, merge_backend,
                  chunks, wire_codec, interpret)
-    sp2 = _obs.trace("samplesort.phase2", n=n, n_dev=n_dev, capacity=cap,
-                     merge_backend=merge_backend,
-                     bytes=total_bytes if _obs.enabled() else 0)
-    with sp2:
+    with _obs.trace("samplesort.phase2", n=n, n_dev=n_dev, capacity=cap,
+                    merge_backend=merge_backend,
+                    bytes=total_bytes if _obs.enabled() else 0):
         if kv:
-            out_k, out_v = p2(ks, vs, starts, vcnt)
-            sp2.fence((out_k, out_v))
-            return out_k, out_v
-        out = p2(ks, starts, vcnt)
-        sp2.fence(out)
-        return out
+            return p2(ks, vs, starts, vcnt)
+        return p2(ks, starts, vcnt)
 
 
 def _hier_sample_sort(enc, values, mesh, axes, n, kv, padded, local_method,
@@ -838,15 +833,13 @@ def _hier_sample_sort(enc, values, mesh, axes, n, kv, padded, local_method,
     # phase 1: local sort + INTRA-host splitters (partition group = inner)
     p1 = _phase1(mesh, axes, (inner_ax,), n, kv, padded, local_method, s,
                  use_histogram, interpret)
-    sp1 = _obs.trace("samplesort.hier.phase1", n=n, n_dev=n_dev, kv=kv,
-                     d_out=d_out, d_in=d_in, samples_per_shard=s)
-    with sp1:
+    with _obs.trace("samplesort.hier.phase1", n=n, n_dev=n_dev, kv=kv,
+                    d_out=d_out, d_in=d_in, samples_per_shard=s):
         if kv:
             ks, vs, starts, vcnt = p1(enc, values)
         else:
             ks, starts, vcnt = p1(enc)
             vs = None
-        sp1.fence(vcnt)
     max1 = _sync_max(vcnt)
     if max1 is None:
         raise ValueError(
@@ -859,14 +852,12 @@ def _hier_sample_sort(enc, values, mesh, axes, n, kv, padded, local_method,
     # phase 2: ICI exchange + intra-host rebalance + outer splitter prep
     p2 = _hier_phase2(mesh, outer_ax, inner_ax, n, kv, c1, s, kname, vname,
                       mb1, use_histogram, interpret)
-    sp2 = _obs.trace("samplesort.hier.phase2", n=n, capacity=c1,
-                     merge_backend=mb1)
-    with sp2:
+    with _obs.trace("samplesort.hier.phase2", n=n, capacity=c1,
+                    merge_backend=mb1):
         if kv:
             ks, vs, starts, vcnt = p2(ks, vs, starts, vcnt)
         else:
             ks, starts, vcnt = p2(ks, starts, vcnt)
-        sp2.fence(vcnt)
     max2 = _sync_max(vcnt)
     c2 = _round_capacity(int(math.ceil(max2 * slack)), m)
     chunks = coll.pipeline_chunks(c2, pipeline_chunks)
@@ -876,15 +867,13 @@ def _hier_sample_sort(enc, values, mesh, axes, n, kv, padded, local_method,
     p3 = _hier_phase3(mesh, outer_ax, inner_ax, n, kv, c2, chunks, s,
                       kname, vname, mb2, wire_codec, use_histogram,
                       interpret)
-    sp3 = _obs.trace("samplesort.hier.phase3", n=n, capacity=c2,
-                     chunks=chunks, wire_codec=wire_codec or "none",
-                     merge_backend=mb2)
-    with sp3:
+    with _obs.trace("samplesort.hier.phase3", n=n, capacity=c2,
+                    chunks=chunks, wire_codec=wire_codec or "none",
+                    merge_backend=mb2):
         if kv:
             ks, vs, starts, vcnt = p3(ks, vs, starts, vcnt)
         else:
             ks, starts, vcnt = p3(ks, starts, vcnt)
-        sp3.fence(vcnt)
     L = next_pow2(d_out * chunks) * (c2 // chunks)
     max3 = _sync_max(vcnt)
     c3 = _round_capacity(int(math.ceil(max3 * slack)), L)
@@ -901,9 +890,7 @@ def _hier_sample_sort(enc, values, mesh, axes, n, kv, padded, local_method,
         dcn = n_dev * d_out * c2 * itemsize
         if wire_codec == "int8":
             val_is = jnp.dtype(vname).itemsize
-            saved = n_dev * coll.wire_bytes_saved(d_out, c2, val_is)
-            dcn -= saved
-            metrics.counter("collectives.wire_bytes_saved").inc(saved)
+            dcn -= n_dev * coll.wire_bytes_saved(d_out, c2, val_is)
         coll.record_exchange("ici", ici)
         coll.record_exchange("dcn", dcn)
         coll.record_split_exchange(n_dev * n_dev * m * itemsize,
@@ -915,16 +902,11 @@ def _hier_sample_sort(enc, values, mesh, axes, n, kv, padded, local_method,
     # phase 4: ICI finalize exchange + GLOBAL rank rebalance
     p4 = _hier_phase4(mesh, outer_ax, inner_ax, n, kv, L, c3, kname, vname,
                       mb3, interpret)
-    sp4 = _obs.trace("samplesort.hier.phase4", n=n, capacity=c3,
-                     merge_backend=mb3)
-    with sp4:
+    with _obs.trace("samplesort.hier.phase4", n=n, capacity=c3,
+                    merge_backend=mb3):
         if kv:
-            out_k, out_v = p4(ks, vs, starts, vcnt)
-            sp4.fence((out_k, out_v))
-            return out_k, out_v
-        out = p4(ks, starts, vcnt)
-        sp4.fence(out)
-        return out
+            return p4(ks, vs, starts, vcnt)
+        return p4(ks, starts, vcnt)
 
 
 # ---------------------------------------------------------------------------
@@ -1032,11 +1014,9 @@ def sample_topk(x: jnp.ndarray, k: int, mesh: Mesh,
                                        int(mesh.shape[axes[0]]))
         else:
             coll.record_exchange("ici", cand_bytes)
-    sp = _obs.trace("samplesort.topk", n=n, k=k, n_dev=n_dev,
-                    bytes=cand_bytes)
-    with sp:
+    with _obs.trace("samplesort.topk", n=n, k=k, n_dev=n_dev,
+                    bytes=cand_bytes):
         ev, ei = prog(enc)
-        sp.fence((ev, ei))
     return keycodec.decode(ev, x.dtype, descending=True), ei
 
 

@@ -12,9 +12,10 @@ switch governs all recording::
     print(obs.report.render_markdown())
     obs.disable()
 
-Disabled (the default) the whole layer is a single flag check per call
-site — no spans, no events, no metric writes, bit-identical outputs.
-See README "Observability" for the metric catalog.
+Disabled (the default) the whole layer is a flag check per call site — no
+spans, no events, no metric writes, bit-identical outputs.  Spans also reach
+the JAX profiler trace whenever one is being recorded, switch or not.
+See README "Observability" for the span and metric catalog.
 """
 from __future__ import annotations
 

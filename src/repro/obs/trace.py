@@ -7,22 +7,33 @@ path threads through:
 
     from repro.obs import trace
 
-    with trace.trace("samplesort.all_to_all", bytes=nbytes) as sp:
+    with trace.trace("samplesort.phase2", bytes=nbytes) as sp:
         out = exchange(...)
-        sp.fence(out)          # block_until_ready outside jit, no-op inside
+        sp.fence(out)          # blocks only while the autotune loop is armed
 
 Design contract (enforced by tests/test_obs.py):
 
-  * **Zero overhead when disabled.**  ``trace(...)`` checks one module-level
-    flag before any allocation and returns a shared no-op singleton; nothing
-    is recorded, no span objects are built, and traced functions return
-    bit-identical outputs.  Hot paths that would compute expensive span
-    attributes guard on :func:`enabled` first.
-  * **jit-safe.**  :meth:`Span.fence` only calls ``block_until_ready`` on
-    concrete arrays; under a trace (inside ``jax.jit``/``shard_map``) it is
-    a no-op, so instrumented functions stay traceable.  Wall time is always
-    recorded; device time (``device_ms``) only exists when a fence actually
-    ran, so timings are never silently trace-time garbage.
+  * **One clock with the device.**  While a JAX profiler trace is being
+    recorded (jaxlib's own ``TraceMe.is_enabled()``, see
+    ``profiler_active``), every span also enters a
+    ``jax.profiler.TraceAnnotation`` of its name, its attributes as the
+    annotation's arguments.  So program spans sit on the calling thread's
+    line of the trace, beside JAX's ``PjitFunction(...)`` dispatch events
+    and on the same clock as the device's operations.  The outermost span
+    of a call draws a per-process call sequence number (``call``) that its
+    children carry too, so one call's spans share an identifier.
+  * **Zero overhead when off.**  With no profiler trace active and
+    recording disabled, ``trace(...)`` makes two checks before any
+    allocation and returns a shared no-op singleton; nothing is recorded,
+    no span objects are built, and traced functions return bit-identical
+    outputs.  ``REPRO_OBS`` (:func:`enable`) governs only the in-memory
+    records: span records, events, counters, gauges and histograms.
+  * **Spans never block.**  :meth:`Span.fence` waits for the device only
+    while the closed-loop autotuner is armed (``REPRO_AUTOTUNE=1``), whose
+    ``planner.cost_model_error`` signal is the one reader of the fenced
+    device time (``device_ms``).  Otherwise it returns its argument
+    untouched, so a span never adds a host sync to what it measures.
+    Under a trace (inside ``jax.jit``/``shard_map``) it never blocks.
   * **Nested.**  The active span stack lives in a contextvar, so spans nest
     per thread/async context and each finished record carries its depth and
     parent name.
@@ -30,25 +41,30 @@ Design contract (enforced by tests/test_obs.py):
 Events (``record_event``) are the structured, non-timing side of the same
 log: the planner appends one ``plan_decision`` event per cache miss with the
 full candidate cost table, and the engine appends ``cost_observation``
-events pairing predicted with measured ns — the raw series behind the
-``cost_model_error`` metric.
+events pairing predicted with measured ns (armed autotuner only) — the raw
+series behind the ``cost_model_error`` metric.
 """
 from __future__ import annotations
 
 import contextlib
 import contextvars
+import itertools
 import json
 import os
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "enable", "disable", "enabled", "tracing", "trace", "Span",
-    "record_event", "events", "spans", "clear", "to_json",
+    "profiler_active", "record_event", "events", "spans", "clear",
+    "to_json",
 ]
 
-# THE flag: every entry point checks it before allocating anything
+# THE recording flag: span records, events and metrics; every entry point
+# checks it (and, for spans, the profiler) before allocating anything
 _ENABLED = bool(os.environ.get("REPRO_OBS"))
 
 _LOCK = threading.Lock()
@@ -56,6 +72,12 @@ _SPANS: List[Dict[str, Any]] = []          # finished spans, completion order
 _EVENTS: List[Dict[str, Any]] = []         # structured events, append order
 _STACK: contextvars.ContextVar[Tuple["Span", ...]] = contextvars.ContextVar(
     "repro_obs_span_stack", default=())
+_CALLS = itertools.count(1)                # call sequence of outermost spans
+
+# ``profiler_active()`` is true while a JAX profiler trace is being
+# recorded: jaxlib's own ``TraceMe.is_enabled`` (TraceAnnotation subclasses
+# TraceMe), bound directly so a span site pays one builtin call
+profiler_active = TraceAnnotation.is_enabled
 
 
 def enabled() -> bool:
@@ -108,30 +130,38 @@ def _concrete(value: Any) -> bool:
 
 
 class Span:
-    """One timed region.  Wall time always; device time when fenced."""
+    """One timed region: a record when recording is enabled, an annotation
+    in the profiler trace while one is active."""
 
-    __slots__ = ("name", "attrs", "depth", "parent", "_t0",
-                 "wall_ms", "device_ms")
+    __slots__ = ("name", "attrs", "depth", "parent", "call", "_t0",
+                 "_record", "_ann", "wall_ms", "device_ms")
 
     def __init__(self, name: str, attrs: Dict[str, Any]):
         self.name = name
         self.attrs = attrs
         self.depth = 0
         self.parent: Optional[str] = None
+        self.call = 0
         self._t0 = 0.0
+        self._record = _ENABLED
+        self._ann: Optional[TraceAnnotation] = None
         self.wall_ms: Optional[float] = None
         self.device_ms: Optional[float] = None
 
     def set(self, **attrs) -> "Span":
         """Attach attributes discovered mid-span (bucket counts, plans)."""
         self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
         return self
 
     def fence(self, value):
-        """Block until ``value`` is device-complete and record the span's
-        device time.  No-op on tracers (inside jit) — returns ``value``
-        unchanged either way, so call sites can fence their return."""
-        if _concrete(value):
+        """With the autotune loop armed, block until ``value`` is
+        device-complete and record the span's device time; otherwise (and
+        on tracers, inside jit) do nothing.  Returns ``value`` unchanged
+        either way, so call sites can fence their return."""
+        from repro.core import tuning
+        if tuning.autotune_armed() and _concrete(value):
             import jax
             jax.block_until_ready(value)
             self.device_ms = (time.perf_counter() - self._t0) * 1e3
@@ -141,20 +171,31 @@ class Span:
         stack = _STACK.get()
         self.depth = len(stack)
         self.parent = stack[-1].name if stack else None
+        self.call = stack[0].call if stack else next(_CALLS)
         _STACK.set(stack + (self,))
+        if profiler_active():
+            self._ann = TraceAnnotation(self.name, call=self.call,
+                                        **self.attrs)
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
         self.wall_ms = (time.perf_counter() - self._t0) * 1e3
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
         stack = _STACK.get()
         if stack and stack[-1] is self:
             _STACK.set(stack[:-1])
+        if not self._record:
+            return
         with _LOCK:
             _SPANS.append({
                 "name": self.name, "parent": self.parent,
-                "depth": self.depth, "wall_ms": self.wall_ms,
-                "device_ms": self.device_ms, "attrs": dict(self.attrs),
+                "depth": self.depth, "call": self.call,
+                "wall_ms": self.wall_ms, "device_ms": self.device_ms,
+                "attrs": dict(self.attrs),
             })
 
 
@@ -185,9 +226,10 @@ _NOOP = _NoopSpan()
 
 
 def trace(name: str, **attrs):
-    """Open a span (use as a context manager).  Disabled -> the shared
-    no-op singleton; nothing is allocated or recorded."""
-    if not _ENABLED:
+    """Open a span (use as a context manager).  With recording disabled
+    and no profiler trace active -> the shared no-op singleton; nothing
+    is allocated or recorded."""
+    if not _ENABLED and not profiler_active():
         return _NOOP
     return Span(name, attrs)
 

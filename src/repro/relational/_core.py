@@ -1,8 +1,10 @@
 """Shared machinery for the relational ops: the sorted post-pass
-primitives, planner resolution, and obs plumbing.
+primitives, planner resolution, and obs spans.
 
 Every op in this package is (sort via the front door) + (an O(n) scan /
-searchsorted post-pass on the sorted column).  The post-passes here are
+searchsorted post-pass on the sorted column), traced as one
+``relational.<op>`` span holding a ``relational.sort`` and a
+``relational.post_pass`` span.  The post-passes here are
 scatter-free where possible (XLA:CPU serializes scatters): compaction is
 a stable partition by one sort, which also avoids the random gathers a
 TPU runs slowly.
@@ -15,6 +17,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs import metrics as _metrics
+from repro.obs import trace as _obs
 from repro.relational.relspec import RelSpec, SORT_OPS, STABLE_OPS
 
 
@@ -135,55 +139,35 @@ def pad_tail(arr: jnp.ndarray, n_valid: jnp.ndarray, fill) -> jnp.ndarray:
 # planner resolution + obs
 # ---------------------------------------------------------------------------
 
-def resolve_plan(spec: RelSpec, n: int, dtype):
-    """-> (method, plan).  Distributed specs return (None, None): the mesh
-    sort dispatches through ``planner.choose_distributed`` on its own.
+def resolve_method(spec: RelSpec, n: int, dtype) -> Optional[str]:
+    """The sort backend for ``spec``.  Distributed specs return None: the
+    mesh sort dispatches through ``planner.choose_distributed`` on its own.
     Explicit methods skip pricing; "auto" goes through the relational cost
     entries (``planner.choose_relational_cached``)."""
     if spec.mesh is not None or spec.op not in SORT_OPS:
-        return None, None
+        return None
     if spec.method != "auto":
-        return spec.method, None
+        return spec.method
     if n == 0:
-        return "xla", None
+        return "xla"
     from repro.engine import planner
-    plan = planner.choose_relational_cached(spec.op, n, dtype=dtype)
-    return plan.method, plan
+    return planner.choose_relational_cached(spec.op, n, dtype=dtype).method
 
 
 def span(spec: RelSpec, n: int):
-    """Obs span for one relational op (no-op object when obs is off),
-    plus the per-op invocation counter."""
-    from repro.obs import trace as _obs
+    """Obs span for one relational op (the no-op object when obs is off
+    and no profiler trace is active), plus the per-op invocation counter."""
     sp = _obs.trace(f"relational.{spec.op}", n=n,
                     method=spec.method, distributed=spec.mesh is not None)
     if _obs.enabled():
-        from repro.obs import metrics as _m
-        _m.counter(f"relational.{spec.op}").inc()
+        _metrics.counter(f"relational.{spec.op}").inc()
     return sp
 
 
-def finish(sp, spec: RelSpec, plan, n: int) -> None:
-    """Pair the fenced span with its relational plan: one
-    ``relational_cost_observation`` event + the
-    ``relational.cost_model_error`` ratio histogram — the same
-    predicted-vs-measured audit the engine keeps for raw sorts
-    (``engine._obs_finish``), in a separate histogram so relational
-    post-pass noise never perturbs the autotuner's refresh signal."""
-    if plan is None or sp.device_ms is None:
-        return
-    predicted = plan.costs.get(plan.method)
-    if not predicted or predicted != predicted or predicted == float("inf"):
-        return
-    from repro.obs import trace as _obs
-    measured_ns = sp.device_ms * 1e6
-    _obs.record_event("relational_cost_observation", op=spec.op, n=n,
-                      method=plan.method, predicted_ns=predicted,
-                      measured_ns=measured_ns,
-                      error=measured_ns / predicted)
-    from repro.obs import metrics as _m
-    _m.histogram("relational.cost_model_error").observe(
-        measured_ns / predicted)
+def post_pass():
+    """The ``relational.post_pass`` span: everything an op does on the
+    sorted column (boundary mask, compaction, aggregates, padding)."""
+    return _obs.trace("relational.post_pass")
 
 
 def sorted_column(spec: RelSpec, x: jnp.ndarray, method: Optional[str],
@@ -191,30 +175,34 @@ def sorted_column(spec: RelSpec, x: jnp.ndarray, method: Optional[str],
     """The op's sort backbone: mesh-global sample-sort when the spec is
     distributed, the planner-picked (or pinned) local backend otherwise.
     Stable-order ops go through the stable argsort pipeline instead —
-    see ``stable_order``."""
+    see ``stable_order``.  Traced as the ``relational.sort`` span."""
     import repro.sort as rsort
-    if spec.mesh is not None:
+    with _obs.trace("relational.sort"):
+        if spec.mesh is not None:
+            if values is not None:
+                return rsort.sort_kv(x, values, mesh=spec.mesh,
+                                     axis_name=spec.axis_name,
+                                     interpret=spec.interpret)
+            return rsort.sort(x, mesh=spec.mesh, axis_name=spec.axis_name,
+                              interpret=spec.interpret)
         if values is not None:
-            return rsort.sort_kv(x, values, mesh=spec.mesh,
-                                 axis_name=spec.axis_name,
+            return rsort.sort_kv(x, values, method=method, stable=True,
                                  interpret=spec.interpret)
-        return rsort.sort(x, mesh=spec.mesh, axis_name=spec.axis_name,
-                          interpret=spec.interpret)
-    if values is not None:
-        return rsort.sort_kv(x, values, method=method, stable=True,
-                             interpret=spec.interpret)
-    return rsort.sort(x, method=method, interpret=spec.interpret)
+        return rsort.sort(x, method=method, interpret=spec.interpret)
 
 
 def stable_order(x: jnp.ndarray, method: Optional[str],
                  interpret: Optional[bool]) -> jnp.ndarray:
     """Stable ascending permutation of a 1-D column via the front door
     (non-stable backends fall back to the engine's stable merge pipeline
-    — exactly what ``cost_model.relational_cost_ns`` prices them at)."""
+    — exactly what ``cost_model.relational_cost_ns`` prices them at).
+    Traced as the ``relational.sort`` span."""
     import repro.sort as rsort
-    return rsort.argsort(x, stable=True, method=method, interpret=interpret)
+    with _obs.trace("relational.sort"):
+        return rsort.argsort(x, stable=True, method=method,
+                             interpret=interpret)
 
 
-__all__ = ["boundary_mask", "compact", "compact_sorted", "pad_tail", "resolve_plan",
-           "span", "finish", "sorted_column", "stable_order",
-           "SORT_OPS", "STABLE_OPS"]
+__all__ = ["boundary_mask", "compact", "compact_sorted", "pad_tail",
+           "resolve_method", "span", "post_pass", "sorted_column",
+           "stable_order", "SORT_OPS", "STABLE_OPS"]

@@ -43,22 +43,18 @@ def run_rle(spec: RelSpec, x: jnp.ndarray) -> RunLength:
         z = jnp.zeros((0,), jnp.int32)
         return RunLength(values=x, run_lengths=z,
                          n_runs=jnp.zeros((), jnp.int32))
-    method, plan = _core.resolve_plan(spec, n, x.dtype)
-    sp = _core.span(spec, n)
-    with sp:
-        s = x if spec.assume_sorted \
-            else _core.sorted_column(spec, x, method)
-        mask = _core.boundary_mask(s)
-        vals, n_runs, seg = _core.compact_sorted(s, mask)
-        lengths = jax.ops.segment_sum(jnp.ones((n,), jnp.int32), seg,
-                                      num_segments=n)
-        out = RunLength(
-            values=_core.pad_tail(vals, n_runs, spec.fill_value),
-            run_lengths=_core.pad_tail(lengths, n_runs, 0),
-            n_runs=n_runs)
-        sp.fence(out.values)
-    _core.finish(sp, spec, plan, n)
-    return out
+    with _core.span(spec, n):
+        s = x if spec.assume_sorted else _core.sorted_column(
+            spec, x, _core.resolve_method(spec, n, x.dtype))
+        with _core.post_pass():
+            mask = _core.boundary_mask(s)
+            vals, n_runs, seg = _core.compact_sorted(s, mask)
+            lengths = jax.ops.segment_sum(jnp.ones((n,), jnp.int32), seg,
+                                          num_segments=n)
+            return RunLength(
+                values=_core.pad_tail(vals, n_runs, spec.fill_value),
+                run_lengths=_core.pad_tail(lengths, n_runs, 0),
+                n_runs=n_runs)
 
 
 def rle_decode(values: jnp.ndarray, run_lengths: jnp.ndarray,
@@ -75,15 +71,11 @@ def run_delta(spec: RelSpec, x: jnp.ndarray) -> Delta:
     n = x.shape[0]
     if n == 0:
         return Delta(deltas=x)
-    method, plan = _core.resolve_plan(spec, n, x.dtype)
-    sp = _core.span(spec, n)
-    with sp:
-        s = x if spec.assume_sorted \
-            else _core.sorted_column(spec, x, method)
-        d = jnp.concatenate([s[:1], s[1:] - s[:-1]])
-        sp.fence(d)
-    _core.finish(sp, spec, plan, n)
-    return Delta(deltas=d)
+    with _core.span(spec, n):
+        s = x if spec.assume_sorted else _core.sorted_column(
+            spec, x, _core.resolve_method(spec, n, x.dtype))
+        with _core.post_pass():
+            return Delta(deltas=jnp.concatenate([s[:1], s[1:] - s[:-1]]))
 
 
 def delta_decode(deltas: jnp.ndarray) -> jnp.ndarray:

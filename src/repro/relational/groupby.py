@@ -79,21 +79,20 @@ def run(spec: RelSpec, keys: jnp.ndarray, values: jnp.ndarray) -> GroupBy:
             for a in spec.agg)
         return GroupBy(keys=keys, n_groups=jnp.zeros((), jnp.int32),
                        aggregates=empty)
-    method, plan = _core.resolve_plan(spec, n, keys.dtype)
-    sp = _core.span(spec, n)
-    with sp:
+    with _core.span(spec, n):
+        method = _core.resolve_method(spec, n, keys.dtype)
         # the mesh path's kv sample-sort is not stable, which is fine:
         # every supported reduction is order-free given exact arithmetic
         # (the stable local pipeline just fixes the summation order)
         sk, sv = _core.sorted_column(spec, keys, method, values=values)
-        mask = _core.boundary_mask(sk)
-        ukeys, n_groups, seg = _core.compact(spec, sk, mask)
-        aggs = _aggregate(sv, seg, n, spec.agg, n_groups, spec.fill_value)
-        out = GroupBy(keys=_core.pad_tail(ukeys, n_groups, spec.fill_value),
-                      n_groups=n_groups, aggregates=aggs)
-        sp.fence(out.keys)
-    _core.finish(sp, spec, plan, n)
-    return out
+        with _core.post_pass():
+            mask = _core.boundary_mask(sk)
+            ukeys, n_groups, seg = _core.compact(spec, sk, mask)
+            aggs = _aggregate(sv, seg, n, spec.agg, n_groups,
+                              spec.fill_value)
+            return GroupBy(
+                keys=_core.pad_tail(ukeys, n_groups, spec.fill_value),
+                n_groups=n_groups, aggregates=aggs)
 
 
 def run_group_ranks(spec: RelSpec, keys: jnp.ndarray,
@@ -106,8 +105,7 @@ def run_group_ranks(spec: RelSpec, keys: jnp.ndarray,
     """
     g = spec.num_groups
     n = keys.shape[-1]
-    sp = _core.span(spec, int(keys.size))
-    with sp:
+    with _core.span(spec, int(keys.size)):
         if keys.ndim > 1 or g <= ONE_HOT_MAX_GROUPS or n == 0:
             onehot = jax.nn.one_hot(keys, g, dtype=jnp.int32)
             if constrain is not None:
@@ -117,16 +115,18 @@ def run_group_ranks(spec: RelSpec, keys: jnp.ndarray,
             counts = jnp.sum(onehot, axis=-2)
         else:
             order = _core.stable_order(keys, spec.method, spec.interpret)
-            sk = keys[order]
-            seg = jnp.cumsum(_core.boundary_mask(sk).astype(jnp.int32)) - 1
-            # group start in sorted coords = first position of each run;
-            # rank = sorted position - start, scattered back to input order
-            starts = jnp.full((n,), n, jnp.int32).at[seg].min(
-                jnp.arange(n, dtype=jnp.int32))
-            sorted_rank = jnp.arange(n, dtype=jnp.int32) - starts[seg]
-            ranks = jnp.zeros((n,), jnp.int32).at[order].set(sorted_rank)
-            counts = jnp.zeros((g,), jnp.int32).at[
-                jnp.clip(keys, 0, g - 1)].add(1)
-        sp.fence(ranks)
-    _core.finish(sp, spec, None, n)
+            with _core.post_pass():
+                sk = keys[order]
+                seg = jnp.cumsum(
+                    _core.boundary_mask(sk).astype(jnp.int32)) - 1
+                # group start in sorted coords = first position of each
+                # run; rank = sorted position - start, scattered back to
+                # input order
+                starts = jnp.full((n,), n, jnp.int32).at[seg].min(
+                    jnp.arange(n, dtype=jnp.int32))
+                sorted_rank = jnp.arange(n, dtype=jnp.int32) - starts[seg]
+                ranks = jnp.zeros((n,), jnp.int32).at[order].set(
+                    sorted_rank)
+                counts = jnp.zeros((g,), jnp.int32).at[
+                    jnp.clip(keys, 0, g - 1)].add(1)
     return GroupRanks(ranks=ranks, counts=counts)

@@ -41,33 +41,31 @@ def run(spec: RelSpec, lk: jnp.ndarray, rk: jnp.ndarray) -> Join:
     if nl == 0 or nr == 0:
         pad = jnp.full((size,), fill, jnp.int32)
         return Join(pad, pad, jnp.zeros((), jnp.int32))
-    method, plan = _core.resolve_plan(spec, max(nl, nr), lk.dtype)
-    sp = _core.span(spec, nl + nr)
-    with sp:
+    with _core.span(spec, nl + nr):
+        method = _core.resolve_method(spec, max(nl, nr), lk.dtype)
         ol = _core.stable_order(lk, method, spec.interpret)
-        sl = lk[ol]
         orr = _core.stable_order(rk, method, spec.interpret)
-        sr = rk[orr]
-        # merge-scan: each left-sorted element's matching run on the right
-        start = jnp.searchsorted(sr, sl, side="left").astype(jnp.int32)
-        stop = jnp.searchsorted(sr, sl, side="right").astype(jnp.int32)
-        off = jnp.cumsum(stop - start)              # inclusive pair offsets
-        n_pairs = off[-1].astype(jnp.int32)
-        # duplicate-pair expansion: pair t belongs to the left-sorted
-        # element li with off[li-1] <= t < off[li]; its right partner is
-        # the (t - off[li-1])-th element of li's run
-        t = jnp.arange(size, dtype=jnp.int32)
-        li = jnp.searchsorted(off, t, side="right").astype(jnp.int32)
-        li = jnp.clip(li, 0, nl - 1)
-        prev = jnp.where(li > 0, off[jnp.maximum(li - 1, 0)], 0)
-        ri = jnp.clip(start[li] + (t - prev), 0, nr - 1)
-        valid = t < n_pairs
-        out = Join(
-            left_idx=jnp.where(valid, ol[li], fill).astype(jnp.int32),
-            right_idx=jnp.where(valid, orr[ri], fill).astype(jnp.int32),
-            n_pairs=n_pairs)
-        sp.fence(out.left_idx)
-    _core.finish(sp, spec, plan, nl + nr)
+        with _core.post_pass():
+            sl, sr = lk[ol], rk[orr]
+            # merge-scan: each left-sorted element's matching run on the
+            # right
+            start = jnp.searchsorted(sr, sl, side="left").astype(jnp.int32)
+            stop = jnp.searchsorted(sr, sl, side="right").astype(jnp.int32)
+            off = jnp.cumsum(stop - start)          # inclusive pair offsets
+            n_pairs = off[-1].astype(jnp.int32)
+            # duplicate-pair expansion: pair t belongs to the left-sorted
+            # element li with off[li-1] <= t < off[li]; its right partner
+            # is the (t - off[li-1])-th element of li's run
+            t = jnp.arange(size, dtype=jnp.int32)
+            li = jnp.searchsorted(off, t, side="right").astype(jnp.int32)
+            li = jnp.clip(li, 0, nl - 1)
+            prev = jnp.where(li > 0, off[jnp.maximum(li - 1, 0)], 0)
+            ri = jnp.clip(start[li] + (t - prev), 0, nr - 1)
+            valid = t < n_pairs
+            out = Join(
+                left_idx=jnp.where(valid, ol[li], fill).astype(jnp.int32),
+                right_idx=jnp.where(valid, orr[ri], fill).astype(jnp.int32),
+                n_pairs=n_pairs)
     try:                                  # eager calls get the honest error;
         concrete = int(out.n_pairs)       # traced counts stay the caller's
     except Exception:                     # responsibility (documented)

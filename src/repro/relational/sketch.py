@@ -41,8 +41,7 @@ class QuantileSketch(NamedTuple):
 def run_histogram(spec: RelSpec, x: jnp.ndarray) -> HistogramSketch:
     bins = spec.num_bins
     n = x.shape[0]
-    sp = _core.span(spec, n)
-    with sp:
+    with _core.span(spec, n):
         xf = x.astype(jnp.float32)
         lo = jnp.asarray(spec.lo, jnp.float32) if spec.lo is not None \
             else (jnp.min(xf) if n else jnp.zeros((), jnp.float32))
@@ -61,8 +60,6 @@ def run_histogram(spec: RelSpec, x: jnp.ndarray) -> HistogramSketch:
             counts = jnp.zeros((bins,), jnp.int32).at[idx].add(
                 inside.astype(jnp.int32))
             out = HistogramSketch(counts=counts, edges=edges)
-        sp.fence(out.counts)
-    _core.finish(sp, spec, None, n)
     return out
 
 
@@ -71,17 +68,13 @@ def run_quantile(spec: RelSpec, x: jnp.ndarray) -> QuantileSketch:
     # lower order statistic per fraction; k = largest one we must reach
     ords = tuple(int(q * (n - 1)) for q in spec.qs)
     k = max(ords) + 1
-    sp = _core.span(spec, n)
-    with sp:
+    with _core.span(spec, n):
         enc = keycodec.encode(x, descending=False)
         kth, _ = _select(enc[None, :], k, spec.interpret)
         # ascending survivor prefix: position j IS the j-th order statistic
         vals = keycodec.decode(
             kth[0, jnp.asarray(ords, jnp.int32)], x.dtype)
-        out = QuantileSketch(values=vals)
-        sp.fence(out.values)
-    _core.finish(sp, spec, None, n)
-    return out
+        return QuantileSketch(values=vals)
 
 
 def _select(enc: jnp.ndarray, k: int, interpret):

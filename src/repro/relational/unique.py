@@ -37,26 +37,24 @@ def run(spec: RelSpec, x: jnp.ndarray) -> Unique:
                       if spec.return_inverse else None,
                       counts=jnp.zeros((0,), jnp.int32)
                       if spec.return_counts else None)
-    method, plan = _core.resolve_plan(spec, n, x.dtype)
-    sp = _core.span(spec, n)
-    with sp:
+    with _core.span(spec, n):
+        method = _core.resolve_method(spec, n, x.dtype)
         s = _core.sorted_column(spec, x, method)
-        mask = _core.boundary_mask(s)
-        uvals, n_unique, _ = _core.compact(spec, s, mask)
-        inverse = counts = None
-        if spec.return_inverse or spec.return_counts:
-            # uvals is non-decreasing (tail repeats the max), and every
-            # input value occurs in its valid prefix, so one binary
-            # search recovers each element's slot — works unchanged on
-            # the distributed path (no argsort needed over the mesh)
-            inverse = jnp.searchsorted(uvals, x, side="left"
-                                       ).astype(jnp.int32)
-        if spec.return_counts:
-            counts = jnp.zeros((n,), jnp.int32).at[inverse].add(1)
-        out = Unique(values=_core.pad_tail(uvals, n_unique, spec.fill_value),
-                     n_unique=n_unique,
-                     inverse=inverse if spec.return_inverse else None,
-                     counts=counts)
-        sp.fence(out.values)
-    _core.finish(sp, spec, plan, n)
-    return out
+        with _core.post_pass():
+            mask = _core.boundary_mask(s)
+            uvals, n_unique, _ = _core.compact(spec, s, mask)
+            inverse = counts = None
+            if spec.return_inverse or spec.return_counts:
+                # uvals is non-decreasing (tail repeats the max), and every
+                # input value occurs in its valid prefix, so one binary
+                # search recovers each element's slot — works unchanged on
+                # the distributed path (no argsort needed over the mesh)
+                inverse = jnp.searchsorted(uvals, x, side="left"
+                                           ).astype(jnp.int32)
+            if spec.return_counts:
+                counts = jnp.zeros((n,), jnp.int32).at[inverse].add(1)
+            return Unique(
+                values=_core.pad_tail(uvals, n_unique, spec.fill_value),
+                n_unique=n_unique,
+                inverse=inverse if spec.return_inverse else None,
+                counts=counts)

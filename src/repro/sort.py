@@ -37,6 +37,7 @@ from repro.core.sortspec import (  # noqa: F401  (public re-exports)
     Capabilities, SortBackend, SortSpec, backend_names, get_backend,
     register_backend, registered_backends, sort_defaults, unregister_backend)
 from repro.engine.planner import clear_plan_cache  # noqa: F401
+from repro.obs import trace as _obs
 
 __all__ = [
     "run", "sort", "argsort", "topk", "sort_kv", "segment_sort",
@@ -49,7 +50,8 @@ _Arr = jnp.ndarray
 
 
 def run(spec: SortSpec, x: _Arr) -> Union[_Arr, Tuple[_Arr, _Arr]]:
-    """Execute ``spec`` on ``x``.  Returns, by spec shape:
+    """Execute ``spec`` on ``x`` inside the front door's ``sort.run`` span.
+    Returns, by spec shape:
 
       plain sort                       sorted array
       ``indices=True``                 the sorting permutation (int32)
@@ -59,6 +61,11 @@ def run(spec: SortSpec, x: _Arr) -> Union[_Arr, Tuple[_Arr, _Arr]]:
                                        or the permutation if ``indices=True``
       ``valid_lengths``                padded rows, valid prefixes sorted
     """
+    with _obs.trace("sort.run"):
+        return _run(spec, x)
+
+
+def _run(spec: SortSpec, x: _Arr) -> Union[_Arr, Tuple[_Arr, _Arr]]:
     from repro import engine
     x = jnp.asarray(x)
     spec = spec.canonical(x)
@@ -134,7 +141,8 @@ def run(spec: SortSpec, x: _Arr) -> Union[_Arr, Tuple[_Arr, _Arr]]:
 
 
 # ---------------------------------------------------------------------------
-# ergonomic wrappers — each builds a spec and runs it
+# ergonomic wrappers — each builds a spec and runs it, inside the same
+# ``sort.run`` span as ``run``
 # ---------------------------------------------------------------------------
 
 def sort(x: _Arr, *, axis: int = -1, descending: bool = False,
@@ -148,10 +156,12 @@ def sort(x: _Arr, *, axis: int = -1, descending: bool = False,
     (sample-sort; ``axis_name=None`` spans all mesh axes, taking the
     two-level ICI/DCN schedule on multi-axis meshes; odd-even fallback
     on a single axis)."""
-    return run(SortSpec(axis=axis, descending=descending, method=method,
-                        run_len=run_len, interpret=interpret,
-                        valid_lengths=valid_lengths, fill_value=fill_value,
-                        mesh=mesh, axis_name=axis_name), x)
+    with _obs.trace("sort.run"):
+        return _run(SortSpec(axis=axis, descending=descending,
+                             method=method, run_len=run_len,
+                             interpret=interpret, valid_lengths=valid_lengths,
+                             fill_value=fill_value, mesh=mesh,
+                             axis_name=axis_name), x)
 
 
 def argsort(x: _Arr, *, axis: int = -1, descending: bool = False,
@@ -160,9 +170,10 @@ def argsort(x: _Arr, *, axis: int = -1, descending: bool = False,
             interpret: Optional[bool] = None) -> _Arr:
     """The sorting permutation (ties keep ascending index order in both
     directions on every backend; ``stable=True`` forces a stable pipeline)."""
-    return run(SortSpec(axis=axis, descending=descending, stable=stable,
-                        indices=True, method=method, run_len=run_len,
-                        interpret=interpret), x)
+    with _obs.trace("sort.run"):
+        return _run(SortSpec(axis=axis, descending=descending, stable=stable,
+                             indices=True, method=method, run_len=run_len,
+                             interpret=interpret), x)
 
 
 def topk(x: _Arr, k: int, *, axis: int = -1, method: Optional[str] = None,
@@ -176,9 +187,10 @@ def topk(x: _Arr, k: int, *, axis: int = -1, method: Optional[str] = None,
     ``mesh``/``axis_name`` a flat array is selected globally over the mesh
     axis — local select per shard plus ONE candidate all-gather, matching
     ``jax.lax.top_k`` bit-exactly (indices are global positions)."""
-    return run(SortSpec(axis=axis, k=k, descending=True, method=method,
-                        run_len=run_len, interpret=interpret,
-                        mesh=mesh, axis_name=axis_name), x)
+    with _obs.trace("sort.run"):
+        return _run(SortSpec(axis=axis, k=k, descending=True, method=method,
+                             run_len=run_len, interpret=interpret,
+                             mesh=mesh, axis_name=axis_name), x)
 
 
 def sort_kv(keys: _Arr, values: _Arr, *, axis: int = -1,
@@ -189,10 +201,11 @@ def sort_kv(keys: _Arr, values: _Arr, *, axis: int = -1,
     """Sort ``keys`` carrying ``values`` -> (sorted keys, permuted values).
     With ``mesh``/``axis_name`` the pair is sorted globally over the mesh
     axis (payload buckets ride the sample-sort exchange)."""
-    return run(SortSpec(axis=axis, descending=descending, stable=stable,
-                        values=jnp.asarray(values), method=method,
-                        run_len=run_len, interpret=interpret,
-                        mesh=mesh, axis_name=axis_name), keys)
+    with _obs.trace("sort.run"):
+        return _run(SortSpec(axis=axis, descending=descending, stable=stable,
+                             values=jnp.asarray(values), method=method,
+                             run_len=run_len, interpret=interpret,
+                             mesh=mesh, axis_name=axis_name), keys)
 
 
 def segment_sort(values: _Arr, *, segment_ids: Optional[_Arr] = None,
@@ -205,6 +218,7 @@ def segment_sort(values: _Arr, *, segment_ids: Optional[_Arr] = None,
     """
     if segment_ids is None and row_splits is None:
         raise ValueError("segment_sort needs segment_ids or row_splits")
-    return run(SortSpec(descending=descending, method=method,
-                        segment_ids=segment_ids, row_splits=row_splits,
-                        indices=indices), values)
+    with _obs.trace("sort.run"):
+        return _run(SortSpec(descending=descending, method=method,
+                             segment_ids=segment_ids, row_splits=row_splits,
+                             indices=indices), values)

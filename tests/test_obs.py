@@ -4,6 +4,9 @@
   * histograms answer percentiles within one bucket width of numpy;
   * disabled mode allocates nothing, records nothing, and leaves traced
     function outputs bit-identical;
+  * spans reach an active JAX profiler trace whether recording is on or
+    not, and one call's spans share its call sequence number;
+  * spans block on the device only while the autotune loop is armed;
   * the planner emits exactly one ``plan_decision`` event per cache miss
     and zero per cache hit.
 """
@@ -15,7 +18,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import repro.relational as rel
 import repro.sort as rsort
+from repro.core import tuning
 from repro.engine import planner
 from repro.obs import metrics, report, trace
 
@@ -34,6 +39,31 @@ def _clean_obs():
     trace.clear()
     metrics.reset()
     planner.clear_plan_cache()
+
+
+@pytest.fixture()
+def armed(monkeypatch):
+    """The closed-loop autotuner armed, as ``REPRO_AUTOTUNE=1`` arms it:
+    the one mode in which spans fence."""
+    monkeypatch.setattr(tuning, "_autotune_live", True)
+
+
+@pytest.fixture()
+def unarmed(monkeypatch):
+    monkeypatch.setattr(tuning, "_autotune_live", False)
+
+
+@pytest.fixture()
+def syncs(monkeypatch):
+    """Counts ``jax.block_until_ready`` calls."""
+    seen = []
+    real = jax.block_until_ready
+
+    def counting(x):
+        seen.append(1)
+        return real(x)
+    monkeypatch.setattr(jax, "block_until_ready", counting)
+    return seen
 
 
 # ---------------------------------------------------------------------------
@@ -62,7 +92,7 @@ def test_span_nesting_depth_and_parent():
     assert by_name["outer"]["attrs"] == {"n": 4}
 
 
-def test_span_fence_records_device_time_eagerly():
+def test_span_fence_records_device_time_eagerly(armed):
     x = jnp.arange(1024, dtype=jnp.float32)
     with trace.tracing():
         with trace.trace("eager") as sp:
@@ -72,7 +102,17 @@ def test_span_fence_records_device_time_eagerly():
     assert rec["wall_ms"] >= rec["device_ms"] >= 0.0
 
 
-def test_span_fence_is_jit_safe():
+def test_span_fence_does_not_block_unless_armed(unarmed, syncs):
+    x = jnp.arange(1024, dtype=jnp.float32)
+    with trace.tracing():
+        with trace.trace("eager") as sp:
+            out = sp.fence(jnp.sort(x))
+    assert out is not None and syncs == []
+    (rec,) = trace.spans()
+    assert rec["device_ms"] is None and rec["wall_ms"] >= 0.0
+
+
+def test_span_fence_is_jit_safe(armed):
     """Under jit the fence sees tracers: it must not block (device_ms
     stays None) and the traced function must stay compilable."""
     x = jnp.arange(1024, dtype=jnp.float32)
@@ -149,7 +189,7 @@ def test_histogram_snapshot_and_registry():
 # ---------------------------------------------------------------------------
 
 def test_disabled_is_allocation_free_and_records_nothing():
-    assert not trace.enabled()
+    assert not trace.enabled() and not trace.profiler_active()
     # one shared no-op singleton: no per-call span allocation
     assert trace.trace("a", n=1) is trace.trace("b", k=2)
     with trace.trace("x") as sp:
@@ -197,6 +237,73 @@ def test_disabled_overhead_is_noise():
 
 
 # ---------------------------------------------------------------------------
+# profiler annotations and call identity
+# ---------------------------------------------------------------------------
+
+def test_spans_annotate_an_active_profiler_trace_without_recording(
+        tmp_path):
+    """With recording off, a span is real only while a profiler trace is
+    active; it records nothing in memory either way."""
+    assert trace.trace("a") is trace.trace("b")
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert trace.profiler_active()
+        with trace.trace("annotated", n=3) as sp:
+            assert isinstance(sp, trace.Span)
+            sp.set(hit=True)
+    finally:
+        jax.profiler.stop_trace()
+    assert trace.spans() == []
+    assert trace.trace("a") is trace.trace("b")
+
+
+def test_one_calls_spans_share_its_call_number():
+    x = jnp.asarray(np.random.default_rng(4).standard_normal((2, 256)),
+                    jnp.float32)
+    with trace.tracing():
+        rsort.topk(x, 8)
+        rsort.topk(x, 8)
+    recs = trace.spans()
+    roots = [s for s in recs if s["depth"] == 0]
+    assert [s["name"] for s in roots] == ["sort.run", "sort.run"]
+    assert roots[0]["call"] != roots[1]["call"]
+    names = {s["name"] for s in recs}
+    assert {"planner.choose", "engine.topk"} <= names
+    assert any(n.startswith("backend.") for n in names)
+    first = recs[:recs.index(roots[0]) + 1]
+    assert {s["call"] for s in first} == {roots[0]["call"]}
+    hits = [s["attrs"]["hit"] for s in recs if s["name"] == "planner.choose"]
+    assert hits == [False, True]
+
+
+def _front_door_call(op):
+    rng = np.random.default_rng(5)
+    keys = jnp.asarray(rng.integers(0, 16, 256), jnp.int32)
+    vals = jnp.asarray(rng.integers(0, 9, 256), jnp.int32)
+    if op == "sort_kv":
+        return rsort.sort_kv(keys, vals)
+    if op == "topk":
+        return rsort.topk(keys.astype(jnp.float32).reshape(2, 128), 8)
+    return rel.group_by(keys, vals, agg="sum")
+
+
+@pytest.mark.parametrize("op", ["sort_kv", "topk", "group_by"])
+def test_front_door_does_not_block_unless_armed(op, unarmed, syncs):
+    with trace.tracing():
+        out = _front_door_call(op)
+    assert syncs == []
+    assert all(s["device_ms"] is None for s in trace.spans())
+    assert all(np.asarray(leaf).size for leaf in jax.tree.leaves(out))
+
+
+def test_armed_autotune_fences_the_engine_span(armed, syncs):
+    with trace.tracing():
+        _front_door_call("topk")
+    (eng,) = [s for s in trace.spans() if s["name"] == "engine.topk"]
+    assert eng["device_ms"] is not None and syncs
+
+
+# ---------------------------------------------------------------------------
 # planner decision events
 # ---------------------------------------------------------------------------
 
@@ -219,7 +326,7 @@ def test_planner_decision_event_once_per_miss_zero_per_hit():
     assert metrics.counter("planner.plan_cache_hits").value == 1
 
 
-def test_cost_observation_pairs_predicted_with_measured():
+def test_cost_observation_pairs_predicted_with_measured(armed):
     x = jnp.asarray(np.random.default_rng(2).standard_normal((1, 4096)),
                     jnp.float32)
     with trace.tracing():
@@ -235,7 +342,7 @@ def test_cost_observation_pairs_predicted_with_measured():
 # reports
 # ---------------------------------------------------------------------------
 
-def test_reports_render():
+def test_reports_render(armed):
     x = jnp.asarray(np.random.default_rng(3).standard_normal((1, 2048)),
                     jnp.float32)
     with trace.tracing():

@@ -392,6 +392,14 @@ def test_relational_ops_emit_spans_and_counters():
         names = [s["name"] for s in trace.spans()]
         assert "relational.unique" in names
         assert "relational.group_by" in names
+        # each op: its sort, then its post-pass, as child spans
+        children = [(s["parent"], s["name"]) for s in trace.spans()
+                    if s["name"] in ("relational.sort",
+                                     "relational.post_pass")]
+        assert children == [("relational.unique", "relational.sort"),
+                            ("relational.unique", "relational.post_pass"),
+                            ("relational.group_by", "relational.sort"),
+                            ("relational.group_by", "relational.post_pass")]
     finally:
         metrics.reset()
         trace.disable()

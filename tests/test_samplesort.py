@@ -94,6 +94,27 @@ def test_sample_sort_matches_np(n, dist):
     np.testing.assert_array_equal(out, np.sort(x))
 
 
+def test_host_syncs_counted_where_the_sync_happens():
+    """The measured-capacity mode reads the bucket counts back once per
+    flat sort; with a fixed capacity under an outer jit it reads none."""
+    from repro.obs import metrics, trace
+    x = jnp.asarray(np.random.default_rng(2).standard_normal(256),
+                    jnp.float32)
+    mesh = _mesh()
+    m = -(-256 // len(jax.devices()))
+    with trace.tracing():
+        metrics.reset()
+        try:
+            samplesort.sample_sort(x, mesh)
+            assert metrics.counter("samplesort.host_syncs").value == 1
+            out = jax.jit(lambda v: samplesort.sample_sort(
+                v, mesh, capacity=m))(x)
+            assert metrics.counter("samplesort.host_syncs").value == 1
+        finally:
+            metrics.reset()
+    np.testing.assert_array_equal(np.asarray(out), np.sort(np.asarray(x)))
+
+
 @pytest.mark.parametrize("descending", [False, True])
 def test_sample_sort_kv_uneven_extreme_keys(descending):
     """Payloads survive the bucket exchange even when genuine keys equal
