@@ -13,6 +13,8 @@ the planner), and tests/test_sortspec.py sweeps every claim for truth.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -51,6 +53,21 @@ def _gather_kv(keys, values, order):
 # xla — the "off-memory" reference point
 # ---------------------------------------------------------------------------
 
+@functools.partial(jax.jit, static_argnames="descending")
+def _xla_sort_kv(keys, values, descending):
+    """One stable two-operand sort that carries the payload: the
+    permutation of ``jnp.argsort(keys, stable=True)`` with no gather.
+    Descending reverses both rows around the ascending sort, as
+    ``jnp.argsort`` does, so ties keep ascending input order."""
+    if descending:
+        keys, values = jnp.flip(keys, -1), jnp.flip(values, -1)
+    keys, values = jax.lax.sort((keys, values), dimension=-1, num_keys=1,
+                                is_stable=True)
+    if descending:
+        keys, values = jnp.flip(keys, -1), jnp.flip(values, -1)
+    return keys, values
+
+
 @register_backend
 class XlaBackend(SortBackend):
     """jnp.sort / lax.top_k with the repo's grad-safe VJP and the unified
@@ -64,9 +81,7 @@ class XlaBackend(SortBackend):
 
     def sort_kv(self, keys, values, *, descending=False, plan=None,
                 interpret=None):
-        order = self.argsort(keys, descending=descending)
-        return (jnp.take_along_axis(keys, order, axis=-1),
-                jnp.take_along_axis(values, order, axis=-1))
+        return _xla_sort_kv(keys, values, descending)
 
     def argsort(self, rows, *, descending=False, plan=None, interpret=None):
         # jnp's descending comparator == the flip-remap stable form: ties
