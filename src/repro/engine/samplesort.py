@@ -36,9 +36,12 @@ be one mesh axis, a tuple of axes, or ``None`` for all of them, and
 ``hierarchical=None`` auto-selects the two-level path on two-axis meshes.
 
 The all-to-alls need static per-(source, destination) bucket capacities;
-each phase boundary syncs the measured bucket maximum to the host and the
-next jitted program is compiled at that capacity (with the tuning
-profile's slack so nearby workloads share executables).
+each phase boundary syncs the measured bucket maximum to the host, inside
+a ``samplesort.sync`` span (attributes ``max_bucket``, ``capacity``), and
+the next jitted program is compiled at that capacity (with the tuning
+profile's slack so nearby workloads share executables).  The flat path's
+two programs are named ``samplesort_phase1`` and ``samplesort_phase2``, so
+a device trace tells them apart.
 
 Everything runs on **encoded keys** (``core/keycodec.py``): signed ints,
 floats and ``descending`` all reduce to one ascending unsigned sort, and
@@ -349,7 +352,7 @@ def _phase1(mesh: Mesh, axes: Tuple[str, ...], part_axes: Tuple[str, ...],
     p = _n_dev(mesh, part_axes)
     m = -(-n // n_dev)
 
-    def local(*args):
+    def samplesort_phase1(*args):
         xs = args[0]
         vs = args[1] if kv else None
         my = _lin_index(mesh, axes)
@@ -391,7 +394,7 @@ def _phase1(mesh: Mesh, axes: Tuple[str, ...], part_axes: Tuple[str, ...],
 
     spec = P(axes)
     n_out = 4 if kv else 3
-    fn = _smap(local, mesh, (spec, spec) if kv else (spec,),
+    fn = _smap(samplesort_phase1, mesh, (spec, spec) if kv else (spec,),
                (spec,) * n_out)
     return jax.jit(fn)
 
@@ -416,7 +419,7 @@ def _phase2(mesh: Mesh, axes: Tuple[str, ...], n: int, kv: bool,
     maxkey = jnp.array(jnp.iinfo(jnp.dtype(key_dtype_name)).max,
                        jnp.dtype(key_dtype_name))
 
-    def local(*args):
+    def samplesort_phase2(*args):
         if kv:
             ks, vs, starts, vcnt = args
         else:
@@ -433,7 +436,7 @@ def _phase2(mesh: Mesh, axes: Tuple[str, ...], n: int, kv: bool,
 
     spec = P(axes)
     n_in = 4 if kv else 3
-    fn = _smap(local, mesh, (spec,) * n_in,
+    fn = _smap(samplesort_phase2, mesh, (spec,) * n_in,
                (spec, spec) if kv else spec)
     return jax.jit(fn)
 
@@ -757,29 +760,10 @@ def _flat_sample_sort(enc, values, mesh, axes, n, kv, padded, local_method,
     # the one host sync: the realized bucket maximum sets the static
     # exchange capacity, so buffers and merge work scale with what the
     # data needs (~m/D with regular sampling) instead of the worst case m
-    max_bucket = _sync_max(vcnt)
-    if capacity is None:
-        if max_bucket is None:
-            raise ValueError(
-                "sample_sort's measured-capacity mode reads the bucket "
-                "counts on the host and cannot run under an outer jit; "
-                f"pass capacity= (the shard length {m} is always safe)")
-        cap = _round_capacity(int(math.ceil(max_bucket * slack)), m)
-    else:
-        cap = _round_capacity(capacity, m)
-        if max_bucket is None and cap < m:
-            # under a trace there is no way to raise later, and a
-            # too-small capacity would silently drop elements — only the
-            # provably-safe shard-length capacity is allowed
-            raise ValueError(
-                f"under an outer jit, capacity must be >= the shard "
-                f"length {m} (the realized bucket maximum cannot be "
-                f"checked at trace time); got {capacity}")
-        if max_bucket is not None and cap < max_bucket:
-            raise ValueError(
-                f"capacity {capacity} is smaller than the realized maximum "
-                f"bucket ({max_bucket}); the shard length {m} is always "
-                f"safe")
+    with _obs.trace("samplesort.sync") as sp:
+        max_bucket = _sync_max(vcnt)
+        cap = _flat_capacity(max_bucket, capacity, slack, m)
+        sp.set(max_bucket=max_bucket, capacity=cap)
     chunks = coll.pipeline_chunks(cap, pipeline_chunks) \
         if pipeline_chunks is not None else 1
     if merge_backend is None:
@@ -814,6 +798,33 @@ def _flat_sample_sort(enc, values, mesh, axes, n, kv, padded, local_method,
         return p2(ks, starts, vcnt)
 
 
+def _flat_capacity(max_bucket: Optional[int], capacity: Optional[int],
+                   slack: float, m: int) -> int:
+    """The flat exchange's static capacity: the measured bucket maximum
+    times ``slack``, or the caller's ``capacity`` checked against it."""
+    if capacity is None:
+        if max_bucket is None:
+            raise ValueError(
+                "sample_sort's measured-capacity mode reads the bucket "
+                "counts on the host and cannot run under an outer jit; "
+                f"pass capacity= (the shard length {m} is always safe)")
+        return _round_capacity(int(math.ceil(max_bucket * slack)), m)
+    cap = _round_capacity(capacity, m)
+    if max_bucket is None and cap < m:
+        # under a trace there is no way to raise later, and a too-small
+        # capacity would silently drop elements — only the provably-safe
+        # shard-length capacity is allowed
+        raise ValueError(
+            f"under an outer jit, capacity must be >= the shard length {m} "
+            f"(the realized bucket maximum cannot be checked at trace "
+            f"time); got {capacity}")
+    if max_bucket is not None and cap < max_bucket:
+        raise ValueError(
+            f"capacity {capacity} is smaller than the realized maximum "
+            f"bucket ({max_bucket}); the shard length {m} is always safe")
+    return cap
+
+
 def _hier_sample_sort(enc, values, mesh, axes, n, kv, padded, local_method,
                       s, capacity, slack, use_histogram, merge_backend,
                       pipeline_chunks, wire_codec, itemsize, kname, vname,
@@ -840,13 +851,15 @@ def _hier_sample_sort(enc, values, mesh, axes, n, kv, padded, local_method,
         else:
             ks, starts, vcnt = p1(enc)
             vs = None
-    max1 = _sync_max(vcnt)
-    if max1 is None:
-        raise ValueError(
-            "hierarchical sample_sort measures per-phase exchange "
-            "capacities on the host and cannot run under an outer jit; "
-            "call it eagerly, or pass hierarchical=False with capacity=")
-    c1 = _round_capacity(int(math.ceil(max1 * slack)), m)
+    with _obs.trace("samplesort.sync") as sp:
+        max1 = _sync_max(vcnt)
+        if max1 is None:
+            raise ValueError(
+                "hierarchical sample_sort measures per-phase exchange "
+                "capacities on the host and cannot run under an outer jit; "
+                "call it eagerly, or pass hierarchical=False with capacity=")
+        c1 = _round_capacity(int(math.ceil(max1 * slack)), m)
+        sp.set(max_bucket=max1, capacity=c1)
     mb1 = merge_backend or _pick_merge_backend(c1)
 
     # phase 2: ICI exchange + intra-host rebalance + outer splitter prep
@@ -858,8 +871,10 @@ def _hier_sample_sort(enc, values, mesh, axes, n, kv, padded, local_method,
             ks, vs, starts, vcnt = p2(ks, vs, starts, vcnt)
         else:
             ks, starts, vcnt = p2(ks, starts, vcnt)
-    max2 = _sync_max(vcnt)
-    c2 = _round_capacity(int(math.ceil(max2 * slack)), m)
+    with _obs.trace("samplesort.sync") as sp:
+        max2 = _sync_max(vcnt)
+        c2 = _round_capacity(int(math.ceil(max2 * slack)), m)
+        sp.set(max_bucket=max2, capacity=c2)
     chunks = coll.pipeline_chunks(c2, pipeline_chunks)
     mb2 = merge_backend or _pick_merge_backend(c2 // chunks)
 
@@ -875,8 +890,10 @@ def _hier_sample_sort(enc, values, mesh, axes, n, kv, padded, local_method,
         else:
             ks, starts, vcnt = p3(ks, starts, vcnt)
     L = next_pow2(d_out * chunks) * (c2 // chunks)
-    max3 = _sync_max(vcnt)
-    c3 = _round_capacity(int(math.ceil(max3 * slack)), L)
+    with _obs.trace("samplesort.sync") as sp:
+        max3 = _sync_max(vcnt)
+        c3 = _round_capacity(int(math.ceil(max3 * slack)), L)
+        sp.set(max_bucket=max3, capacity=c3)
     mb3 = merge_backend or _pick_merge_backend(c3)
 
     if _obs.enabled():

@@ -76,13 +76,14 @@ def _run(spec: SortSpec, x: _Arr) -> Union[_Arr, Tuple[_Arr, _Arr]]:
         # top-k specs run the candidate path (local select + one
         # all-gather) — never a full mesh sort
         from repro.core.sortspec import get_backend as _get
-        if spec.k is not None:
-            return _get("distributed").topk_mesh(
-                x, spec.k, spec.mesh, spec.axis_name,
-                interpret=spec.interpret)
-        return _get("distributed").sort_mesh(
-            x, spec.mesh, spec.axis_name, values=spec.values,
-            descending=spec.descending, interpret=spec.interpret)
+        with _obs.trace("backend.distributed"):
+            if spec.k is not None:
+                return _get("distributed").topk_mesh(
+                    x, spec.k, spec.mesh, spec.axis_name,
+                    interpret=spec.interpret)
+            return _get("distributed").sort_mesh(
+                x, spec.mesh, spec.axis_name, values=spec.values,
+                descending=spec.descending, interpret=spec.interpret)
 
     if spec.valid_lengths is not None:
         if spec.indices or spec.values is not None:

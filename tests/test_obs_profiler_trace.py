@@ -45,6 +45,7 @@ def test_program_spans_reach_the_trace(tmp_path, monkeypatch):
 
     monkeypatch.delenv("REPRO_OBS", raising=False)
     monkeypatch.setattr(obs, "_ENABLED", False)
+    obs.clear()                 # records an earlier test left in the process
     rng = np.random.default_rng(0)
     logits = jnp.asarray(rng.standard_normal((4, 512)), jnp.float32)
     keys = jnp.asarray(rng.integers(0, 50, 1024), jnp.int32)
