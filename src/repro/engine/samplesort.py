@@ -216,14 +216,30 @@ def _pick_merge_backend(run_len: int) -> str:
 # shared traced building blocks (run inside the jitted shard_map programs)
 # ---------------------------------------------------------------------------
 
-def _exchange_merge(ks, vs, starts, vcnt, coll_axis, p, local_len, c,
+def _send_buffer(x, starts, vcnt, c, fill):
+    """(p, c) capacity-padded send buffer of a sorted pool ``x`` cut into
+    contiguous buckets: row j is ``x[starts[j] : starts[j] + c]`` with the
+    slots at and past ``vcnt[j]`` set to ``fill``.
+
+    Each row is one dynamic slice, a plain copy; an index array, though
+    arithmetic, compiles to a general gather.  ``dynamic_slice`` clamps a
+    start so the slice fits, so the pool is read extended by ``c`` fill
+    slots: a bucket that starts within ``c`` of the end is read in place."""
+    ext = jnp.concatenate([x, jnp.full((c,), fill, x.dtype)])
+    rows = jnp.stack([jax.lax.dynamic_slice(ext, (starts[j],), (c,))
+                      for j in range(starts.shape[0])])
+    within = jnp.arange(c, dtype=jnp.int32)[None, :] < vcnt[:, None]
+    return jnp.where(within, rows, fill)
+
+
+def _exchange_merge(ks, vs, starts, vcnt, coll_axis, p, c,
                     maxkey, merge_backend, interpret, *,
                     chunks: int = 1, wire_codec: Optional[str] = None):
     """One bucket exchange round over ``coll_axis`` (fan-out ``p``) plus
     the merge of the received runs.
 
-    ``ks`` is a sorted local pool of ``local_len`` slots cut into ``p``
-    buckets by ``starts``/``vcnt`` (genuine-key counts).  Send buffers are
+    ``ks`` is a sorted local pool cut into ``p`` contiguous buckets by
+    ``starts``/``vcnt`` (genuine-key counts).  Send buffers are
     capacity-``c`` padded with ``maxkey``; with ``chunks > 1`` the
     exchange is issued as that many collectives over contiguous bucket
     slices (``collectives.chunked_all_to_all``) so the receiver merges
@@ -237,10 +253,7 @@ def _exchange_merge(ks, vs, starts, vcnt, coll_axis, p, local_len, c,
     per-slot validity recovered through the merge's position payload, and
     the (p,) genuine-key counts received from each source.
     """
-    idx = starts[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
-    within = jnp.arange(c, dtype=jnp.int32)[None, :] < vcnt[:, None]
-    src = jnp.clip(idx, 0, local_len - 1)
-    sendk = jnp.where(within, ks[src], maxkey)
+    sendk = _send_buffer(ks, starts, vcnt, c, maxkey)
     recvk = coll.chunked_all_to_all(sendk, coll_axis, chunks=chunks)
     recv_cnt = coll.all_to_all(vcnt[:, None], coll_axis)[:, 0]   # (p,)
 
@@ -273,7 +286,7 @@ def _exchange_merge(ks, vs, starts, vcnt, coll_axis, p, local_len, c,
 
     mv = None
     if vs is not None:
-        sendv = jnp.where(within, vs[src], jnp.zeros((), vs.dtype))
+        sendv = _send_buffer(vs, starts, vcnt, c, jnp.zeros((), vs.dtype))
         if wire_codec == "int8":
             q, scale = coll.wire_encode_int8(sendv)
             rq = coll.chunked_all_to_all(q, coll_axis, chunks=chunks)
@@ -426,7 +439,7 @@ def _phase2(mesh: Mesh, axes: Tuple[str, ...], n: int, kv: bool,
             (ks, starts, vcnt), vs = args, None
         my = _lin_index(mesh, axes)
         mk, mv, mvalid, recv_cnt = _exchange_merge(
-            ks, vs, starts, vcnt, ax, n_dev, m, c, maxkey,
+            ks, vs, starts, vcnt, ax, n_dev, c, maxkey,
             merge_backend, interpret, chunks=chunks, wire_codec=wire_codec)
         shard_k, shard_v = _rebalance(mk, mv, mvalid, recv_cnt, ax,
                                       n_dev, m, my)
@@ -471,7 +484,7 @@ def _hier_phase2(mesh: Mesh, outer: str, inner: str, n: int, kv: bool,
         hi = jax.lax.axis_index(inner)
 
         mk, mv, mvalid, recv_cnt = _exchange_merge(
-            ks, vs, starts, vcnt, inner, d_in, m, c1, maxkey,
+            ks, vs, starts, vcnt, inner, d_in, c1, maxkey,
             merge_backend, interpret)
         shard_k, shard_v = _rebalance(mk, mv, mvalid, recv_cnt, inner,
                                       d_in, m, hi)
@@ -531,7 +544,7 @@ def _hier_phase3(mesh: Mesh, outer: str, inner: str, n: int, kv: bool,
             (ks, starts, vcnt), vs = args, None
 
         mk, mv, mvalid, recv_cnt = _exchange_merge(
-            ks, vs, starts, vcnt, outer, d_out, m, c2, maxkey,
+            ks, vs, starts, vcnt, outer, d_out, c2, maxkey,
             merge_backend, interpret, chunks=chunks, wire_codec=wire_codec)
 
         # the merged pool interleaves capacity pads with genuine max-key
@@ -590,7 +603,7 @@ def _hier_phase4(mesh: Mesh, outer: str, inner: str, n: int, kv: bool,
         my = _lin_index(mesh, (outer, inner))
 
         mk, mv, mvalid, recv_cnt = _exchange_merge(
-            ks, vs, starts, vcnt, inner, d_in, L, c3, maxkey,
+            ks, vs, starts, vcnt, inner, d_in, c3, maxkey,
             merge_backend, interpret)
         shard_k, shard_v = _rebalance(mk, mv, mvalid, recv_cnt,
                                       (outer, inner), n_dev, m, my)

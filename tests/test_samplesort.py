@@ -1,7 +1,7 @@
 """Single-round distributed sample-sort: unit pieces, mesh runs, dispatch.
 
 Runs correctly at any local device count: on the tier-1 single-device job
-the mesh degenerates to D=1 (plus one subprocess test that forces 8
+the mesh degenerates to D=1 (plus subprocess tests that force 8 and 4
 simulated devices), while the CI multi-device job executes this whole file
 under ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` so every
 collective (bucket all-to-all, rank rebalance, splitter all-gather) runs
@@ -69,6 +69,106 @@ def test_bucket_bounds_routes_agree_random():
     np.testing.assert_array_equal(
         np.asarray(samplesort.bucket_bounds(ks, sp, use_histogram=False)),
         np.asarray(samplesort.bucket_bounds(ks, sp, use_histogram=True)))
+
+
+# ---------------------------------------------------------------------------
+# send buffers: contiguous bucket slices == the index-gather formulation
+# ---------------------------------------------------------------------------
+
+def _gather_send_buffer(x, starts, vcnt, c, fill):
+    """The send buffer as an index gather: row j reads
+    ``x[clip(starts[j] + i)]`` and keeps it where ``i < vcnt[j]``."""
+    idx = starts[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
+    within = jnp.arange(c, dtype=jnp.int32)[None, :] < vcnt[:, None]
+    src = jnp.clip(idx, 0, x.shape[0] - 1)
+    return jnp.where(within, x[src], fill)
+
+
+_P, _POOL, _CAP = 4, 37, 16
+# per simulated device: bucket sizes (summing to the pool length) and the
+# genuine counts; pad slots (size > count) are a prefix-valid tail, as on
+# the padded last shard.  Covered: starts[j] + c past the pool's end
+# (device 1's last bucket), empty buckets (device 0's second, device 2's
+# last, which starts at the pool's very end), vcnt == c (devices 0 and 2)
+_SIZES = [(10, 0, 11, 16), (9, 12, 7, 9), (16, 5, 16, 0), (3, 14, 12, 8)]
+_VCNT = [(10, 0, 11, 16), (9, 12, 7, 9), (16, 5, 16, 0), (3, 14, 12, 5)]
+
+
+def _send_pools(payload_dtype):
+    """(P, POOL) sorted key pools, payloads, bucket starts and counts."""
+    rng = np.random.default_rng(41)
+    maxkey = np.iinfo(np.uint32).max
+    ks, starts = [], []
+    for sizes, cnt in zip(_SIZES, _VCNT):
+        pool = [np.sort(rng.integers(j * 1000, (j + 1) * 1000, cnt[j]))
+                for j in range(_P)]
+        pool = [np.concatenate([b, np.full(s - len(b), maxkey)])
+                for b, s in zip(pool, sizes)]
+        ks.append(np.concatenate(pool).astype(np.uint32))
+        starts.append(np.concatenate([[0], np.cumsum(sizes)[:-1]]))
+    vs = rng.standard_normal((_P, _POOL)) * 100
+    return (jnp.asarray(np.stack(ks)), jnp.asarray(vs.astype(payload_dtype)),
+            jnp.asarray(np.asarray(starts, np.int32)),
+            jnp.asarray(np.asarray(_VCNT, np.int32)))
+
+
+def test_send_pools_cover_the_edge_buckets():
+    _, _, starts, vcnt = _send_pools(np.int32)
+    starts, vcnt = np.asarray(starts), np.asarray(vcnt)
+    assert ((starts + _CAP > _POOL) & (vcnt > 0)).any()
+    assert (vcnt == 0).any() and (starts == _POOL).any()
+    assert (vcnt == _CAP).any()
+
+
+@pytest.mark.parametrize("kv,chunks,wire_codec", [
+    (False, 1, None), (False, 2, None),
+    (True, 1, None), (True, 2, None),
+    (True, 1, "int8"), (True, 2, "int8"),
+])
+def test_send_buffer_matches_gather(monkeypatch, kv, chunks, wire_codec):
+    """The sliced send buffers equal the index-gather ones element for
+    element, and so does the whole exchange round built on them (four
+    simulated devices: the collectives run over a vmapped axis)."""
+    ks, vs, starts, vcnt = _send_pools(
+        np.float32 if wire_codec else np.int32)
+    maxkey = jnp.uint32(np.iinfo(np.uint32).max)
+    zero = jnp.zeros((), vs.dtype)
+    for d in range(_P):
+        for x, fill in ((ks[d], maxkey), (vs[d], zero)):
+            np.testing.assert_array_equal(
+                np.asarray(samplesort._send_buffer(
+                    x, starts[d], vcnt[d], _CAP, fill)),
+                np.asarray(_gather_send_buffer(
+                    x, starts[d], vcnt[d], _CAP, fill)))
+
+    def exchange(ks, vs, starts, vcnt):
+        return samplesort._exchange_merge(
+            ks, vs if kv else None, starts, vcnt, "d", _P, _CAP, maxkey,
+            "sort", None, chunks=chunks, wire_codec=wire_codec)
+
+    run = jax.vmap(exchange, axis_name="d")
+    sliced = run(ks, vs, starts, vcnt)
+    monkeypatch.setattr(samplesort, "_send_buffer", _gather_send_buffer)
+    gathered = run(ks, vs, starts, vcnt)
+    assert (sliced[1] is None) == (not kv)
+    for a, b in zip(sliced, gathered):
+        if a is not None:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_send_buffer_lowers_without_gather():
+    """A (4, c) send buffer is four slices, not a gather — and the index
+    formulation it replaced does lower to one, so the check can fail."""
+    x = jax.ShapeDtypeStruct((1000,), jnp.uint32)
+    st = jax.ShapeDtypeStruct((4,), jnp.int32)
+    fill = jax.ShapeDtypeStruct((), jnp.uint32)
+
+    def stablehlo(f):
+        return jax.jit(f, static_argnums=3).lower(
+            x, st, st, 256, fill).as_text()
+
+    assert "gather" not in stablehlo(samplesort._send_buffer)
+    assert "gather" in stablehlo(_gather_send_buffer)
 
 
 # ---------------------------------------------------------------------------
@@ -346,3 +446,55 @@ print("SAMPLESORT_8DEV_OK")
     r = subprocess.run([sys.executable, "-c", code], env=env,
                        capture_output=True, text=True, timeout=420)
     assert "SAMPLESORT_8DEV_OK" in r.stdout, r.stderr[-2000:]
+
+
+# ---------------------------------------------------------------------------
+# forced 4-device runs with a capacity below the shard length: the tail
+# buckets take the extended-slice case of the send buffers
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("hierarchical", [False, True])
+def test_sample_sort_4dev_capacity_below_shard(hierarchical):
+    code = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, jax.numpy as jnp, numpy as np
+from repro.engine import samplesort
+HIER = %s
+tail_reads = []
+sliced = samplesort._send_buffer
+def spy(x, starts, vcnt, c, fill):
+    # a bucket with genuine keys whose slice runs past the pool's end
+    past = jnp.any((starts + c > x.shape[0]) & (vcnt > 0))
+    jax.debug.callback(lambda b: tail_reads.append(bool(b)), past)
+    return sliced(x, starts, vcnt, c, fill)
+samplesort._send_buffer = spy
+rng = np.random.default_rng(5)
+n = 4003                                    # shards of 1001, one padded
+k = rng.integers(-50, 50, n).astype(np.int32)
+k[::97] = np.iinfo(np.int32).max            # genuine keys tie the fill
+v = np.arange(n, dtype=np.int32)
+if HIER:
+    mesh = jax.make_mesh((2, 2), ("dcn", "ici"))
+    kw = dict(axis_name=None, hierarchical=True)
+else:
+    mesh = jax.make_mesh((4,), ("data",))
+    kw = dict(capacity=512)                 # < 1001, >= the largest bucket
+sk, sv = samplesort.sample_sort(jnp.asarray(k), mesh, values=jnp.asarray(v),
+                                **kw)
+sk, sv = np.asarray(sk), np.asarray(sv)
+jax.effects_barrier()
+assert any(tail_reads), tail_reads
+assert (sk == np.sort(k)).all()
+assert (k[sv] == sk).all() and len(set(sv.tolist())) == n
+if not HIER:
+    out = np.asarray(samplesort.sample_sort(jnp.asarray(k), mesh, **kw))
+    assert (out == np.sort(k)).all()
+print("SAMPLESORT_4DEV_OK")
+""" % hierarchical
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {**os.environ, "PYTHONPATH": os.path.join(repo, "src")}
+    env.pop("XLA_FLAGS", None)        # the subprocess pins its own count
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=420)
+    assert "SAMPLESORT_4DEV_OK" in r.stdout, r.stderr[-2000:]
