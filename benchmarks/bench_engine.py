@@ -177,23 +177,16 @@ def run_topk_distributed(sizes=DEFAULT_SIZES, k=TOPK_K):
 
 
 def run_distributed(sizes=DEFAULT_SIZES):
-    """sample vs oddeven (flat mesh) plus the two-level hierarchical
-    schedule on a 2 x D/2 grid; empty on 1-device hosts."""
+    """sample vs oddeven over a one-axis mesh; empty on 1-device hosts."""
     import jax
     import jax.numpy as jnp
-    from repro.core import distributed_sort as ds, topology
+    from repro.core import distributed_sort as ds
     from repro.engine import planner
 
     n_dev = len(jax.devices())
     if n_dev < 2:
         return []
     mesh = jax.make_mesh((n_dev,), ("data",))
-    # hierarchical leg: a 2 x (D/2) grid when the device count allows —
-    # on one host both tiers are the same physical link, so the wall
-    # times measure schedule overhead, not the DCN win (the crossover
-    # table in README comes from the cost model at real tier rates)
-    mesh2 = jax.make_mesh((2, n_dev // 2), ("host", "dev")) \
-        if n_dev >= 4 and n_dev % 2 == 0 else None
     rows, summary = [], {}
     rng = np.random.default_rng(0)
     for n in sizes:
@@ -209,26 +202,8 @@ def run_distributed(sizes=DEFAULT_SIZES):
             rows.append((f"engine.dist_{strat}.warm_us.n{n}",
                          round(warm * 1e6, 1), f"D={n_dev}"))
             summary[(strat, n)] = (cold, warm)
-        if mesh2 is not None:
-            cold, warm = _time_cold_warm_eager(
-                lambda v: ds.distributed_sort(v, mesh2, strategy="hier"),
-                x, reps)
-            rows.append((f"engine.dist_hier.cold_ms.n{n}",
-                         round(cold * 1e3, 1), f"D=2x{n_dev // 2}"))
-            rows.append((f"engine.dist_hier.warm_us.n{n}",
-                         round(warm * 1e6, 1), f"D=2x{n_dev // 2}"))
         auto = planner.choose_distributed(n, n_dev).strategy
         rows.append((f"engine.dist_auto.n{n}", 0.0, f"{n}:{auto}"))
-        if mesh2 is not None:
-            # the strategy the 2-tier mesh would actually run: odd-even
-            # is single-axis-only, so it is out of the running here
-            # (same filter distributed_sort applies on auto)
-            topo = topology.for_mesh(mesh2)
-            costs = planner.choose_distributed(n, n_dev,
-                                               topology=topo).costs
-            usable = {s: c for s, c in costs.items() if s != "oddeven"}
-            rows.append((f"engine.dist_auto_2tier.n{n}", 0.0,
-                         f"{n}:{min(usable, key=usable.__getitem__)}"))
     n_max = max(n - n % n_dev for n in sizes)
     oc, ow = summary[("oddeven", n_max)]
     sc, sw = summary[("sample", n_max)]

@@ -14,16 +14,12 @@ fi
 # TIER1_MULTIDEV=<D> runs the distributed-sort suites on D simulated
 # host-platform devices instead of the full single-device suite — the CI
 # multi-device job sets TIER1_MULTIDEV=8 so every push exercises the
-# sample-sort / odd-even paths at real D>1, not just the degenerate D=1,
-# and (at D>=8) the two-level hierarchical schedule on a real 2x4
-# (hosts x devices) grid, fuzz lens included.
+# sample-sort / odd-even paths at real D>1, not just the degenerate D=1.
 if [[ -n "${TIER1_MULTIDEV:-}" ]]; then
   export XLA_FLAGS="--xla_force_host_platform_device_count=${TIER1_MULTIDEV} ${XLA_FLAGS:-}"
   exec python -m pytest -x -q --durations=10 \
     tests/test_distributed_sort.py tests/test_samplesort.py \
-    tests/test_hierarchical_sort.py tests/test_topology.py \
     tests/test_distributed_topk.py tests/test_relational_distributed.py \
-    "tests/test_fuzz_conformance.py::test_fuzz_hier_sample_sort_matches_flat_and_jnp" \
     "$@"
 fi
 # TIER1_SPILL=1 runs the out-of-core spill tier by itself: the spill unit

@@ -318,12 +318,10 @@ class SelectBackend(SortBackend):
 
 @register_backend
 class DistributedBackend(SortBackend):
-    """Mesh-global sorting behind the registry: the sample-sort
-    (engine/samplesort.py — single-round flat, or the two-level ICI/DCN
-    hierarchical schedule on multi-axis meshes) with odd-even
-    transposition as the small-(n, D) single-axis fallback, strategy
-    priced by ``planner.choose_distributed`` against the active
-    ``core.topology``.
+    """Mesh-global sorting behind the registry: the single-round
+    sample-sort (engine/samplesort.py, over one mesh axis or several)
+    with odd-even transposition as the small-(n, D) single-axis
+    fallback, strategy priced by ``planner.choose_distributed``.
 
     The natural entry is a spec carrying mesh fields —
     ``SortSpec(mesh=..., axis_name=...)`` through ``repro.sort`` — which

@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict, Optional, Tuple
+from typing import Dict, Optional
 
 from repro.core import cas, network
 from repro.core import tuning as _tuning
@@ -360,9 +360,7 @@ def spill_sort_cost_ns(n: int, batch: int = 1, itemsize: int = 4, *,
 
 
 def collective_cost_ns(n_dev: int, m: int, itemsize: int,
-                       consts: DeviceSortConstants = None, *,
-                       alpha: Optional[float] = None,
-                       per_byte: Optional[float] = None) -> float:
+                       consts: DeviceSortConstants = None) -> float:
     """Estimated ns for ONE collective round in which every device
     exchanges ``n_dev`` shards of ``m`` elements.
 
@@ -371,52 +369,14 @@ def collective_cost_ns(n_dev: int, m: int, itemsize: int,
     pays two: the bucket exchange and the rank rebalance).  This is the
     cluster-scale Eq. 3-4 term: temp-row operand movement priced per
     exchange, with the strategy choice reducing to *how many exchanges*.
-
-    ``alpha``/``per_byte`` override the link rates per call — this is the
-    two-tier hook: the planner prices ICI-only rounds with the profile's
-    default rates and DCN / mixed rounds with a ``Topology`` axis's
-    measured ones (see :func:`flat_collective_rates` and
-    :func:`hierarchical_sort_cost_ns`).
     """
     c = consts or _tuning.active().constants
-    a = alpha if alpha is not None else c.collective_alpha
-    b = per_byte if per_byte is not None else c.collective_per_byte
-    return a + b * n_dev * m * itemsize
-
-
-def flat_collective_rates(inner: int, outer: int, *,
-                          consts: DeviceSortConstants = None,
-                          ici_alpha: Optional[float] = None,
-                          ici_per_byte: Optional[float] = None,
-                          dcn_alpha: Optional[float] = None,
-                          dcn_per_byte: Optional[float] = None
-                          ) -> Tuple[float, float]:
-    """(alpha, per_byte) a FLAT all-to-all effectively pays on a two-tier
-    ``outer x inner`` mesh.
-
-    With destinations spread uniformly over ``D = outer*inner`` devices, a
-    fraction ``(outer-1)/outer`` of every device's exchanged bytes crosses
-    the slow outer (DCN) tier and the rest stays on ICI — so the flat
-    round runs at the traffic-weighted blend of the two per-byte rates,
-    and its launch latency is the slower tier's (the round completes when
-    the slowest link does).  ``outer <= 1`` degrades to pure ICI.
-    """
-    c = consts or _tuning.active().constants
-    ia = ici_alpha if ici_alpha is not None else c.collective_alpha
-    ib = ici_per_byte if ici_per_byte is not None else c.collective_per_byte
-    da = dcn_alpha if dcn_alpha is not None else c.dcn_alpha
-    db = dcn_per_byte if dcn_per_byte is not None else c.dcn_per_byte
-    if outer <= 1:
-        return ia, ib
-    f_dcn = (outer - 1) / outer
-    return max(ia, da), ib * (1.0 - f_dcn) + db * f_dcn
+    return c.collective_alpha + c.collective_per_byte * n_dev * m * itemsize
 
 
 def distributed_sort_cost_ns(strategy: str, n: int, n_dev: int,
                              itemsize: int = 4, *,
-                             consts: DeviceSortConstants = None,
-                             alpha: Optional[float] = None,
-                             per_byte: Optional[float] = None) -> float:
+                             consts: DeviceSortConstants = None) -> float:
     """Estimated ns to globally sort ``n`` elements over ``n_dev`` devices.
 
     Both strategies pay the same local shard sort; they differ in movement
@@ -428,21 +388,14 @@ def distributed_sort_cost_ns(strategy: str, n: int, n_dev: int,
     so odd-even wins at small (n, D) on collective launch count and sample
     wins once the per-round merge work dominates — the planner picks the
     winner per workload (``planner.choose_distributed``).
-
-    ``alpha``/``per_byte`` override the collective link rates (see
-    :func:`collective_cost_ns`): on a hierarchical mesh the planner prices
-    the flat strategies at the blended two-tier rate from
-    :func:`flat_collective_rates`.
     """
     c = consts or _tuning.active().constants
     m = -(-n // n_dev)
     local = c.xla * m * _log2(m)
     if strategy == "oddeven":
         round_merge = c.bitonic * (2 * m) * _log2(2 * m)
-        return local + n_dev * (
-            collective_cost_ns(1, m, itemsize, c,
-                               alpha=alpha, per_byte=per_byte)
-            + round_merge)
+        return local + n_dev * (collective_cost_ns(1, m, itemsize, c)
+                                + round_merge)
     if strategy == "sample":
         # r*m·log r aggregates the capacity-padded exchange staging and
         # merge tree over received runs; + m covers the rank-rebalance
@@ -450,70 +403,9 @@ def distributed_sort_cost_ns(strategy: str, n: int, n_dev: int,
         # the measured one (README §Distributed sort)
         r = 1 << max(0, (n_dev - 1).bit_length())
         merge = c.merge_level * ((r * m) * (_log2(r) if r > 1 else 0.0) + m)
-        return local + 2 * collective_cost_ns(n_dev, m, itemsize, c,
-                                              alpha=alpha,
-                                              per_byte=per_byte) + merge
+        return local + 2 * collective_cost_ns(n_dev, m, itemsize, c) + merge
     raise ValueError(
         f"no distributed cost model for strategy {strategy!r}")
-
-
-def hierarchical_sort_cost_ns(n: int, inner: int, outer: int,
-                              itemsize: int = 4, *,
-                              consts: DeviceSortConstants = None,
-                              ici_alpha: Optional[float] = None,
-                              ici_per_byte: Optional[float] = None,
-                              dcn_alpha: Optional[float] = None,
-                              dcn_per_byte: Optional[float] = None) -> float:
-    """Estimated ns for the two-level hierarchical sample-sort over an
-    ``outer x inner`` mesh (``outer`` hosts on DCN, ``inner`` devices per
-    host on ICI) — the distributed analogue of the paper's partition /
-    temp-row structure, restructured around the link hierarchy the way
-    Mutlu et al. prescribe.
-
-    Four terms:
-
-      local        one m·log m shard sort (identical to the flat path's)
-      merge        the SAME fitted r·m·log r staging/merge aggregate the
-                   flat ``sample`` strategy pays: both schedules merge
-                   every element through ~log D tree levels in total —
-                   the hierarchy redistributes the levels across rounds,
-                   it does not add asymptotic merge work.  Pricing it
-                   identically makes the flat-vs-hier decision hinge on
-                   MOVEMENT, the paper's actual claim.
-      intra rounds ICI confinement costs three inner-way all-to-alls:
-                   the opening exchange, the intra-host rebalance, and
-                   the finalize exchange after the DCN round (each host
-                   receives its key range spread over its devices with
-                   no inter-device order, so one more splitter round
-                   must restore it).
-      inter round  ONE outer-way bucket all-to-all at the DCN rate (the
-                   second splitter round — splitters travel by
-                   all-gather, priced into the launch term).
-      rebalance    the final rank-directed shard materialisation.  With
-                   balanced global splitters almost every element's final
-                   rank lands on its own host, so the exchange volume runs
-                   at the ICI rate plus an O(m) cross-host spill at the
-                   DCN rate — this locality is exactly why the
-                   hierarchical structure beats the flat all-to-all when
-                   DCN is the bottleneck, and why it loses (three ICI
-                   rounds of pure overhead) when the tiers are uniform.
-    """
-    c = consts or _tuning.active().constants
-    ia = ici_alpha if ici_alpha is not None else c.collective_alpha
-    ib = ici_per_byte if ici_per_byte is not None else c.collective_per_byte
-    da = dcn_alpha if dcn_alpha is not None else c.dcn_alpha
-    db = dcn_per_byte if dcn_per_byte is not None else c.dcn_per_byte
-    d = max(1, inner) * max(1, outer)
-    m = -(-n // d)
-    local = c.xla * m * _log2(m)
-    r = 1 << max(0, (d - 1).bit_length())
-    merge = c.merge_level * ((r * m) * (_log2(r) if r > 1 else 0.0) + m)
-    intra = 3 * collective_cost_ns(inner, m, itemsize, c,
-                                   alpha=ia, per_byte=ib)
-    inter = collective_cost_ns(outer, m, itemsize, c,
-                               alpha=da, per_byte=db)
-    rebalance = max(ia, da) + ib * d * m * itemsize + db * m * itemsize
-    return local + merge + intra + inter + rebalance
 
 
 # ---- report helpers ----------------------------------------------------------

@@ -8,9 +8,8 @@ scale the same structure maps 1:1 onto a device mesh:
     intra-stage parallelism ->  SPMD over the mesh axis
     temp-row exchange       ->  shard exchange over ICI
 
-One entry point, three strategies behind it (``strategy="auto"`` prices
-them with ``planner.choose_distributed``, on two-axis meshes against the
-link rates of the mesh's ``core.topology.Topology``):
+One entry point, two strategies behind it (``strategy="auto"`` prices
+them with ``planner.choose_distributed``):
 
   ``oddeven``  odd-even transposition merge: D rounds of neighbour
                ppermute + bitonic merge-split.  Minimal per-round state,
@@ -25,12 +24,8 @@ link rates of the mesh's ``core.topology.Topology``):
                uneven lengths, descending, and key-value payloads (the
                keycodec reduces them all to one ascending unsigned sort),
                so any request odd-even cannot express routes here
-               regardless of the cost model.
-  ``hier``     two-level hierarchical sample-sort (same module): intra-host
-               round over the fast inner tier, ONE chunked cross-host
-               exchange over the slow outer tier, intra-host finalize.
-               Needs a two-axis ``(outer, inner)`` mesh; auto picks it
-               when the topology's tier rates say the slow tier dominates.
+               regardless of the cost model.  Over several mesh axes it
+               is the only strategy.
 
 The odd-even collective cost is one shard (m elements) over ICI per round
 per device pair: ``collective_bytes(D, m) = D * m * itemsize`` per device —
@@ -115,10 +110,8 @@ def distributed_sort(x: jnp.ndarray, mesh: Mesh, axis_name=None,
     ``(keys, values)`` when a payload rides along).
 
     ``strategy`` is ``"auto"`` (cost-model pick via
-    ``planner.choose_distributed`` — on a two-axis mesh the candidates
-    are priced against the mesh's topology tier rates), ``"sample"``
-    (single-round flat sample-sort), ``"hier"`` (two-level hierarchical
-    sample-sort; needs a two-axis mesh) or ``"oddeven"`` (D-round
+    ``planner.choose_distributed``), ``"sample"`` (single-round
+    sample-sort over every named axis) or ``"oddeven"`` (D-round
     transposition merge; single-axis only).  Requests odd-even cannot
     express — uneven lengths, ``descending``, payloads — always route to
     sample-sort; forcing ``strategy="oddeven"`` for one of those raises.
@@ -130,43 +123,36 @@ def distributed_sort(x: jnp.ndarray, mesh: Mesh, axis_name=None,
     vocab-scale shard gets tiled run generation + merge tree while a small
     one stays on a single-tile backend.
     """
-    from repro.core import topology as _topology
+    from repro.core.topology import auto_mesh, to_auto_mesh
     from repro.engine import planner, samplesort
-    mesh = _topology.auto_mesh(mesh)
-    x = _topology.to_auto_mesh(x)
+    mesh = auto_mesh(mesh)
+    x = to_auto_mesh(x)
     axes = samplesort._axes_tuple(mesh, axis_name)
     n_dev = samplesort._n_dev(mesh, axes)
     multi = len(axes) > 1
     n = x.shape[-1]
     needs_sample = bool(descending or values is not None or n % n_dev)
     if strategy == "auto":
-        topo = _topology.for_mesh(mesh, axes) if multi else None
-        plan = planner.choose_distributed_cached(n, n_dev, x.dtype,
-                                                 topology=topo)
+        plan = planner.choose_distributed_cached(n, n_dev, x.dtype)
         # odd-even is a single-axis, even-length, ascending, value-only
         # schedule — drop it from the running when the request (or the
         # mesh shape) rules it out and take the cheapest remaining
         usable = {s: c for s, c in plan.costs.items()
                   if s != "oddeven" or not (needs_sample or multi)}
         strategy = min(usable, key=usable.__getitem__)
-    if strategy not in ("sample", "oddeven", "hier"):
+    if strategy not in ("sample", "oddeven"):
         raise ValueError(
-            f"strategy must be 'auto', 'sample', 'hier' or 'oddeven', "
+            f"strategy must be 'auto', 'sample' or 'oddeven', "
             f"got {strategy!r}")
-    if strategy == "hier" and len(axes) != 2:
-        raise ValueError(
-            f"strategy='hier' needs a two-axis (outer, inner) mesh; "
-            f"got axes {axes}")
-    if strategy in ("sample", "hier"):
+    if strategy == "sample":
         return samplesort.sample_sort(x, mesh, axes, values=values,
                                       descending=descending,
                                       local_method=local_method,
-                                      hierarchical=(strategy == "hier"),
                                       interpret=interpret)
     if multi:
         raise ValueError(
             "oddeven transposition runs over ONE mesh axis; pass a single "
-            f"axis name or use strategy='sample'/'hier' (got axes {axes})")
+            f"axis name or use strategy='sample' (got axes {axes})")
     axis_name = axes[0]
     if needs_sample:
         raise ValueError(
@@ -218,8 +204,7 @@ def distributed_topk(x: jnp.ndarray, k: int, mesh: Mesh,
     """Mesh-global top-k -> ``(values, indices)``, bit-exact with
     ``jax.lax.top_k`` (values descending, ties keep the lowest global
     index).  ``axis_name`` follows ``distributed_sort``: one axis, a
-    tuple, or ``None`` for the whole mesh (the candidate all-gather is
-    tiny, so there is no hierarchical variant to pick).
+    tuple, or ``None`` for the whole mesh.
 
     There is only one strategy here on purpose: selection makes the
     strategy question moot.  Both full-sort strategies move O(m) per

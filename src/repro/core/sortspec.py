@@ -346,9 +346,9 @@ class SortSpec:
         if axis_name is not None and self.mesh is None:
             raise ValueError("axis_name requires a mesh")
         if self.mesh is not None:
-            # one axis name, a tuple of axes (hierarchical meshes), or
-            # None -> the whole mesh; normalised to a validated tuple by
-            # the same helper every distributed consumer uses
+            # one axis name, a tuple of axes, or None -> the whole
+            # mesh; normalised to a validated tuple by the same helper
+            # every distributed consumer uses
             from repro.engine.samplesort import _axes_tuple
             axis_name = _axes_tuple(self.mesh, axis_name)
             if ndim != 1:

@@ -126,10 +126,8 @@ class DeviceSortConstants:
     pallas_interpret_penalty: float = 300.0   # CPU interpret-mode multiplier
     # mesh collectives (distributed dispatch): one collective round costs
     # alpha (launch/latency) + bytes-moved-per-device / bandwidth.  The
-    # ici pair prices the fast intra-host tier; the dcn pair the ~10x
-    # slower inter-host tier (repro.core.topology derives its default
-    # per-axis link rates from these, and a calibrated Topology overrides
-    # them per mesh axis).
+    # dcn pair prices nothing: it stays in the schema because persisted
+    # profiles carry it and from_dict rejects unknown constants.
     collective_alpha: float = 2_000.0         # ns per collective launch
     collective_per_byte: float = 0.02         # ns/byte (~50 GB/s ICI link)
     dcn_alpha: float = 20_000.0               # ns per cross-host launch

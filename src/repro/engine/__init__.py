@@ -1,7 +1,7 @@
-"""repro.engine — hierarchical out-of-core sort engine.
+"""repro.engine — out-of-core sort engine: tiled runs and a merge tree.
 
 Completes the memory hierarchy between one VMEM tile (kernels/bitonic_sort)
-and the device mesh (core/distributed_sort):
+and the device mesh (engine/samplesort, core/distributed_sort):
 
     SRAM array  ->  VMEM tile  ->  engine runs + merge tree  ->  mesh shards
 

@@ -1,55 +1,20 @@
 """Collectives facade — the one home for the distributed sort's exchanges.
 
-Every mesh collective the sorting stack issues goes through here, so the
-movement accounting the paper centres on (Eq. 3-4: operand exchange priced
-per link crossed) has a single chokepoint:
-
-  * :func:`all_to_all` — the tiled bucket exchange, over one mesh axis or
-    a tuple of axes (the flat degenerate case of the hierarchy).
-  * :func:`chunked_all_to_all` — the same exchange split into ``chunks``
-    independent collectives over contiguous slices of each bucket.  Each
-    slice of a sorted run is itself sorted, so the consumer merges
-    ``D * chunks`` shorter runs instead of ``D`` long ones — the merge
-    tree's first levels depend only on the first chunk, which lets the
-    scheduler overlap the remaining (slow-tier DCN) transfers with local
-    merge work instead of serialising transfer-then-merge.
-  * the **int8 wire codec** — opt-in lossy compression of float *payload*
-    buckets on the slow tier, reusing ``optim/grad_compress``'s scheme
-    (per-bucket absmax scale, round-to-nearest int8).  Keys are never
-    compressed: the sort order must stay bit-exact; only the payload the
-    caller explicitly marked compressible rides the narrow format.
-  * :func:`record_exchange` — per-tier byte counters
-    (``collectives.ici_bytes`` / ``collectives.dcn_bytes``) so the obs
-    subsystem sees how much traffic each tier of the topology carried.
-
-The first three run inside jitted ``shard_map`` programs; the counters are
-host-side (obs is zero-overhead when disabled, and counters cannot tick
-inside a trace anyway) — callers record the analytic volume next to the
-program launch, exactly like ``samplesort.alltoall_bytes``.
+:func:`all_to_all` is the tiled bucket exchange, over one mesh axis or a
+tuple of axes.  It runs inside jitted ``shard_map`` programs; the
+movement it costs is counted host-side by its callers
+(``samplesort.alltoall_bytes``), next to the program launch.
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple, Union
+from typing import Tuple, Union
 
 import jax
 import jax.numpy as jnp
 
-from repro.obs import metrics, trace as _obs
-
-__all__ = [
-    "AxisName", "all_to_all", "chunked_all_to_all", "pipeline_chunks",
-    "wire_encode_int8", "wire_decode_int8", "wire_bytes_saved",
-    "record_exchange", "DEFAULT_PIPELINE_CHUNKS", "WIRE_CODECS",
-]
+__all__ = ["AxisName", "all_to_all"]
 
 AxisName = Union[str, Tuple[str, ...]]
-
-# how many slices the slow-tier bucket exchange is split into by default:
-# enough that the first merge levels start ~1/4 of the way into the
-# transfer, few enough that per-collective launch overhead stays noise
-DEFAULT_PIPELINE_CHUNKS = 4
-
-WIRE_CODECS = ("int8",)
 
 
 def _axis_arg(axis_name: AxisName):
@@ -69,109 +34,3 @@ def all_to_all(v: jnp.ndarray, axis_name: AxisName) -> jnp.ndarray:
     return jax.lax.all_to_all(v, _axis_arg(axis_name), split_axis=0,
                               concat_axis=0, tiled=True)
 
-
-def pipeline_chunks(capacity: int, requested: Optional[int] = None) -> int:
-    """The realizable chunk count for a bucket of ``capacity`` slots: the
-    largest power of two <= ``requested`` that divides the capacity (a
-    chunk must be a whole slice of every bucket).  Odd capacities pipeline
-    at 1 — correctness never depends on the split."""
-    req = DEFAULT_PIPELINE_CHUNKS if requested is None else requested
-    req = max(1, req)
-    chunks = 1
-    while chunks * 2 <= min(req, capacity) and capacity % (chunks * 2) == 0:
-        chunks *= 2
-    return chunks
-
-
-def chunked_all_to_all(v: jnp.ndarray, axis_name: AxisName, *,
-                       chunks: int = 1) -> jnp.ndarray:
-    """(D, c) -> (D, chunks, c // chunks): the bucket exchange issued as
-    ``chunks`` independent collectives over contiguous bucket slices.
-
-    ``out[j, i]`` is slice ``i`` of the bucket device ``j`` sent here; a
-    contiguous slice of a sorted bucket is itself sorted, so the receiver
-    treats the result as ``D * chunks`` sorted runs.  Splitting the
-    exchange is what buys transfer/merge overlap on the slow tier — the
-    merge tree's early levels consume chunk 0 while later chunks are
-    still in flight (on a single-stream backend the chunks simply run
-    back-to-back; the result is identical either way).
-    """
-    d, c = v.shape
-    if chunks <= 1:
-        return all_to_all(v, axis_name)[:, None, :]
-    if c % chunks:
-        raise ValueError(
-            f"bucket capacity {c} is not divisible by chunks={chunks} "
-            f"(use pipeline_chunks to pick a realizable count)")
-    pieces = v.reshape(d, chunks, c // chunks)
-    outs = [all_to_all(pieces[:, i, :], axis_name) for i in range(chunks)]
-    return jnp.stack(outs, axis=1)
-
-
-# ---------------------------------------------------------------------------
-# int8 wire codec (grad_compress's scheme, applied to exchange buckets)
-# ---------------------------------------------------------------------------
-
-def wire_encode_int8(v: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """(D, c) float buckets -> (int8 buckets, (D, 1) f32 scales).
-
-    Per-bucket absmax scaling with round-to-nearest — the exact scheme
-    ``optim/grad_compress`` ships for momentum tensors.  Lossy: only the
-    payload side of a key-value exchange may ride this, and only when the
-    caller opted in (``wire_codec="int8"``); keys always travel wide.
-    """
-    a = jnp.max(jnp.abs(v.astype(jnp.float32)), axis=-1, keepdims=True)
-    scale = a / 127.0
-    safe = jnp.where(scale > 0, scale, 1.0)
-    q = jnp.clip(jnp.round(v.astype(jnp.float32) / safe), -127, 127)
-    return q.astype(jnp.int8), safe.astype(jnp.float32)
-
-
-def wire_decode_int8(q: jnp.ndarray, scale: jnp.ndarray,
-                     dtype) -> jnp.ndarray:
-    """Inverse of :func:`wire_encode_int8` (up to quantisation error)."""
-    return (q.astype(jnp.float32) * scale).astype(dtype)
-
-
-def wire_bytes_saved(n_dev: int, capacity: int, itemsize: int) -> int:
-    """Bytes the int8 codec keeps off the wire for one payload exchange:
-    each slot shrinks to 1 byte + a 4-byte per-bucket scale."""
-    wide = n_dev * capacity * itemsize
-    narrow = n_dev * capacity * 1 + n_dev * 4
-    return max(0, wide - narrow)
-
-
-# ---------------------------------------------------------------------------
-# per-tier movement accounting (host-side; obs no-ops when disabled)
-# ---------------------------------------------------------------------------
-
-def record_exchange(tier: str, nbytes: int) -> None:
-    """Count ``nbytes`` of collective traffic against a topology tier.
-    Callers pass the analytic per-round volume (they know capacity and
-    fan-out); the counter names are stable obs API:
-    ``collectives.ici_bytes`` / ``collectives.dcn_bytes``."""
-    if not _obs.enabled() or nbytes <= 0:
-        return
-    metrics.counter(f"collectives.{tier}_bytes").inc(int(nbytes))
-
-
-def record_split_exchange(nbytes: int, inner: int, outer: int) -> None:
-    """Account one FLAT exchange over an ``outer x inner`` two-tier mesh:
-    with destinations uniform over the mesh, ``(outer-1)/outer`` of the
-    traffic crosses DCN and the rest stays on ICI (the same split
-    ``cost_model.flat_collective_rates`` prices)."""
-    if outer <= 1:
-        record_exchange("ici", nbytes)
-        return
-    f_dcn = (outer - 1) / outer
-    record_exchange("dcn", int(nbytes * f_dcn))
-    record_exchange("ici", int(nbytes * (1.0 - f_dcn)))
-
-
-def axis_sizes(mesh, axes: Sequence[str]) -> Tuple[int, ...]:
-    """Mesh axis sizes in the given order (validating membership)."""
-    for a in axes:
-        if a not in mesh.axis_names:
-            raise ValueError(f"axis {a!r} not in mesh axes "
-                             f"{tuple(mesh.axis_names)}")
-    return tuple(int(mesh.shape[a]) for a in axes)

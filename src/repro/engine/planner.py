@@ -244,7 +244,7 @@ def choose_relational_cached(op: str, n: int, batch: int = 1,
 
 
 # ---------------------------------------------------------------------------
-# distributed dispatch — sample-sort vs odd-even vs hierarchical
+# distributed dispatch — sample-sort vs odd-even
 # ---------------------------------------------------------------------------
 
 DIST_STRATEGIES = ("sample", "oddeven")
@@ -253,13 +253,12 @@ DIST_STRATEGIES = ("sample", "oddeven")
 @dataclasses.dataclass(frozen=True)
 class DistPlan:
     """Dispatch decision for a mesh-global sort of n over n_dev devices."""
-    strategy: str                # "sample" | "oddeven" | "hier"
+    strategy: str                # "sample" | "oddeven"
     n_dev: int
     costs: Dict[str, float]      # estimated ns per strategy
 
 
-def choose_distributed(n: int, n_dev: int, dtype=jnp.float32, *,
-                       topology=None) -> DistPlan:
+def choose_distributed(n: int, n_dev: int, dtype=jnp.float32) -> DistPlan:
     """Price the distributed strategies and return the cheapest one.
 
     Odd-even transposition pays D collective launches but only a bitonic
@@ -267,76 +266,29 @@ def choose_distributed(n: int, n_dev: int, dtype=jnp.float32, *,
     plus one merge-path tree.  Small (n, D) therefore stays on odd-even
     and large workloads cross over to the single-round exchange — the
     mesh-level mirror of the engine's run-length crossover.
-
-    With a two-tier ``topology`` (``core.topology.Topology``, e.g. from
-    ``Topology.for_mesh``) a third candidate joins: the **hierarchical**
-    two-level sample-sort, priced per tier
-    (``cost_model.hierarchical_sort_cost_ns``) while the flat strategies
-    pay the *blended* two-tier link rate
-    (``cost_model.flat_collective_rates`` — a flat exchange sends an
-    ``(outer-1)/outer`` fraction of its traffic over the slow tier).
-    Flat wins on uniform meshes (the hierarchy's extra intra rounds are
-    pure overhead there); hierarchical wins once the slow tier is
-    skewed enough that confining most traffic to the fast tier pays.
     """
     itemsize = jnp.dtype(dtype).itemsize
     consts = constants()
-    hier = topology is not None and topology.is_hierarchical \
-        and len(topology.axes) >= 2
-    if not hier:
-        costs = {
-            s: cost_model.distributed_sort_cost_ns(s, n, n_dev, itemsize,
-                                                   consts=consts)
-            for s in DIST_STRATEGIES
-        }
-        return DistPlan(strategy=min(costs, key=costs.__getitem__),
-                        n_dev=n_dev, costs=costs)
-    if topology.n_devices != n_dev:
-        raise ValueError(
-            f"topology spans {topology.n_devices} devices, the sort "
-            f"plans for {n_dev}")
-    outer = topology.axes[0]
-    innermost = topology.axes[-1]
-    inner_size = n_dev // outer.size
-    ia, ib = innermost.latency_ns, innermost.per_byte_ns
-    da, db = outer.latency_ns, outer.per_byte_ns
-    fa, fb = cost_model.flat_collective_rates(
-        inner_size, outer.size, ici_alpha=ia, ici_per_byte=ib,
-        dcn_alpha=da, dcn_per_byte=db)
     costs = {
         s: cost_model.distributed_sort_cost_ns(s, n, n_dev, itemsize,
-                                               consts=consts,
-                                               alpha=fa, per_byte=fb)
+                                               consts=consts)
         for s in DIST_STRATEGIES
     }
-    costs["hier"] = cost_model.hierarchical_sort_cost_ns(
-        n, inner_size, outer.size, itemsize, consts=consts,
-        ici_alpha=ia, ici_per_byte=ib, dcn_alpha=da, dcn_per_byte=db)
     return DistPlan(strategy=min(costs, key=costs.__getitem__),
                     n_dev=n_dev, costs=costs)
 
 
-def choose_distributed_cached(n: int, n_dev: int, dtype=jnp.float32, *,
-                              topology=None) -> DistPlan:
+def choose_distributed_cached(n: int, n_dev: int,
+                              dtype=jnp.float32) -> DistPlan:
     """``choose_distributed`` memoized alongside the single-device plans —
-    same invalidation rules (calibration state, registry generation) plus
-    the topology generation and *full* per-axis identity, so
-    ``topology.calibrate()`` or swapping the active topology transparently
-    re-plans.  The key carries the link rates, not just the mesh shape:
-    two same-shaped topologies with different tier rates are different
-    pricing problems and must never share a plan."""
-    from repro.core import topology as _topo
-    tsig = None if topology is None else tuple(
-        (a.name, a.size, a.tier, a.bandwidth_bytes_per_s, a.latency_ns)
-        for a in topology.axes)
+    same invalidation rules (calibration state, registry generation)."""
     with _obs.trace("planner.choose") as sp:
-        key = ("dist", n, n_dev, jnp.dtype(dtype).name, tsig,
-               _topo.generation(), _tuning.generation(),
-               sortspec.registry_generation(), jax.default_backend())
+        key = ("dist", n, n_dev, jnp.dtype(dtype).name,
+               _tuning.generation(), sortspec.registry_generation(),
+               jax.default_backend())
         plan = _cached(sp, key)
         if plan is None:
-            plan = _PLAN_CACHE[key] = choose_distributed(
-                n, n_dev, dtype, topology=topology)
+            plan = _PLAN_CACHE[key] = choose_distributed(n, n_dev, dtype)
     return plan
 
 

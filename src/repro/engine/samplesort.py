@@ -1,4 +1,4 @@
-"""Distributed sample-sort over an explicit device topology.
+"""Distributed sample-sort over a device mesh.
 
 ``core/distributed_sort.py``'s odd-even transposition moves every shard D
 times over the interconnect.  This module is the cluster-scale analogue of
@@ -7,41 +7,19 @@ and pay the Eq. 3-4 temp-row cycles to exchange operands once per stage):
 local sort -> splitters -> ONE capacity-padded bucket all-to-all -> merge ->
 rank-directed rebalance.
 
-PR 10 reworks the exchange onto ``engine/collectives.py`` and a two-level
-**hierarchical** mode for meshes whose axes span two interconnect tiers
-(fast intra-host ICI, ~10x slower inter-host DCN — ``core/topology.py``):
+``axis_name`` may be one mesh axis, a tuple of axes, or ``None`` for all
+of them.  Over several axes the devices are ordered row-major (outer axis
+major), the order tuple-axis collectives use, and the schedule is the
+same: one splitter round, one bucket exchange and one rank rebalance over
+every named axis.
 
-  flat (one tier, the degenerate case)
-      local sort -> global splitters -> one all-to-all over ALL mesh axes
-      -> merge -> global rebalance.  Every element crosses the slow tier
-      inside one big exchange.
-
-  hierarchical (two tiers, ``axes = (outer=DCN, inner=ICI)``)
-      1. local sort + **intra-host** splitters            (phase 1)
-      2. ICI bucket exchange + merge + intra-host rebalance,
-         then **outer** splitters over the host-sorted shards (phase 2)
-      3. DCN bucket exchange — chunked/pipelined, optional int8 wire
-         codec on the payload — + merge + compaction, then per-host
-         sub-splitters over the received pool                (phase 3)
-      4. ICI finalize exchange + merge + **global** rebalance (phase 4)
-
-    The second ICI round (phase 4) is load-bearing: after the DCN round,
-    host g holds exactly the keys of global range g, but spread over its
-    devices with *no* inter-device order — each device received only from
-    its same-inner-position peers.  One more intra-host splitter round
-    restores a total order before the rank arithmetic of the rebalance.
-
-Both modes live behind the same ``sample_sort`` entry; ``axis_name`` may
-be one mesh axis, a tuple of axes, or ``None`` for all of them, and
-``hierarchical=None`` auto-selects the two-level path on two-axis meshes.
-
-The all-to-alls need static per-(source, destination) bucket capacities;
-each phase boundary syncs the measured bucket maximum to the host, inside
-a ``samplesort.sync`` span (attributes ``max_bucket``, ``capacity``), and
-the next jitted program is compiled at that capacity (with the tuning
-profile's slack so nearby workloads share executables).  The flat path's
-two programs are named ``samplesort_phase1`` and ``samplesort_phase2``, so
-a device trace tells them apart.
+The all-to-all needs a static per-(source, destination) bucket capacity:
+between the two jitted programs the measured bucket maximum is synced to
+the host, inside a ``samplesort.sync`` span (attributes ``max_bucket``,
+``capacity``), and the exchange is compiled at that capacity (with the
+tuning profile's slack so nearby workloads share executables).  The two
+programs are named ``samplesort_phase1`` and ``samplesort_phase2``, so a
+device trace tells them apart.
 
 Everything runs on **encoded keys** (``core/keycodec.py``): signed ints,
 floats and ``descending`` all reduce to one ascending unsigned sort, and
@@ -197,9 +175,8 @@ def _lin_index(mesh: Mesh, axes: Tuple[str, ...]) -> jnp.ndarray:
 
 
 def _pick_merge_backend(run_len: int) -> str:
-    """Default merge backend for runs of ``run_len`` slots (the same rule
-    the flat path always used, parameterised so each hierarchical phase
-    picks for its own capacity)."""
+    """Default merge backend for received runs of ``run_len`` slots (the
+    exchange capacity)."""
     if jax.default_backend() == "tpu":
         # a stable sort of the received runs: the ranking merges are
         # gather-bound there (engine/merge.py)
@@ -233,72 +210,50 @@ def _send_buffer(x, starts, vcnt, c, fill):
 
 
 def _exchange_merge(ks, vs, starts, vcnt, coll_axis, p, c,
-                    maxkey, merge_backend, interpret, *,
-                    chunks: int = 1, wire_codec: Optional[str] = None):
-    """One bucket exchange round over ``coll_axis`` (fan-out ``p``) plus
-    the merge of the received runs.
+                    maxkey, merge_backend, interpret):
+    """The bucket exchange over ``coll_axis`` (fan-out ``p``) plus the
+    merge of the received runs.
 
     ``ks`` is a sorted local pool cut into ``p`` contiguous buckets by
     ``starts``/``vcnt`` (genuine-key counts).  Send buffers are
-    capacity-``c`` padded with ``maxkey``; with ``chunks > 1`` the
-    exchange is issued as that many collectives over contiguous bucket
-    slices (``collectives.chunked_all_to_all``) so the receiver merges
-    ``p * chunks`` shorter runs and the early merge levels overlap the
-    in-flight tail of a slow-tier transfer.  ``wire_codec='int8'`` sends
-    the *payload* buckets through the lossy grad_compress codec (keys
-    always travel wide).
+    capacity-``c`` padded with ``maxkey``.
 
     Returns ``(mk, mv, mvalid, recv_cnt)``: merged keys (length
-    ``next_pow2(p * chunks) * (c // chunks)``), merged payload (or None),
-    per-slot validity recovered through the merge's position payload, and
-    the (p,) genuine-key counts received from each source.
+    ``next_pow2(p) * c``), merged payload (or None), per-slot validity
+    recovered through the merge's position payload, and the (p,)
+    genuine-key counts received from each source.
     """
     sendk = _send_buffer(ks, starts, vcnt, c, maxkey)
-    recvk = coll.chunked_all_to_all(sendk, coll_axis, chunks=chunks)
+    runs = coll.all_to_all(sendk, coll_axis)                     # (p, c)
     recv_cnt = coll.all_to_all(vcnt[:, None], coll_axis)[:, 0]   # (p,)
 
-    cp = c // chunks
-    n_runs = p * chunks
-    r_runs = next_pow2(n_runs)
-    runs = recvk.reshape(n_runs, cp)
-    if r_runs != n_runs:
+    # the merge takes a power-of-two number of runs; p need not be one
+    r_runs = next_pow2(p)
+    if r_runs != p:
         runs = jnp.concatenate(
-            [runs, jnp.full((r_runs - n_runs, cp), maxkey, runs.dtype)])
+            [runs, jnp.full((r_runs - p, c), maxkey, runs.dtype)])
     # one int32 position payload rides the merge; validity flags (and the
     # user payload) are recovered by gathering through it, so ties between
     # capacity fill and genuine max keys cannot corrupt anything
-    pos = jnp.arange(r_runs * cp, dtype=jnp.int32).reshape(1, r_runs, cp)
+    pos = jnp.arange(r_runs * c, dtype=jnp.int32).reshape(1, r_runs, c)
     mk, mpos = merge_runs(runs[None], pos, descending=False,
                           backend=merge_backend, interpret=interpret)
-    mk, mpos = mk[0], mpos[0]                                    # (R*cp,)
+    mk, mpos = mk[0], mpos[0]                                    # (R*c,)
 
-    # valid slots are a prefix of each *bucket*; slice i of bucket j holds
-    # clip(cnt_j - i*cp, 0, cp) of them
-    piece_valid = jnp.clip(
-        recv_cnt[:, None] - jnp.arange(chunks, dtype=jnp.int32)[None, :] * cp,
-        0, cp)                                                   # (p, chunks)
-    run_valid = (jnp.arange(cp, dtype=jnp.int32)[None, :]
-                 < piece_valid.reshape(-1)[:, None])             # (n_runs, cp)
-    if r_runs != n_runs:
+    # valid slots are a prefix of each received run
+    run_valid = jnp.arange(c, dtype=jnp.int32)[None, :] < recv_cnt[:, None]
+    if r_runs != p:
         run_valid = jnp.concatenate(
-            [run_valid, jnp.zeros((r_runs - n_runs, cp), bool)])
+            [run_valid, jnp.zeros((r_runs - p, c), bool)])
     mvalid = run_valid.reshape(-1)[mpos]
 
     mv = None
     if vs is not None:
         sendv = _send_buffer(vs, starts, vcnt, c, jnp.zeros((), vs.dtype))
-        if wire_codec == "int8":
-            q, scale = coll.wire_encode_int8(sendv)
-            rq = coll.chunked_all_to_all(q, coll_axis, chunks=chunks)
-            rs = coll.all_to_all(scale, coll_axis)
-            recvv = coll.wire_decode_int8(rq.reshape(p, c), rs, vs.dtype)
-        else:
-            recvv = coll.chunked_all_to_all(sendv, coll_axis,
-                                            chunks=chunks).reshape(p, c)
-        vflat = recvv.reshape(-1)
-        if r_runs != n_runs:
+        vflat = coll.all_to_all(sendv, coll_axis).reshape(-1)
+        if r_runs != p:
             vflat = jnp.concatenate(
-                [vflat, jnp.zeros(((r_runs - n_runs) * cp,), vflat.dtype)])
+                [vflat, jnp.zeros(((r_runs - p) * c,), vflat.dtype)])
         mv = vflat[mpos]
     return mk, mv, mvalid, recv_cnt
 
@@ -314,31 +269,30 @@ def _running_count(flags, width: int = 1024):
     return (within + (jnp.cumsum(total) - total)[:, None]).reshape(-1)[:n]
 
 
-def _rebalance(mk, mv, mvalid, recv_cnt, coll_axis, group, m, my):
+def _rebalance(mk, mv, mvalid, recv_cnt, coll_axis, n_dev, m, my):
     """Rank-directed rebalance of a merged pool back to equal ``m``-slot
-    shards over ``group`` devices: rank r lives at slot ``r % m`` of
-    device ``r // m`` (``my`` is this device's rank-order index within
-    the group, matching ``coll_axis``'s device order).  Exactly one
-    device owns each slot, so the receive reduction is a plain sum over
-    sources (dtype pinned — accumulating zeros is exact, but sum would
-    promote narrow ints).  Tail slots past the group's valid count come
-    back ZERO, not maxkey — callers that feed the shard into another
-    search round must refill them."""
-    n_slots = group * m
+    shards over ``n_dev`` devices: rank r lives at slot ``r % m`` of
+    device ``r // m`` (``my`` is this device's linear index, matching
+    ``coll_axis``'s device order).  Exactly one device owns each slot, so
+    the receive reduction is a plain sum over sources (dtype pinned —
+    accumulating zeros is exact, but sum would promote narrow ints).
+    Slots past the global length ``n`` come back zero; the caller cuts
+    them off."""
+    n_slots = n_dev * m
     c_my = jnp.sum(recv_cnt).astype(jnp.int32)
-    counts_all = jax.lax.all_gather(c_my, coll_axis).reshape(-1)  # (group,)
-    offset = jnp.sum(jnp.where(jnp.arange(group) < my, counts_all, 0))
+    counts_all = jax.lax.all_gather(c_my, coll_axis).reshape(-1)  # (n_dev,)
+    offset = jnp.sum(jnp.where(jnp.arange(n_dev) < my, counts_all, 0))
     lrank = _running_count(mvalid) - 1
     grank = offset + lrank
     flat = jnp.where(mvalid, grank, n_slots)                  # OOB -> drop
     outk = jnp.zeros((n_slots,), mk.dtype).at[flat].set(
-        mk, mode="drop").reshape(group, m)
+        mk, mode="drop").reshape(n_dev, m)
     shard_k = jnp.sum(coll.all_to_all(outk, coll_axis), axis=0,
                       dtype=mk.dtype)
     shard_v = None
     if mv is not None:
         outv = jnp.zeros((n_slots,), mv.dtype).at[flat].set(
-            mv, mode="drop").reshape(group, m)
+            mv, mode="drop").reshape(n_dev, m)
         shard_v = jnp.sum(coll.all_to_all(outv, coll_axis), axis=0,
                           dtype=mv.dtype)
     return shard_k, shard_v
@@ -349,20 +303,16 @@ def _rebalance(mk, mv, mvalid, recv_cnt, coll_axis, group, m, my):
 # ---------------------------------------------------------------------------
 
 @functools.lru_cache(maxsize=128)
-def _phase1(mesh: Mesh, axes: Tuple[str, ...], part_axes: Tuple[str, ...],
-            n: int, kv: bool, padded: bool, local_method: Optional[str],
-            s: int, use_histogram: bool, interpret: Optional[bool]):
+def _phase1(mesh: Mesh, axes: Tuple[str, ...], n: int, kv: bool,
+            padded: bool, local_method: Optional[str], s: int,
+            use_histogram: bool, interpret: Optional[bool]):
     """Jitted program: encoded shard -> (sorted shard[, payload], starts,
-    vcnt).  ``axes`` is the full sharding (validity follows the linear
-    device index over it); ``part_axes`` is the group the splitters
-    partition over — all of ``axes`` for the flat path, the inner axis
-    only for the hierarchical first round.
+    vcnt).  Validity follows the linear device index over ``axes``.
 
     Cached on its statics so repeated serving-shape calls hit the compiled
     executable; the mesh participates in the key (jax meshes hash).
     """
     n_dev = _n_dev(mesh, axes)
-    p = _n_dev(mesh, part_axes)
     m = -(-n // n_dev)
 
     def samplesort_phase1(*args):
@@ -388,11 +338,10 @@ def _phase1(mesh: Mesh, axes: Tuple[str, ...], part_axes: Tuple[str, ...],
         else:
             ks = _front.sort(xs, method=local_method, interpret=interpret)
 
-        # regular samples -> pooled splitters (one tiny all-gather over
-        # the partition group)
+        # regular samples -> pooled splitters (one tiny all-gather)
         sample_pos = ((jnp.arange(s) + 1) * m) // (s + 1)
-        samples = jax.lax.all_gather(ks[sample_pos], _coll_axis(part_axes))
-        splitters = select_splitters(samples, p)
+        samples = jax.lax.all_gather(ks[sample_pos], _coll_axis(axes))
+        splitters = select_splitters(samples, n_dev)
 
         bounds = bucket_bounds(ks, splitters, use_histogram=use_histogram,
                                interpret=interpret)
@@ -413,14 +362,13 @@ def _phase1(mesh: Mesh, axes: Tuple[str, ...], part_axes: Tuple[str, ...],
 
 
 # ---------------------------------------------------------------------------
-# flat phase 2: bucket exchange + merge of the received runs + rebalance
+# phase 2: bucket exchange + merge of the received runs + rebalance
 # ---------------------------------------------------------------------------
 
 @functools.lru_cache(maxsize=128)
 def _phase2(mesh: Mesh, axes: Tuple[str, ...], n: int, kv: bool,
             capacity: int, key_dtype_name: str,
             val_dtype_name: Optional[str], merge_backend: str,
-            chunks: int, wire_codec: Optional[str],
             interpret: Optional[bool]):
     """Jitted program: (sorted shard[, payload], starts, vcnt) -> output
     shard(s).  ``capacity`` is the static per-(source, destination) bucket
@@ -440,7 +388,7 @@ def _phase2(mesh: Mesh, axes: Tuple[str, ...], n: int, kv: bool,
         my = _lin_index(mesh, axes)
         mk, mv, mvalid, recv_cnt = _exchange_merge(
             ks, vs, starts, vcnt, ax, n_dev, c, maxkey,
-            merge_backend, interpret, chunks=chunks, wire_codec=wire_codec)
+            merge_backend, interpret)
         shard_k, shard_v = _rebalance(mk, mv, mvalid, recv_cnt, ax,
                                       n_dev, m, my)
         if kv:
@@ -450,170 +398,6 @@ def _phase2(mesh: Mesh, axes: Tuple[str, ...], n: int, kv: bool,
     spec = P(axes)
     n_in = 4 if kv else 3
     fn = _smap(samplesort_phase2, mesh, (spec,) * n_in,
-               (spec, spec) if kv else spec)
-    return jax.jit(fn)
-
-
-# ---------------------------------------------------------------------------
-# hierarchical phases 2-4 (two-level: ICI round, DCN round, ICI finalize)
-# ---------------------------------------------------------------------------
-
-@functools.lru_cache(maxsize=128)
-def _hier_phase2(mesh: Mesh, outer: str, inner: str, n: int, kv: bool,
-                 c1: int, s2: int, key_dtype_name: str,
-                 val_dtype_name: Optional[str], merge_backend: str,
-                 use_histogram: bool, interpret: Optional[bool]):
-    """Intra-host round: ICI bucket exchange + merge + intra-host
-    rebalance, then the OUTER splitter prep.  In: phase-1 outputs (shard,
-    intra starts/vcnt).  Out: host-sorted equal shards + (d_out,) outer
-    bucket starts/vcnt."""
-    d_out = int(mesh.shape[outer])
-    d_in = int(mesh.shape[inner])
-    n_dev = d_out * d_in
-    m = -(-n // n_dev)
-    host_span = d_in * m
-    kdt = jnp.dtype(key_dtype_name)
-    maxkey = jnp.array(jnp.iinfo(kdt).max, kdt)
-
-    def local(*args):
-        if kv:
-            ks, vs, starts, vcnt = args
-        else:
-            (ks, starts, vcnt), vs = args, None
-        ho = jax.lax.axis_index(outer)
-        hi = jax.lax.axis_index(inner)
-
-        mk, mv, mvalid, recv_cnt = _exchange_merge(
-            ks, vs, starts, vcnt, inner, d_in, c1, maxkey,
-            merge_backend, interpret)
-        shard_k, shard_v = _rebalance(mk, mv, mvalid, recv_cnt, inner,
-                                      d_in, m, hi)
-
-        # after the intra rebalance, host g holds global slice
-        # [g*host_span, (g+1)*host_span) sorted across its devices; the
-        # rebalance zero-fills tail slots, which would corrupt the outer
-        # splitter search — refill with the max key (validity is analytic)
-        host_valid = jnp.clip(n - ho * host_span, 0, host_span)
-        my_valid = jnp.clip(host_valid - hi * m, 0, m).astype(jnp.int32)
-        slot = jnp.arange(m, dtype=jnp.int32)
-        shard_k = jnp.where(slot < my_valid, shard_k, maxkey)
-
-        # outer splitters: pooled over the WHOLE mesh (each host's shards
-        # are now sorted, so regular positions are proper quantiles)
-        sample_pos = ((jnp.arange(s2) + 1) * m) // (s2 + 1)
-        samples = jax.lax.all_gather(shard_k[sample_pos], (outer, inner))
-        splitters = select_splitters(samples, d_out)
-        bounds = bucket_bounds(shard_k, splitters,
-                               use_histogram=use_histogram,
-                               interpret=interpret)
-        vcnt2 = jnp.clip(jnp.minimum(bounds[1:], my_valid) - bounds[:-1],
-                         0, m).astype(jnp.int32)
-        if kv:
-            return shard_k, shard_v, bounds[:-1], vcnt2
-        return shard_k, bounds[:-1], vcnt2
-
-    spec = P((outer, inner))
-    n_in = 4 if kv else 3
-    fn = _smap(local, mesh, (spec,) * n_in, (spec,) * n_in)
-    return jax.jit(fn)
-
-
-@functools.lru_cache(maxsize=128)
-def _hier_phase3(mesh: Mesh, outer: str, inner: str, n: int, kv: bool,
-                 c2: int, chunks: int, s3: int, key_dtype_name: str,
-                 val_dtype_name: Optional[str], merge_backend: str,
-                 wire_codec: Optional[str], use_histogram: bool,
-                 interpret: Optional[bool]):
-    """Cross-host round: chunked/pipelined DCN bucket exchange + merge +
-    compaction, then the per-host sub-splitter prep for the finalize.
-    Out: compacted sorted pool (length L = next_pow2(d_out*chunks) *
-    (c2//chunks)) + (d_in,) sub-bucket starts/vcnt."""
-    d_out = int(mesh.shape[outer])
-    d_in = int(mesh.shape[inner])
-    n_dev = d_out * d_in
-    m = -(-n // n_dev)
-    cp = c2 // chunks
-    L = next_pow2(d_out * chunks) * cp
-    kdt = jnp.dtype(key_dtype_name)
-    maxkey = jnp.array(jnp.iinfo(kdt).max, kdt)
-
-    def local(*args):
-        if kv:
-            ks, vs, starts, vcnt = args
-        else:
-            (ks, starts, vcnt), vs = args, None
-
-        mk, mv, mvalid, recv_cnt = _exchange_merge(
-            ks, vs, starts, vcnt, outer, d_out, c2, maxkey,
-            merge_backend, interpret, chunks=chunks, wire_codec=wire_codec)
-
-        # the merged pool interleaves capacity pads with genuine max-key
-        # ties, so validity is NOT a prefix — compact it back to one with
-        # a rank scatter (maxkey fill keeps the tail sorted for the
-        # sub-splitter search)
-        c_my = jnp.sum(recv_cnt).astype(jnp.int32)
-        lrank = _running_count(mvalid) - 1
-        tgt = jnp.where(mvalid, lrank, L)                  # OOB -> drop
-        ck = jnp.full((L,), maxkey, mk.dtype).at[tgt].set(mk, mode="drop")
-        cv = None
-        if kv:
-            cv = jnp.zeros((L,), mv.dtype).at[tgt].set(mv, mode="drop")
-
-        # per-host sub-splitters: each host now holds exactly one global
-        # key range, but spread over its devices with no inter-device
-        # order — sample the *valid prefix* (dynamic length c_my), pool
-        # over the inner axis only, and cut d_in sub-buckets
-        pos = jnp.clip(((jnp.arange(s3) + 1) * c_my) // (s3 + 1), 0, L - 1)
-        samples = jax.lax.all_gather(ck[pos], inner)
-        splitters = select_splitters(samples, d_in)
-        bounds = bucket_bounds(ck, splitters, use_histogram=use_histogram,
-                               interpret=interpret)
-        vcnt3 = jnp.clip(jnp.minimum(bounds[1:], c_my) - bounds[:-1],
-                         0, L).astype(jnp.int32)
-        if kv:
-            return ck, cv, bounds[:-1], vcnt3
-        return ck, bounds[:-1], vcnt3
-
-    spec = P((outer, inner))
-    n_in = 4 if kv else 3
-    fn = _smap(local, mesh, (spec,) * n_in, (spec,) * n_in)
-    return jax.jit(fn)
-
-
-@functools.lru_cache(maxsize=128)
-def _hier_phase4(mesh: Mesh, outer: str, inner: str, n: int, kv: bool,
-                 L: int, c3: int, key_dtype_name: str,
-                 val_dtype_name: Optional[str], merge_backend: str,
-                 interpret: Optional[bool]):
-    """Finalize round: ICI sub-bucket exchange + merge, then the GLOBAL
-    rank rebalance over both axes — the concatenation over the linear
-    device order is the globally sorted array."""
-    d_out = int(mesh.shape[outer])
-    d_in = int(mesh.shape[inner])
-    n_dev = d_out * d_in
-    m = -(-n // n_dev)
-    kdt = jnp.dtype(key_dtype_name)
-    maxkey = jnp.array(jnp.iinfo(kdt).max, kdt)
-
-    def local(*args):
-        if kv:
-            ks, vs, starts, vcnt = args
-        else:
-            (ks, starts, vcnt), vs = args, None
-        my = _lin_index(mesh, (outer, inner))
-
-        mk, mv, mvalid, recv_cnt = _exchange_merge(
-            ks, vs, starts, vcnt, inner, d_in, c3, maxkey,
-            merge_backend, interpret)
-        shard_k, shard_v = _rebalance(mk, mv, mvalid, recv_cnt,
-                                      (outer, inner), n_dev, m, my)
-        if kv:
-            return shard_k, shard_v
-        return shard_k
-
-    spec = P((outer, inner))
-    n_in = 4 if kv else 3
-    fn = _smap(local, mesh, (spec,) * n_in,
                (spec, spec) if kv else spec)
     return jax.jit(fn)
 
@@ -642,43 +426,25 @@ def sample_sort(x: jnp.ndarray, mesh: Mesh, axis_name: AxisArg = "data", *,
                 capacity_slack: Optional[float] = None,
                 use_histogram: Optional[bool] = None,
                 merge_backend: Optional[str] = None,
-                hierarchical: Optional[bool] = None,
-                pipeline_chunks: Optional[int] = None,
-                wire_codec: Optional[str] = None,
                 interpret: Optional[bool] = None):
     """Globally sort a 1-D array over ``axis_name`` — one mesh axis, a
-    tuple of axes, or ``None`` for the whole mesh.  Returns the sorted
-    array (or ``(keys, values)`` with a payload), same length and
-    sharding layout as the input.
-
-    On a two-axis mesh ``(outer, inner)`` the sort defaults to the
-    **hierarchical** two-level schedule (see the module docstring): an
-    intra-host samplesort round over the fast inner tier, ONE chunked
-    cross-host exchange over the slow outer tier, and an intra-host
-    finalize — the flat single-exchange path remains available as
-    ``hierarchical=False`` (and is the only path on one-axis meshes).
-    Both produce bit-identical output.
+    tuple of axes, or ``None`` for the whole mesh (devices in row-major
+    order over the axes).  Returns the sorted array (or
+    ``(keys, values)`` with a payload), same length and sharding layout
+    as the input.
 
     ``capacity`` overrides the measured per-(source, destination) bucket
-    capacity on the flat path; it is validated against the realized
-    bucket bounds and raises rather than silently dropping elements when
-    too small (``m``, the shard length, is always sufficient).  Under an
-    outer ``jax.jit`` the measured mode is unavailable (it syncs counts
-    to the host) and the realized bounds cannot be checked, so only
-    ``capacity >= m`` is accepted there; the hierarchical path measures
-    three capacities and cannot run under an outer jit at all.
+    capacity; it is validated against the realized bucket bounds and
+    raises rather than silently dropping elements when too small (``m``,
+    the shard length, is always sufficient).  Under an outer ``jax.jit``
+    the measured mode is unavailable (it syncs counts to the host) and
+    the realized bounds cannot be checked, so only ``capacity >= m`` is
+    accepted there.
 
     ``capacity_slack`` (default: the active tuning profile's) multiplies
-    the *measured* bucket maxima before pow2 rounding: >1 buys headroom
+    the *measured* bucket maximum before pow2 rounding: >1 buys headroom
     so nearby workloads with slightly more skew reuse the same compiled
     programs instead of recompiling at the next capacity.
-
-    ``pipeline_chunks`` splits the slow-tier exchange into that many
-    chunked collectives (``collectives.pipeline_chunks`` picks the
-    realizable count); ``wire_codec='int8'`` sends the float *payload*
-    buckets of the cross-host exchange through the lossy grad_compress
-    codec — keys always travel wide, so the sort ORDER stays exact while
-    payload values are quantised.
     """
     mesh = auto_mesh(mesh)
     x = jnp.asarray(to_auto_mesh(x))
@@ -699,26 +465,6 @@ def sample_sort(x: jnp.ndarray, mesh: Mesh, axis_name: AxisArg = "data", *,
         if values.shape != x.shape:
             raise ValueError(f"values shape {values.shape} must match "
                              f"keys shape {x.shape}")
-    two_tier = len(axes) == 2 and \
-        all(int(mesh.shape[a]) > 1 for a in axes)
-    if hierarchical and len(axes) != 2:
-        raise ValueError(
-            f"hierarchical sample_sort needs exactly two mesh axes "
-            f"(outer, inner); got {axes}")
-    # a degenerate tier (size-1 axis) makes the two-level schedule pure
-    # overhead — it silently collapses to the flat path, same output
-    hier = two_tier if hierarchical is None else (hierarchical and two_tier)
-    if wire_codec is not None:
-        if wire_codec not in coll.WIRE_CODECS:
-            raise ValueError(f"unknown wire_codec {wire_codec!r}; "
-                             f"available: {coll.WIRE_CODECS}")
-        if not kv:
-            raise ValueError("wire_codec compresses the PAYLOAD buckets; "
-                             "pass values= (keys always travel wide)")
-        if not jnp.issubdtype(values.dtype, jnp.floating):
-            raise ValueError(
-                f"wire_codec='int8' quantises float payloads, got "
-                f"{jnp.dtype(values.dtype).name!r}")
     if use_histogram is None:
         use_histogram = jax.default_backend() == "tpu"
     s = samples_per_shard or default_samples_per_shard(m, n_dev)
@@ -737,31 +483,7 @@ def sample_sort(x: jnp.ndarray, mesh: Mesh, axis_name: AxisArg = "data", *,
     itemsize = jnp.dtype(enc.dtype).itemsize + \
         (jnp.dtype(values.dtype).itemsize if kv else 0)
 
-    if hier:
-        out = _hier_sample_sort(
-            enc, values, mesh, axes, n, kv, padded, local_method, s,
-            capacity, slack, use_histogram, merge_backend,
-            pipeline_chunks, wire_codec, itemsize, kname, vname, interpret)
-    else:
-        out = _flat_sample_sort(
-            enc, values, mesh, axes, n, kv, padded, local_method, s,
-            capacity, slack, use_histogram, merge_backend,
-            pipeline_chunks, wire_codec, itemsize, kname, vname, interpret)
-    if kv:
-        out_k, out_v = out
-        keys = keycodec.decode(out_k[:n], x.dtype, descending=descending)
-        return keys, out_v[:n]
-    return keycodec.decode(out[:n], x.dtype, descending=descending)
-
-
-def _flat_sample_sort(enc, values, mesh, axes, n, kv, padded, local_method,
-                      s, capacity, slack, use_histogram, merge_backend,
-                      pipeline_chunks, wire_codec, itemsize, kname, vname,
-                      interpret):
-    """The one-tier path: splitters over the whole mesh, ONE exchange."""
-    n_dev = _n_dev(mesh, axes)
-    m = -(-n // n_dev)
-    p1 = _phase1(mesh, axes, axes, n, kv, padded, local_method, s,
+    p1 = _phase1(mesh, axes, n, kv, padded, local_method, s,
                  use_histogram, interpret)
     with _obs.trace("samplesort.phase1", n=n, n_dev=n_dev, kv=kv,
                     samples_per_shard=s):
@@ -775,12 +497,10 @@ def _flat_sample_sort(enc, values, mesh, axes, n, kv, padded, local_method,
     # data needs (~m/D with regular sampling) instead of the worst case m
     with _obs.trace("samplesort.sync") as sp:
         max_bucket = _sync_max(vcnt)
-        cap = _flat_capacity(max_bucket, capacity, slack, m)
+        cap = _capacity(max_bucket, capacity, slack, m)
         sp.set(max_bucket=max_bucket, capacity=cap)
-    chunks = coll.pipeline_chunks(cap, pipeline_chunks) \
-        if pipeline_chunks is not None else 1
     if merge_backend is None:
-        merge_backend = _pick_merge_backend(cap // chunks)
+        merge_backend = _pick_merge_backend(cap)
 
     total_bytes = n_dev * alltoall_bytes_per_device(n_dev, m, itemsize, cap)
     if _obs.enabled() and max_bucket is not None:
@@ -794,27 +514,26 @@ def _flat_sample_sort(enc, values, mesh, axes, n, kv, padded, local_method,
         metrics.gauge("samplesort.bucket_skew").set(skew)
         metrics.counter("samplesort.alltoall_bytes").inc(total_bytes)
         metrics.counter("samplesort.sorts").inc()
-        if len(axes) == 2:
-            coll.record_split_exchange(total_bytes,
-                                       int(mesh.shape[axes[1]]),
-                                       int(mesh.shape[axes[0]]))
-        else:
-            coll.record_exchange("ici", total_bytes)
 
     p2 = _phase2(mesh, axes, n, kv, cap, kname, vname, merge_backend,
-                 chunks, wire_codec, interpret)
+                 interpret)
     with _obs.trace("samplesort.phase2", n=n, n_dev=n_dev, capacity=cap,
                     merge_backend=merge_backend,
                     bytes=total_bytes if _obs.enabled() else 0):
         if kv:
-            return p2(ks, vs, starts, vcnt)
-        return p2(ks, starts, vcnt)
+            out_k, out_v = p2(ks, vs, starts, vcnt)
+        else:
+            out_k = p2(ks, starts, vcnt)
+    keys = keycodec.decode(out_k[:n], x.dtype, descending=descending)
+    if kv:
+        return keys, out_v[:n]
+    return keys
 
 
-def _flat_capacity(max_bucket: Optional[int], capacity: Optional[int],
-                   slack: float, m: int) -> int:
-    """The flat exchange's static capacity: the measured bucket maximum
-    times ``slack``, or the caller's ``capacity`` checked against it."""
+def _capacity(max_bucket: Optional[int], capacity: Optional[int],
+              slack: float, m: int) -> int:
+    """The exchange's static capacity: the measured bucket maximum times
+    ``slack``, or the caller's ``capacity`` checked against it."""
     if capacity is None:
         if max_bucket is None:
             raise ValueError(
@@ -836,107 +555,6 @@ def _flat_capacity(max_bucket: Optional[int], capacity: Optional[int],
             f"capacity {capacity} is smaller than the realized maximum "
             f"bucket ({max_bucket}); the shard length {m} is always safe")
     return cap
-
-
-def _hier_sample_sort(enc, values, mesh, axes, n, kv, padded, local_method,
-                      s, capacity, slack, use_histogram, merge_backend,
-                      pipeline_chunks, wire_codec, itemsize, kname, vname,
-                      interpret):
-    """The two-level driver: four jitted phases, three capacity syncs."""
-    outer_ax, inner_ax = axes
-    d_out = int(mesh.shape[outer_ax])
-    d_in = int(mesh.shape[inner_ax])
-    n_dev = d_out * d_in
-    m = -(-n // n_dev)
-    if capacity is not None:
-        raise ValueError(
-            "capacity= overrides the FLAT exchange capacity; the "
-            "hierarchical path measures three per-phase capacities "
-            "(pass hierarchical=False to pin the flat one)")
-
-    # phase 1: local sort + INTRA-host splitters (partition group = inner)
-    p1 = _phase1(mesh, axes, (inner_ax,), n, kv, padded, local_method, s,
-                 use_histogram, interpret)
-    with _obs.trace("samplesort.hier.phase1", n=n, n_dev=n_dev, kv=kv,
-                    d_out=d_out, d_in=d_in, samples_per_shard=s):
-        if kv:
-            ks, vs, starts, vcnt = p1(enc, values)
-        else:
-            ks, starts, vcnt = p1(enc)
-            vs = None
-    with _obs.trace("samplesort.sync") as sp:
-        max1 = _sync_max(vcnt)
-        if max1 is None:
-            raise ValueError(
-                "hierarchical sample_sort measures per-phase exchange "
-                "capacities on the host and cannot run under an outer jit; "
-                "call it eagerly, or pass hierarchical=False with capacity=")
-        c1 = _round_capacity(int(math.ceil(max1 * slack)), m)
-        sp.set(max_bucket=max1, capacity=c1)
-    mb1 = merge_backend or _pick_merge_backend(c1)
-
-    # phase 2: ICI exchange + intra-host rebalance + outer splitter prep
-    p2 = _hier_phase2(mesh, outer_ax, inner_ax, n, kv, c1, s, kname, vname,
-                      mb1, use_histogram, interpret)
-    with _obs.trace("samplesort.hier.phase2", n=n, capacity=c1,
-                    merge_backend=mb1):
-        if kv:
-            ks, vs, starts, vcnt = p2(ks, vs, starts, vcnt)
-        else:
-            ks, starts, vcnt = p2(ks, starts, vcnt)
-    with _obs.trace("samplesort.sync") as sp:
-        max2 = _sync_max(vcnt)
-        c2 = _round_capacity(int(math.ceil(max2 * slack)), m)
-        sp.set(max_bucket=max2, capacity=c2)
-    chunks = coll.pipeline_chunks(c2, pipeline_chunks)
-    mb2 = merge_backend or _pick_merge_backend(c2 // chunks)
-
-    # phase 3: chunked DCN exchange + compaction + sub-splitter prep
-    p3 = _hier_phase3(mesh, outer_ax, inner_ax, n, kv, c2, chunks, s,
-                      kname, vname, mb2, wire_codec, use_histogram,
-                      interpret)
-    with _obs.trace("samplesort.hier.phase3", n=n, capacity=c2,
-                    chunks=chunks, wire_codec=wire_codec or "none",
-                    merge_backend=mb2):
-        if kv:
-            ks, vs, starts, vcnt = p3(ks, vs, starts, vcnt)
-        else:
-            ks, starts, vcnt = p3(ks, starts, vcnt)
-    L = next_pow2(d_out * chunks) * (c2 // chunks)
-    with _obs.trace("samplesort.sync") as sp:
-        max3 = _sync_max(vcnt)
-        c3 = _round_capacity(int(math.ceil(max3 * slack)), L)
-        sp.set(max_bucket=max3, capacity=c3)
-    mb3 = merge_backend or _pick_merge_backend(c3)
-
-    if _obs.enabled():
-        # per-tier movement bill (analytic, like the flat path's):
-        # ICI carries the intra round (exchange + intra rebalance), the
-        # finalize exchange, and its share of the global rebalance; DCN
-        # carries the cross-host buckets (narrowed by the wire codec) and
-        # the rest of the rebalance
-        ici = n_dev * alltoall_bytes_per_device(d_in, m, itemsize, c1)
-        ici += n_dev * d_in * c3 * itemsize
-        dcn = n_dev * d_out * c2 * itemsize
-        if wire_codec == "int8":
-            val_is = jnp.dtype(vname).itemsize
-            dcn -= n_dev * coll.wire_bytes_saved(d_out, c2, val_is)
-        coll.record_exchange("ici", ici)
-        coll.record_exchange("dcn", dcn)
-        coll.record_split_exchange(n_dev * n_dev * m * itemsize,
-                                   d_in, d_out)
-        metrics.counter("samplesort.alltoall_bytes").inc(
-            ici + dcn + n_dev * n_dev * m * itemsize)
-        metrics.counter("samplesort.sorts").inc()
-
-    # phase 4: ICI finalize exchange + GLOBAL rank rebalance
-    p4 = _hier_phase4(mesh, outer_ax, inner_ax, n, kv, L, c3, kname, vname,
-                      mb3, interpret)
-    with _obs.trace("samplesort.hier.phase4", n=n, capacity=c3,
-                    merge_backend=mb3):
-        if kv:
-            return p4(ks, vs, starts, vcnt)
-        return p4(ks, starts, vcnt)
 
 
 # ---------------------------------------------------------------------------
@@ -1003,9 +621,6 @@ def sample_topk(x: jnp.ndarray, k: int, mesh: Mesh,
     over D already-sorted candidate runs — finishes on every device.  No
     full-array sort, no bucket all-to-all, no rebalance round: for
     ``k ≪ n`` the collective bill shrinks from O(m) per device to O(D·k).
-    The candidate pool is small enough that even on a two-tier mesh the
-    flat all-gather IS the right schedule — there is no hierarchical
-    variant to pick.
 
     Correctness of the candidate cut: a shard with ``g`` genuine elements
     contributes ``min(kc, g)`` of them, and ``sum(min(kc, g_d)) >= k``
@@ -1038,12 +653,6 @@ def sample_topk(x: jnp.ndarray, k: int, mesh: Mesh,
         cand_bytes = n_dev * topk_candidate_bytes_per_device(
             n_dev, k, m, jnp.dtype(enc.dtype).itemsize)
         metrics.counter("samplesort.topk_candidate_bytes").inc(cand_bytes)
-        if len(axes) == 2:
-            coll.record_split_exchange(cand_bytes,
-                                       int(mesh.shape[axes[1]]),
-                                       int(mesh.shape[axes[0]]))
-        else:
-            coll.record_exchange("ici", cand_bytes)
     with _obs.trace("samplesort.topk", n=n, k=k, n_dev=n_dev,
                     bytes=cand_bytes):
         ev, ei = prog(enc)

@@ -25,7 +25,7 @@ import numpy as np
 
 from repro import sort as sorting
 from repro.configs.base import get_config, get_smoke_config
-from repro.core import topology as _topology, tuning as _tuning
+from repro.core import tuning as _tuning
 from repro.obs import metrics as _metrics, report as _obs_report, \
     trace as _obs
 from repro.launch import steps as steps_lib
@@ -33,7 +33,7 @@ from repro.launch.mesh import dp_axes_of, make_host_mesh
 from repro.models.model_zoo import build
 from repro.sharding.partitioning import ShardingPolicy
 
-# where serve persists its tuning/topology snapshot between runs (the
+# where serve persists its tuning-profile snapshot between runs (the
 # --state-dir flag overrides; unset means no persistence)
 SERVE_STATE_ENV = "REPRO_SERVE_STATE_DIR"
 
@@ -67,12 +67,10 @@ class LengthSortedScheduler:
     itself goes distributed: a (length, position) composite key is sorted
     globally over the mesh by the sample-sort (``axis_name`` follows
     ``distributed_sort`` — one axis, a tuple, or ``None`` for the whole
-    mesh; on a two-axis ``(hosts, devices)`` mesh the planner picks the
-    flat or hierarchical schedule from the topology tier rates), so a
-    fleet-scale queue never funnels through one device.  Single-device
-    meshes and backlogs under ``distributed_min`` keep the local argsort
-    path — per-queue-length shard_map programs only pay off once the
-    backlog reaches engine scale.
+    mesh), so a fleet-scale queue never funnels through one device.
+    Single-device meshes and backlogs under ``distributed_min`` keep the
+    local argsort path — per-queue-length shard_map programs only pay off
+    once the backlog reaches engine scale.
     """
 
     def __init__(self, batch_size: int, method: str = "auto", *,
@@ -155,16 +153,14 @@ def resolve_state_dir(explicit: Optional[str] = None
     return pathlib.Path(d) if d else None
 
 
-def restore_state(state_dir: os.PathLike, mesh=None) -> List[str]:
-    """Restore a previous run's snapshot from ``state_dir`` into the
-    ambient tuning/topology state.  Both restores are identity-gated: a
+def restore_state(state_dir: os.PathLike) -> List[str]:
+    """Restore a previous run's tuning-profile snapshot from ``state_dir``
+    into the ambient tuning state.  The restore is identity-gated: a
     profile whose device fingerprint differs (snapshot copied from another
-    machine) or a topology whose (fingerprint, mesh signature) does not
-    match the serving mesh is skipped, never trusted.  Returns the names
-    of what was restored (for the startup log line)."""
+    machine) is skipped, never trusted.  Returns the names of what was
+    restored (for the startup log line)."""
     restored: List[str] = []
-    d = pathlib.Path(state_dir)
-    pp = _tuning.profile_path(directory=d)
+    pp = _tuning.profile_path(directory=pathlib.Path(state_dir))
     if pp.is_file():
         try:
             prof = _tuning.load(pp)
@@ -174,37 +170,16 @@ def restore_state(state_dir: os.PathLike, mesh=None) -> List[str]:
                 restored.append("tuning profile")
         except _tuning.ProfileError:
             pass
-    if mesh is not None:
-        want = _topology.from_mesh(mesh)
-        tp = _topology.topology_path(want, directory=d)
-        if tp.is_file():
-            try:
-                topo = _topology.load(tp)
-                if (topo.fingerprint == want.fingerprint
-                        and topo.signature() == want.signature()):
-                    _topology.set_active(dataclasses.replace(
-                        topo, source="persisted"))
-                    restored.append("topology")
-            except _topology.TopologyError:
-                pass
     return restored
 
 
-def snapshot_state(state_dir: os.PathLike, mesh=None) -> List[pathlib.Path]:
-    """Snapshot the ACTIVE TuningProfile (and, given the serving mesh, the
-    resolved Topology) into ``state_dir`` so the next run starts from this
-    run's calibration instead of the platform defaults.  Returns the
-    written paths."""
-    paths: List[pathlib.Path] = []
-    d = pathlib.Path(state_dir)
+def snapshot_state(state_dir: os.PathLike) -> List[pathlib.Path]:
+    """Snapshot the ACTIVE TuningProfile into ``state_dir`` so the next
+    run starts from this run's calibration instead of the platform
+    defaults.  Returns the written paths."""
     prof = _tuning.active()
-    paths.append(_tuning.save(
-        prof, _tuning.profile_path(prof.fingerprint, directory=d)))
-    if mesh is not None:
-        topo = _topology.for_mesh(mesh)
-        paths.append(_topology.save(
-            topo, _topology.topology_path(topo, directory=d)))
-    return paths
+    return [_tuning.save(prof, _tuning.profile_path(
+        prof.fingerprint, directory=pathlib.Path(state_dir)))]
 
 
 def batch_accounting(done: List[Request]):
@@ -237,14 +212,14 @@ def serve(arch: str, smoke: bool = True, n_requests: int = 16,
 
     ``state_dir`` (or ``REPRO_SERVE_STATE_DIR``) makes the server
     stateful across restarts: on startup it restores the snapshotted
-    TuningProfile + Topology (identity-gated), on shutdown it snapshots
-    whatever is active — so a calibration paid once keeps pricing plans
+    TuningProfile (identity-gated), on shutdown it snapshots whatever is
+    active — so a calibration paid once keeps pricing plans
     across process restarts."""
     cfg = get_smoke_config(arch) if smoke else get_config(arch)
     mesh = make_host_mesh()
     sdir = resolve_state_dir(state_dir)
     if sdir is not None:
-        got = restore_state(sdir, mesh)
+        got = restore_state(sdir)
         if got:
             print(f"[serve] restored {' + '.join(got)} from {sdir}")
     if distributed_queue is None:
@@ -278,7 +253,7 @@ def serve(arch: str, smoke: bool = True, n_requests: int = 16,
         # shutdown snapshot — also on an exception mid-run, so a
         # calibration paid this run is never lost
         if sdir is not None:
-            for p in snapshot_state(sdir, mesh):
+            for p in snapshot_state(sdir):
                 print(f"[serve] state snapshot -> {p}")
     waste = float(np.mean(stats["padding_waste"]))
     print(f"[serve] {len(done)} requests in {stats['batches']} batches; "
@@ -358,7 +333,7 @@ def main():
                          "(--no-distributed-queue forces the local path; "
                          "default: on when the host has >1 device)")
     ap.add_argument("--state-dir", default=None,
-                    help="directory for the tuning/topology snapshot "
+                    help="directory for the tuning-profile snapshot "
                          "restored on startup and written on shutdown "
                          f"(default: ${SERVE_STATE_ENV} if set)")
     args = ap.parse_args()

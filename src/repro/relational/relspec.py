@@ -139,9 +139,9 @@ class RelSpec:
                 raise ValueError(
                     f"distributed relational variants exist for "
                     f"{tuple(sorted(MESH_OPS))}; op {op!r} has none")
-            # one axis, a tuple of axes (hierarchical meshes), or None ->
-            # the whole mesh — normalised by the shared helper so the
-            # relational mesh ops accept exactly what distributed_sort does
+            # one axis, a tuple of axes, or None -> the whole mesh —
+            # normalised by the shared helper so the relational mesh ops
+            # accept exactly what distributed_sort does
             from repro.engine.samplesort import _axes_tuple
             axis_name = _axes_tuple(self.mesh, axis_name)
             if method not in ("auto", "distributed"):

@@ -154,9 +154,8 @@ def sort(x: _Arr, *, axis: int = -1, descending: bool = False,
     """Sort along ``axis``; with ``valid_lengths``, sort each row's valid
     prefix of a padded batch (the scheduler's fixed-shape buckets); with
     ``mesh``/``axis_name``, sort a flat array globally over the mesh
-    (sample-sort; ``axis_name=None`` spans all mesh axes, taking the
-    two-level ICI/DCN schedule on multi-axis meshes; odd-even fallback
-    on a single axis)."""
+    (sample-sort; ``axis_name=None`` spans all mesh axes; odd-even
+    fallback on a single axis)."""
     with _obs.trace("sort.run"):
         return _run(SortSpec(axis=axis, descending=descending,
                              method=method, run_len=run_len,

@@ -343,51 +343,6 @@ def test_fuzz_spill_argsort_is_stable(case):
 
 
 # ---------------------------------------------------------------------------
-# hierarchical lens: the two-level (ICI/DCN) schedule vs flat vs the
-# jnp oracle on a 2x4 mesh (runs on the multi-device CI job; skipped
-# below 8 local devices, where no two-tier grid is expressible)
-# ---------------------------------------------------------------------------
-
-import pytest  # noqa: E402
-
-from repro.engine import samplesort as _samplesort  # noqa: E402
-
-HIER_DTYPES = ("float32", "int32", "uint16")
-
-
-@st.composite
-def hier_cases(draw):
-    return {
-        "seed": draw(st.integers(0, 2**31 - 1)),
-        # uneven shard tails, sub-device-count n, and pow2 shapes
-        "n": draw(st.sampled_from([5, 64, 257, 1003, 2048])),
-        "dtype": draw(st.sampled_from(HIER_DTYPES)),
-        "dist": draw(st.sampled_from(DISTRIBUTIONS)),
-        "descending": draw(st.booleans()),
-    }
-
-
-@pytest.mark.skipif(len(jax.devices()) < 8,
-                    reason="2x4 hierarchical mesh needs 8 local devices")
-@given(hier_cases())
-@settings(max_examples=6, deadline=None)
-def test_fuzz_hier_sample_sort_matches_flat_and_jnp(case):
-    mesh = jax.make_mesh((2, 4), ("host", "dev"))
-    x = _values(case["seed"], (case["n"],), case["dtype"], case["dist"])
-    desc = case["descending"]
-    hier = _samplesort.sample_sort(x, mesh, None, descending=desc,
-                                   hierarchical=True)
-    flat = _samplesort.sample_sort(x, mesh, None, descending=desc,
-                                   hierarchical=False)
-    ref = _f64(jnp.sort(x))
-    if desc:
-        ref = ref[::-1]
-    msg = f"hier/{case['dtype']}/{case['dist']}/n={case['n']}/desc={desc}"
-    np.testing.assert_array_equal(_f64(hier), ref, err_msg=msg)
-    np.testing.assert_array_equal(_f64(hier), _f64(flat), err_msg=msg)
-
-
-# ---------------------------------------------------------------------------
 # relational lens: every repro.relational op vs its numpy reference
 # ---------------------------------------------------------------------------
 

@@ -120,17 +120,12 @@ def test_send_pools_cover_the_edge_buckets():
     assert (vcnt == _CAP).any()
 
 
-@pytest.mark.parametrize("kv,chunks,wire_codec", [
-    (False, 1, None), (False, 2, None),
-    (True, 1, None), (True, 2, None),
-    (True, 1, "int8"), (True, 2, "int8"),
-])
-def test_send_buffer_matches_gather(monkeypatch, kv, chunks, wire_codec):
+@pytest.mark.parametrize("kv", [False, True])
+def test_send_buffer_matches_gather(monkeypatch, kv):
     """The sliced send buffers equal the index-gather ones element for
     element, and so does the whole exchange round built on them (four
     simulated devices: the collectives run over a vmapped axis)."""
-    ks, vs, starts, vcnt = _send_pools(
-        np.float32 if wire_codec else np.int32)
+    ks, vs, starts, vcnt = _send_pools(np.int32)
     maxkey = jnp.uint32(np.iinfo(np.uint32).max)
     zero = jnp.zeros((), vs.dtype)
     for d in range(_P):
@@ -144,7 +139,7 @@ def test_send_buffer_matches_gather(monkeypatch, kv, chunks, wire_codec):
     def exchange(ks, vs, starts, vcnt):
         return samplesort._exchange_merge(
             ks, vs if kv else None, starts, vcnt, "d", _P, _CAP, maxkey,
-            "sort", None, chunks=chunks, wire_codec=wire_codec)
+            "sort", None)
 
     run = jax.vmap(exchange, axis_name="d")
     sliced = run(ks, vs, starts, vcnt)
@@ -169,6 +164,93 @@ def test_send_buffer_lowers_without_gather():
 
     assert "gather" not in stablehlo(samplesort._send_buffer)
     assert "gather" in stablehlo(_gather_send_buffer)
+
+
+# ---------------------------------------------------------------------------
+# one whole round (bucket exchange, merge, rank rebalance) over P simulated
+# devices: the collectives run over a vmapped axis
+# ---------------------------------------------------------------------------
+
+_ROUND_SHARD = 203                     # odd and not a power of two
+
+
+def _round_keys(dist, n, rng):
+    """int32 keys with the shape classes of tests/test_mesh_sort_kv.py."""
+    partkey = rng.integers(1, n // 30 + 2, n).astype(np.int32)
+    if dist == "uniform":
+        return partkey
+    if dist == "all_equal":
+        return np.full(n, 7, np.int32)
+    if dist == "few_distinct":
+        return rng.choice(np.array([-5, 10, 20], np.int32), n)
+    hot = partkey.copy()
+    hot[rng.random(n) < 0.4] = int(np.median(partkey))
+    return hot
+
+
+def _round_inputs(keys, n_dev, m):
+    """What phase 1 hands the exchange, built on the host: sorted padded
+    shards (pads behind genuine max-key ties), their payload row ids,
+    bucket starts and genuine-key counts against pooled regular-sample
+    splitters."""
+    n = keys.shape[0]
+    maxkey = np.iinfo(np.uint32).max
+    enc = np.full(n_dev * m, maxkey, np.uint32)
+    enc[:n] = np.asarray(keycodec.encode(jnp.asarray(keys)))
+    rows = np.zeros(n_dev * m, np.int32)
+    rows[:n] = np.arange(n)
+    order = np.argsort(enc.reshape(n_dev, m), axis=1, kind="stable")
+    ks = np.take_along_axis(enc.reshape(n_dev, m), order, 1)
+    vs = np.take_along_axis(rows.reshape(n_dev, m), order, 1)
+    s = samplesort.default_samples_per_shard(m, n_dev)
+    pos = ((np.arange(s) + 1) * m) // (s + 1)
+    splitters = samplesort.select_splitters(jnp.asarray(ks[:, pos]), n_dev)
+    b = np.asarray(jax.jit(jax.vmap(samplesort.bucket_bounds,
+                                    in_axes=(0, None)))(ks, splitters))
+    n_valid = np.clip(n - np.arange(n_dev) * m, 0, m)[:, None]
+    vcnt = np.clip(np.minimum(b[:, 1:], n_valid) - b[:, :-1], 0, m)
+    return (enc[:n], jnp.asarray(ks), jnp.asarray(vs),
+            jnp.asarray(b[:, :-1]), jnp.asarray(vcnt.astype(np.int32)))
+
+
+@pytest.mark.parametrize("kv", [False, True])
+@pytest.mark.parametrize("dist", ["uniform", "all_equal", "few_distinct",
+                                  "hot_median"])
+@pytest.mark.parametrize("n_dev", [2, 3, 4, 8])
+def test_flat_round_matches_np_sort(n_dev, dist, kv):
+    """``_exchange_merge`` then ``_rebalance``, as phase 2 runs them:
+    every output shard is its slice of ``np.sort``, and every payload
+    names a distinct input row holding its key.  Three devices merge a
+    number of runs that is not a power of two; the last shard is padded;
+    the skewed distributions put whole shards into one bucket."""
+    m = _ROUND_SHARD
+    n = n_dev * m - 3
+    keys = _round_keys(dist, n, np.random.default_rng(n_dev))
+    enc, ks, vs, starts, vcnt = _round_inputs(keys, n_dev, m)
+    c = samplesort._round_capacity(int(np.max(np.asarray(vcnt))), m)
+    maxkey = jnp.uint32(np.iinfo(np.uint32).max)
+
+    def one_round(ks, vs, starts, vcnt):
+        mk, mv, mvalid, cnt = samplesort._exchange_merge(
+            ks, vs if kv else None, starts, vcnt, "d", n_dev, c, maxkey,
+            "sort", None)
+        return samplesort._rebalance(mk, mv, mvalid, cnt, "d", n_dev, m,
+                                     jax.lax.axis_index("d"))
+
+    out_k, out_v = jax.jit(jax.vmap(one_round, axis_name="d"))(
+        ks, vs, starts, vcnt)
+    assert (out_v is None) == (not kv)
+    out_k = np.asarray(out_k)
+    ref = np.sort(enc)
+    for d in range(n_dev):
+        lo, hi = d * m, min((d + 1) * m, n)
+        np.testing.assert_array_equal(out_k[d, :hi - lo], ref[lo:hi],
+                                      err_msg=f"shard {d}")
+    if kv:
+        rows = np.asarray(out_v).reshape(-1)[:n]
+        assert ((rows >= 0) & (rows < n)).all()
+        assert len(set(rows.tolist())) == n
+        np.testing.assert_array_equal(enc[rows], out_k.reshape(-1)[:n])
 
 
 # ---------------------------------------------------------------------------
@@ -333,6 +415,25 @@ def test_choose_distributed_crossover():
     assert ratios == sorted(ratios)
 
 
+def test_choose_distributed_cached_four_chip_cell():
+    """The four-chip LINEITEM SF 30 sort plans the sample-sort, and the
+    second lookup is a cache hit."""
+    plan = planner.choose_distributed_cached(179_998_372, 4, jnp.int32)
+    assert plan.strategy == "sample"
+    assert planner.choose_distributed_cached(179_998_372, 4,
+                                             jnp.int32) is plan
+
+
+def test_axes_tuple_validation():
+    mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+    assert samplesort._axes_tuple(mesh, None) == ("data",)
+    assert samplesort._axes_tuple(mesh, "data") == ("data",)
+    with pytest.raises(ValueError):
+        samplesort._axes_tuple(mesh, "nope")
+    with pytest.raises(ValueError):
+        samplesort._axes_tuple(mesh, ("data", "data"))
+
+
 def test_collective_cost_ns_terms():
     c = cost_model.DeviceSortConstants()
     base = cost_model.collective_cost_ns(1, 0, 4, c)
@@ -448,19 +549,57 @@ print("SAMPLESORT_8DEV_OK")
     assert "SAMPLESORT_8DEV_OK" in r.stdout, r.stderr[-2000:]
 
 
+def test_sample_sort_2x4_subprocess():
+    """A two-axis ("host", "dev") mesh with ``axis_name=None`` sorts over
+    the row-major linear device order: one exchange over both axes."""
+    code = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+import jax, jax.numpy as jnp, numpy as np
+from repro.engine import samplesort
+mesh = jax.make_mesh((2, 4), ("host", "dev"))
+assert samplesort._axes_tuple(mesh, None) == ("host", "dev")
+assert samplesort._n_dev(mesh, ("host", "dev")) == 8
+rng = np.random.default_rng(0)
+n = 1003                                    # shards of 126, the last padded
+k = rng.integers(0, 9, n).astype(np.int32)
+v = np.arange(n, dtype=np.int32)
+for desc in (False, True):
+    ref = np.flip(np.sort(k)) if desc else np.sort(k)
+    out = samplesort.sample_sort(jnp.asarray(k), mesh, None, descending=desc)
+    assert (np.asarray(out) == ref).all()
+    sk, sv = samplesort.sample_sort(jnp.asarray(k), mesh, None,
+                                    values=jnp.asarray(v), descending=desc)
+    sk, sv = np.asarray(sk), np.asarray(sv)
+    assert (sk == ref).all()
+    assert (k[sv] == sk).all() and len(set(sv.tolist())) == n
+# unique composites pin the exact permutation
+comp = ((k.astype(np.uint32) & 0xF) << 10) | np.arange(n, dtype=np.uint32)
+out = samplesort.sample_sort(jnp.asarray(comp), mesh, None)
+assert (np.asarray(out) == np.sort(comp)).all()
+print("SAMPLESORT_2X4_OK")
+"""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {**os.environ, "PYTHONPATH": os.path.join(repo, "src")}
+    env.pop("XLA_FLAGS", None)        # the subprocess pins its own count
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=420)
+    assert "SAMPLESORT_2X4_OK" in r.stdout, r.stderr[-2000:]
+
+
 # ---------------------------------------------------------------------------
 # forced 4-device runs with a capacity below the shard length: the tail
 # buckets take the extended-slice case of the send buffers
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("hierarchical", [False, True])
-def test_sample_sort_4dev_capacity_below_shard(hierarchical):
+@pytest.mark.parametrize("shape", [(4,), (2, 2)], ids=["4", "2x2"])
+def test_sample_sort_4dev_capacity_below_shard(shape):
     code = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
 from repro.engine import samplesort
-HIER = %s
+SHAPE = %r
 tail_reads = []
 sliced = samplesort._send_buffer
 def spy(x, starts, vcnt, c, fill):
@@ -474,12 +613,9 @@ n = 4003                                    # shards of 1001, one padded
 k = rng.integers(-50, 50, n).astype(np.int32)
 k[::97] = np.iinfo(np.int32).max            # genuine keys tie the fill
 v = np.arange(n, dtype=np.int32)
-if HIER:
-    mesh = jax.make_mesh((2, 2), ("dcn", "ici"))
-    kw = dict(axis_name=None, hierarchical=True)
-else:
-    mesh = jax.make_mesh((4,), ("data",))
-    kw = dict(capacity=512)                 # < 1001, >= the largest bucket
+# (2, 2) sorts flat over both axes, in the (4,) mesh's device order
+mesh = jax.make_mesh(SHAPE, ("data",) if len(SHAPE) == 1 else ("outer", "inner"))
+kw = dict(axis_name=None, capacity=512)     # < 1001, >= the largest bucket
 sk, sv = samplesort.sample_sort(jnp.asarray(k), mesh, values=jnp.asarray(v),
                                 **kw)
 sk, sv = np.asarray(sk), np.asarray(sv)
@@ -487,11 +623,10 @@ jax.effects_barrier()
 assert any(tail_reads), tail_reads
 assert (sk == np.sort(k)).all()
 assert (k[sv] == sk).all() and len(set(sv.tolist())) == n
-if not HIER:
-    out = np.asarray(samplesort.sample_sort(jnp.asarray(k), mesh, **kw))
-    assert (out == np.sort(k)).all()
+out = np.asarray(samplesort.sample_sort(jnp.asarray(k), mesh, **kw))
+assert (out == np.sort(k)).all()
 print("SAMPLESORT_4DEV_OK")
-""" % hierarchical
+""" % (shape,)
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = {**os.environ, "PYTHONPATH": os.path.join(repo, "src")}
     env.pop("XLA_FLAGS", None)        # the subprocess pins its own count
